@@ -213,6 +213,8 @@ def main() -> None:
                     help="dataset for the engine/stream benches and their "
                          "BENCH_*.json files")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
 
     benches = _bench_list(args)
     print("name,us_per_call,derived")

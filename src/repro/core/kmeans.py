@@ -15,11 +15,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def pairwise_sq_l2(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    """Squared L2 distances ||x - c||^2, shapes (n, D) x (k, D) -> (n, k)."""
+def pairwise_sq_l2(x: jnp.ndarray, c: jnp.ndarray,
+                   precision=None) -> jnp.ndarray:
+    """Squared L2 distances ||x - c||^2, shapes (n, D) x (k, D) -> (n, k).
+
+    ``precision`` goes to the ``x @ c.T`` matmul: the default is one
+    bf16 pass on TPU; ``Precision.HIGHEST`` is full f32 there (exact
+    references).  On CPU every precision computes the same f32 dot."""
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (n, 1)
     c2 = jnp.sum(c * c, axis=-1)                          # (k,)
-    xc = x @ c.T                                          # (n, k)
+    xc = jnp.matmul(x, c.T, precision=precision)          # (n, k)
     return jnp.maximum(x2 - 2.0 * xc + c2[None, :], 0.0)
 
 

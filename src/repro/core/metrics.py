@@ -13,7 +13,10 @@ from .kmeans import pairwise_sq_l2
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _gt_chunk(x, q, k, metric):
-    d = (pairwise_sq_l2(q, x) if metric == "l2" else -(q @ x.T))
+    # the exact reference is exact on every backend: full-f32 matmul
+    hi = jax.lax.Precision.HIGHEST
+    d = (pairwise_sq_l2(q, x, precision=hi) if metric == "l2"
+         else -jnp.matmul(q, x.T, precision=hi))
     _, idx = jax.lax.top_k(-d, k)
     return idx.astype(jnp.int32)
 

@@ -54,6 +54,10 @@ DATASETS = {
                           latent=40, zipf=1.0),
     "t2i": DatasetSpec("t2i", 80_000, 128, 2_000, metric="ip",
                        modality_gap=True),
+    # SIFT1M at its published scale (1M x 128 base, L2; 1,000 of its
+    # 10,000 queries) — the one-chip corpus of chip_smoke.py, generated
+    # on the device
+    "sift1m_full": DatasetSpec("sift1m_full", 1_000_000, 128, 1_000),
     # tiny configs for tests
     "unit": DatasetSpec("unit", 6_000, 32, 200, n_components=16, latent=12),
     "unit_ip": DatasetSpec("unit_ip", 6_000, 32, 200, n_components=16,
@@ -84,12 +88,16 @@ def make_dataset(name: str, seed: int = 0
     z = _latent_mixture(kd, spec.n, spec.n_components, spec.latent,
                         spec.zipf, spec.spread)
     proj = jax.random.normal(kw, (spec.latent, spec.d)) / jnp.sqrt(spec.latent)
-    x = z @ proj + jax.random.normal(kn, (spec.n, spec.d)) * 0.02
+    # full-f32 projections: the same seed makes the same corpus on CPU
+    # and TPU (a default-precision TPU matmul rounds through bf16)
+    hi = jax.lax.Precision.HIGHEST
+    x = (jnp.matmul(z, proj, precision=hi)
+         + jax.random.normal(kn, (spec.n, spec.d)) * 0.02)
     if spec.modality_gap:
         zq = _latent_mixture(kq, spec.n_queries, spec.n_components,
                              spec.latent, spec.zipf, spec.spread * 1.3)
         shift = jax.random.normal(ks, (spec.latent,)) * 0.3
-        q = (zq + shift) @ proj
+        q = jnp.matmul(zq + shift, proj, precision=hi)
         if spec.metric == "ip":  # Zipf-ish norms on data side (MIPS skew)
             norms = 1.0 + jax.random.gamma(kp, 2.0, (spec.n, 1)) * 0.3
             x = x * norms

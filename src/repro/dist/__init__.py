@@ -1,24 +1,15 @@
 """Distributed runtime layer: logical-axis sharding rules, host
 checkpointing with retention, and elastic mesh replanning.
 
-Also home of the ``shard_map`` version shim: ``jax.shard_map`` landed
-after 0.4.x, where the same API lives in ``jax.experimental.shard_map``
-with ``check_rep`` instead of ``check_vma``.
+Also home of ``shard_map``: ``jax.shard_map`` with replication
+checking (``check_vma``) off by default, as every caller here wants.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-        kw.setdefault("check_vma", False)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_04
 
-    def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-        kw.pop("check_vma", None)
-        kw.setdefault("check_rep", False)
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
+def shard_map(f, *, mesh, in_specs, out_specs, **kw):
+    kw.setdefault("check_vma", False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

@@ -1,10 +1,17 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode — the kernel
-body executes in Python for correctness validation; on TPU backends the
-same code lowers to Mosaic.  ``M`` (PQ subspaces) is zero-padded to the
-uint8 lane tile so production shapes are alignment-clean; padded codes
-are 0 and padded LUT rows are 0, so they contribute nothing.
+The backend decides how a kernel runs, explicitly (``_interpret``):
+
+  * ``tpu`` — compiled through Mosaic, always;
+  * ``cpu`` — interpret mode: the kernel body executes as plain XLA ops
+    on the host.  This is the test path (``JAX_PLATFORMS=cpu``), which
+    validates results, never speed;
+  * any other backend raises — a kernel path never falls back to the
+    interpreter or to a jnp reference in silence.
+
+On TPU ``M`` (PQ subspaces) is zero-padded to the uint8 lane tile so
+production shapes are alignment-clean; padded codes are 0 and padded
+LUT rows are 0, so they contribute nothing.
 """
 from __future__ import annotations
 
@@ -19,8 +26,17 @@ from .pq_scan import (pq_scan_paged_kernel, pq_scan_tiled_kernel,
 _LANE = 128
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """True on CPU (interpret mode), False on TPU (compiled); raises on
+    any other backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas scan kernels run compiled on 'tpu' or interpreted on "
+        f"'cpu'; the default backend is {backend!r}")
 
 
 def _pad_m(lut: jnp.ndarray, block_codes: jnp.ndarray, align: int):
@@ -69,10 +85,10 @@ def pq_scan_paged(lut: jnp.ndarray, block_codes: jnp.ndarray,
                   packed: bool = False) -> jnp.ndarray:
     """Per-query paged ADC scan.  lut (B, M, K) f32, block_codes
     (TB, BLK, M) uint8, block_idx (B, S) int32 (>= 0) -> (B, S, BLK) f32."""
-    on_tpu = _on_tpu()
-    lut, block_codes = _align(lut, block_codes, packed, on_tpu)
+    interpret = _interpret()
+    lut, block_codes = _align(lut, block_codes, packed, not interpret)
     return pq_scan_paged_kernel(lut, block_codes, block_idx.astype(jnp.int32),
-                                query_tile=1, interpret=not on_tpu,
+                                query_tile=1, interpret=interpret,
                                 packed=packed)
 
 
@@ -84,12 +100,12 @@ def pq_scan_grouped(lut: jnp.ndarray, block_codes: jnp.ndarray,
     The code tile for each position stays resident in VMEM across the
     query-tile grid steps."""
     b = lut.shape[0]
-    on_tpu = _on_tpu()
-    lut, block_codes = _align(lut, block_codes, packed, on_tpu)
+    interpret = _interpret()
+    lut, block_codes = _align(lut, block_codes, packed, not interpret)
     idx = jnp.broadcast_to(shared_idx[None, :],
                            (b // query_tile, shared_idx.shape[0]))
     return pq_scan_tiled_kernel(lut, block_codes, idx.astype(jnp.int32),
-                                query_tile=query_tile, interpret=not on_tpu,
+                                query_tile=query_tile, interpret=interpret,
                                 packed=packed)
 
 
@@ -103,10 +119,10 @@ def pq_scan_tiled(lut: jnp.ndarray, block_codes: jnp.ndarray,
     lists feed the kernel index_map directly (no (B, W) re-broadcast);
     the code tile for each union position stays resident in VMEM across
     its tile's grid steps."""
-    on_tpu = _on_tpu()
-    lut, block_codes = _align(lut, block_codes, packed, on_tpu)
+    interpret = _interpret()
+    lut, block_codes = _align(lut, block_codes, packed, not interpret)
     return pq_scan_tiled_kernel(lut, block_codes, tile_idx.astype(jnp.int32),
-                                query_tile=query_tile, interpret=not on_tpu,
+                                query_tile=query_tile, interpret=interpret,
                                 packed=packed)
 
 
@@ -124,10 +140,10 @@ def pq_scan_topk(lut: jnp.ndarray, block_codes: jnp.ndarray,
     ``core/engine/fused.py`` for the per-exec-mode construction).
     Returns (acc_d, acc_pos, acc_id, dco) — (B, fetch) sorted candidate
     triple + (B,) logical DCO."""
-    on_tpu = _on_tpu()
-    lut, block_codes = _align(lut, block_codes, packed, on_tpu)
+    interpret = _interpret()
+    lut, block_codes = _align(lut, block_codes, packed, not interpret)
     return pq_scan_topk_kernel(
         lut, block_codes, block_ids, block_other,
         tile_idx.astype(jnp.int32), rank_of, slot_of, rank_u, dead,
-        query_tile=query_tile, fetch=fetch, interpret=not on_tpu,
+        query_tile=query_tile, fetch=fetch, interpret=interpret,
         packed=packed)

@@ -25,7 +25,18 @@ instead of the paper's 32 — ``block`` stays a config knob and the
 paper's Fig. 16 block-size study covers the sweep.  uint8 code tiles
 want (32, 128) alignment, so M is zero-padded to a multiple of 128 by
 ``ops.pq_scan_paged`` (padded codes select lut[m_pad, 0] == 0).
-Validated against ``ref.py`` in interpret mode on CPU.
+
+Block layout rules of the TPU lowering: the last two dimensions of a
+block must be multiples of (8, 128) or equal the array's.  Every
+operand paged one row at a time (per-block ids/other/tombstones) and
+every per-query array tiled by a query tile that may be 1, 2 or 4 rows
+therefore carries a singleton axis in front of its minor dimension —
+``(TB, 1, BLK)``, ``(B, 1, F)``, ``(B, S, 1, BLK)`` — so that a block's
+last two dimensions equal the array's.  The scalar-prefetched scan
+lists live in SMEM (1 MiB on v5e); ``_map_query_chunks`` splits a batch
+whose lists would not fit into sequential kernel calls over query
+chunks.  Validated against ``ref.py`` in interpret mode on CPU and
+compiled for a described v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -39,23 +50,83 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .topk import PAD_POS, bitonic_sort, merge_topf, pow2_ceil
 
+# bytes of scalar-prefetched scan lists one kernel call may hold in SMEM
+# (1 MiB on v5e, shared with the kernel's own scalars)
+SMEM_PREFETCH_BYTES = 256 * 1024
+
+
+def _tiles_per_call(qb: int, s: int) -> int:
+    """Largest divisor of the query-tile count whose (tiles, S) int32
+    scan lists fit ``SMEM_PREFETCH_BYTES``."""
+    d = max(1, min(qb, SMEM_PREFETCH_BYTES // (4 * max(s, 1))))
+    while qb % d:
+        d -= 1
+    return d
+
+
+def _map_query_chunks(call, tile_idx, per_query, query_tile: int):
+    """Run ``call(tile_idx, *per_query)`` over query chunks small enough
+    for SMEM.  ``per_query`` arrays lead with the batch axis; outputs do
+    too.  One chunk is a plain call; more run as one ``lax.map`` over
+    the same kernel, so results are bitwise those of a single call."""
+    qb, s = tile_idx.shape
+    d = _tiles_per_call(qb, s)
+    if d == qb:
+        return call(tile_idx, *per_query)
+    nc = qb // d
+    chunked = [x.reshape((nc, d * query_tile) + x.shape[1:])
+               for x in per_query]
+    out = jax.lax.map(lambda a: call(a[0], *a[1:]),
+                      (tile_idx.reshape(nc, d, s), *chunked))
+    return jax.tree.map(lambda y: y.reshape((-1,) + y.shape[2:]), out)
+
 
 def _tile_codes(codes_ref, packed: bool) -> jnp.ndarray:
     """Code tile -> (BLK, M) int32 codes, unpacking nibble pairs in-VMEM.
 
     A packed tile (quant plane, two 4-bit codes per byte) carries MB =
     M/2 bytes; the lo nibble is the even subquantizer, hi the odd —
-    the single layout defined by ``quant/nibbles.py``.  Callers
-    guarantee 2*MB == lut M (ops wrappers zero-pad the LUT so a padded
-    byte's two zero codes select zero rows and contribute nothing).
+    the single layout defined by ``quant/nibbles.py``.  Interleaving
+    the nibbles back does not lower on TPU, so the unpacked tile is
+    ``[lo | hi]`` — every even subquantizer, then every odd one — and
+    the kernel wrappers permute the LUT rows to match
+    (``_even_odd_lut``).  Callers guarantee 2*MB == lut M (ops wrappers
+    zero-pad the LUT so a padded byte's two zero codes select zero rows
+    and contribute nothing).
     """
     raw = codes_ref[0].astype(jnp.int32)                       # (BLK, MB)
     if not packed:
         return raw
-    blk, mb = raw.shape
-    lo = raw & 15
-    hi = raw >> 4
-    return jnp.stack([lo, hi], axis=-1).reshape(blk, 2 * mb)
+    return jnp.concatenate([raw & 15, raw >> 4], axis=-1)
+
+
+def _even_odd_lut(lut: jnp.ndarray, packed: bool) -> jnp.ndarray:
+    """(B, M, K) LUT in the row order ``_tile_codes`` unpacks to."""
+    if not packed:
+        return lut
+    return jnp.concatenate([lut[:, 0::2], lut[:, 1::2]], axis=1)
+
+
+def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
+    """(QT, BLK) ADC distances of one paged code block for a query tile.
+
+    The 4-bit code of item i, subspace m selects ``lut[m, code]``; the
+    selection is a one-hot tile over the K table entries, flattened
+    (M, K) -> MK and contracted on the MXU, so every query of the tile
+    scores the whole block in one pass.  Both kernels score through
+    here, so their distances are bitwise identical.  The contraction is
+    pinned to full f32 (HIGHEST): the one-hot is exact in bf16 but the
+    LUT is not, and the distances must match the jnp reference's."""
+    qt, m, k = lut_ref.shape
+    codes = _tile_codes(codes_ref, packed)                     # (BLK, M)
+    blk = codes.shape[0]
+    sel = (codes[:, :, None]
+           == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2))
+    oh = sel.astype(jnp.float32).reshape(blk, m * k)           # (BLK, MK)
+    lut = lut_ref[...].reshape(qt, m * k)                      # (QT, MK)
+    return jax.lax.dot_general(lut, oh, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _make_kernel(packed: bool):
@@ -65,22 +136,12 @@ def _make_kernel(packed: bool):
         """One grid step: score one code block for QT queries.
 
         lut_ref:   (QT, M, K) f32 in VMEM
-        codes_ref: (BLK, MB) uint8 in VMEM (the paged block; MB = M, or
-                   M/2 when nibble-packed)
-        out_ref:   (QT, 1, BLK) f32
+        codes_ref: (1, BLK, MB) uint8 in VMEM (the paged block; MB = M,
+                   or M/2 when nibble-packed)
+        out_ref:   (QT, 1, 1, BLK) f32
         """
-        qt, m, k = lut_ref.shape
-        codes = _tile_codes(codes_ref, packed)                 # (BLK, M)
-        blk = codes.shape[0]
-        # one-hot over the K table entries; flatten (M, K) -> MK for the MXU
-        sel = (codes[:, :, None]
-               == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2))
-        oh = sel.astype(jnp.float32).reshape(blk, m * k)       # (BLK, MK)
-        lut = lut_ref[...].reshape(qt, m * k)                  # (QT, MK)
-        # (QT, MK) @ (MK, BLK) on the MXU: every query scores the block at once
-        d = jax.lax.dot_general(lut, oh, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        out_ref[...] = d[:, None, :]
+        d = _score_block(lut_ref, codes_ref, packed)
+        out_ref[...] = d[:, None, None, :]
 
     return _kernel
 
@@ -107,25 +168,31 @@ def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     tb, blk, mb = block_codes.shape
     assert (2 * mb if packed else mb) == m, (mb, m, packed)
     assert b == qb * query_tile, (b, qb, query_tile)
+    lut = _even_odd_lut(lut, packed)
 
-    grid = (qb, s)
-    kernel = pl.pallas_call(
-        _make_kernel(packed),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((query_tile, m, k), lambda qi, si, idx: (qi, 0, 0)),
-                pl.BlockSpec((1, blk, mb),
-                             lambda qi, si, idx: (idx[qi, si], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((query_tile, 1, blk),
-                                   lambda qi, si, idx: (qi, si, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, s, blk), jnp.float32),
-        interpret=interpret,
-    )
-    return kernel(tile_idx, lut, block_codes)
+    def call(idx, lut_c):
+        kernel = pl.pallas_call(
+            _make_kernel(packed),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=idx.shape,
+                in_specs=[
+                    pl.BlockSpec((query_tile, m, k),
+                                 lambda qi, si, idx: (qi, 0, 0)),
+                    pl.BlockSpec((1, blk, mb),
+                                 lambda qi, si, idx: (idx[qi, si], 0, 0)),
+                ],
+                out_specs=pl.BlockSpec((query_tile, 1, 1, blk),
+                                       lambda qi, si, idx: (qi, si, 0, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((lut_c.shape[0], s, 1, blk),
+                                           jnp.float32),
+            interpret=interpret,
+        )
+        return kernel(idx, lut_c, block_codes)
+
+    out = _map_query_chunks(call, tile_idx, [lut], query_tile)
+    return out.reshape(b, s, blk)
 
 
 def pq_scan_paged_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
@@ -179,68 +246,76 @@ def _make_topk_kernel(query_tile: int, blk: int, f: int, with_dead: bool,
             (dead_ref, acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref) = rest
         else:
             (acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref) = rest
-        qt, m, k = lut_ref.shape
+        qt = lut_ref.shape[0]
         si = pl.program_id(1)
 
-        # the accumulator blocks map to (qi, 0) for every scan position,
-        # so they stay resident in VMEM across the inner grid dimension;
-        # first visit initializes them to the empty top-F
+        # the accumulator blocks map to (qi, 0, 0) for every scan
+        # position, so they stay resident in VMEM across the inner grid
+        # dimension; first visit initializes them to the empty top-F
         @pl.when(si == 0)
         def _init():
-            acc_d_ref[...] = jnp.full((qt, f), jnp.inf, jnp.float32)
-            acc_pos_ref[...] = jnp.full((qt, f), PAD_POS, jnp.int32)
-            acc_id_ref[...] = jnp.full((qt, f), -1, jnp.int32)
-            dco_ref[...] = jnp.zeros((qt, 1), jnp.int32)
+            acc_d_ref[...] = jnp.full((qt, 1, f), jnp.inf, jnp.float32)
+            acc_pos_ref[...] = jnp.full((qt, 1, f), PAD_POS, jnp.int32)
+            acc_id_ref[...] = jnp.full((qt, 1, f), -1, jnp.int32)
+            dco_ref[...] = jnp.zeros((qt, 1, 1), jnp.int32)
 
-        # -- score the paged block: same one-hot MXU contraction as the
-        # unfused kernel (_make_kernel), so distances are bitwise identical
-        codes = _tile_codes(codes_ref, packed)                 # (BLK, M)
-        onehot = (codes[:, :, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2))
-        oh = onehot.astype(jnp.float32).reshape(blk, m * k)
-        lut = lut_ref[...].reshape(qt, m * k)
-        d = jax.lax.dot_general(lut, oh, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        # -- score the paged block: the unfused kernel's contraction, so
+        # distances are bitwise identical
+        d = _score_block(lut_ref, codes_ref, packed)           # (QT, BLK)
 
         # -- in-kernel keep mask (Alg. 5 L15-16, scan_blocks' post-hoc
         # logic moved here): invalid slots/absent union positions
         # (slot < 0), invalid items (id < 0), and misc duplicates whose
         # co-assigned list was scanned at an earlier probe rank
-        ids = bids_ref[0]                                      # (BLK,)
-        other = bother_ref[0]                                  # (BLK,)
-        slot = slot_ref[...][:, 0]                             # (QT,)
-        ranku = ranku_ref[...][:, 0]                           # (QT,)
-        o = jnp.maximum(other, 0)
-        orank = jnp.take_along_axis(
-            rank_ref[...], jnp.broadcast_to(o[None, :], (qt, blk)), axis=1)
-        dup = (other[None, :] >= 0) & (orank < ranku[:, None])
-        item_ok = (ids[None, :] >= 0) & (slot[:, None] >= 0)
+        ids = bids_ref[0]                                      # (1, BLK)
+        other = bother_ref[0]                                  # (1, BLK)
+        # this scan position's column of the resident (QT, S) sidecars
+        s_lane = jax.lax.broadcasted_iota(jnp.int32, slot_ref.shape, 2)
+        slot = jnp.sum(jnp.where(s_lane == si, slot_ref[...], 0),
+                       axis=2)                                 # (QT, 1)
+        ranku = jnp.sum(jnp.where(s_lane == si, ranku_ref[...], 0), axis=2)
+        # rank_of[q, other[i]]: a lane gather over nlist does not lower,
+        # so contract the rank rows with a one-hot of `other` instead —
+        # exact at HIGHEST (ranks < 2^24, BIG = 2^30)
+        nlist = rank_ref.shape[2]
+        o_hot = (jax.lax.broadcasted_iota(jnp.int32, (nlist, blk), 0)
+                 == jnp.maximum(other, 0)).astype(jnp.float32)
+        orank = jnp.dot(rank_ref[:, 0, :].astype(jnp.float32), o_hot,
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)    # (QT, BLK)
+        dup = (other >= 0) & (orank < ranku.astype(jnp.float32))
+        item_ok = (ids >= 0) & (slot >= 0)
         keep = item_ok & ~dup
         if with_dead:
             # tombstoned candidates must not consume accumulator slots
             # (they are ADC-computed — DCO counts them — then discarded)
-            keep &= dead_ref[0][None, :] == 0
+            keep &= dead_ref[0].astype(jnp.int32) == 0
         dco_ref[...] += jnp.sum(item_ok.astype(jnp.int32), axis=1,
-                                keepdims=True)
+                                keepdims=True)[:, None, :]
 
         # -- candidate triple in plan layout: pos = slot*BLK + lane is the
-        # flat position of the unfused stream, the lax.top_k tie-break
+        # flat position of the unfused stream, the lax.top_k tie-break.
+        # The block is sorted descending so its best F sit at the end,
+        # ready for merge_topf's half-cleaner against the ascending
+        # accumulator
         lane = jax.lax.broadcasted_iota(jnp.int32, (qt, blk), 1)
-        pos = slot[:, None] * blk + lane
+        pos = slot * blk + lane
         new = bitonic_sort([jnp.where(keep, d, jnp.inf),
                             jnp.where(keep, pos, PAD_POS),
-                            jnp.where(keep, ids[None, :], -1)])
+                            jnp.where(keep, ids, -1)], descending=True)
         if blk >= f:
             # candidates beyond a block's own top-F can never survive
-            new = [x[:, :f] for x in new]
+            new = [x[:, blk - f:] for x in new]
         else:
-            pad = ((0, 0), (0, f - blk))
+            pad = ((0, 0), (f - blk, 0))
             new = [jnp.pad(new[0], pad, constant_values=jnp.inf),
                    jnp.pad(new[1], pad, constant_values=PAD_POS),
                    jnp.pad(new[2], pad, constant_values=-1)]
-        acc = merge_topf([acc_d_ref[...], acc_pos_ref[...], acc_id_ref[...]],
-                         new)
-        acc_d_ref[...], acc_pos_ref[...], acc_id_ref[...] = acc
+        acc = merge_topf([acc_d_ref[:, 0, :], acc_pos_ref[:, 0, :],
+                          acc_id_ref[:, 0, :]], new)
+        acc_d_ref[...] = acc[0][:, None, :]
+        acc_pos_ref[...] = acc[1][:, None, :]
+        acc_id_ref[...] = acc[2][:, None, :]
 
     return kernel
 
@@ -273,11 +348,12 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     DCO counter (one per valid item of a planned block, duplicates
     included — exactly ``scan_blocks``' accounting).  The accumulator
     triple lives in VMEM for the whole inner grid pass (out BlockSpecs
-    constant in the scan dimension); each step is one bitonic sort of
-    the block + one bitonic merge against the accumulator (kernels/
-    topk.py), keyed lexicographically by (d, pos) so the result is
-    bitwise the stable ``preselect_candidates`` selection over the
-    unfused stream with masked entries at ``(+inf, PAD_POS, -1)``.
+    constant in the scan dimension), as do the query tile's rank table
+    and (S,) sidecar rows; each step is one bitonic sort of the block +
+    one bitonic merge against the accumulator (kernels/topk.py), keyed
+    lexicographically by (d, pos) so the result is bitwise the stable
+    ``preselect_candidates`` selection over the unfused stream with
+    masked entries at ``(+inf, PAD_POS, -1)``.
     """
     b, m, k = lut.shape
     qb, s = tile_idx.shape
@@ -291,44 +367,57 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     nlist = rank_of.shape[1]
     with_dead = dead is not None
 
-    in_specs = [
-        pl.BlockSpec((query_tile, m, k), lambda qi, si, idx: (qi, 0, 0)),
-        pl.BlockSpec((1, blk, mb), lambda qi, si, idx: (idx[qi, si], 0, 0)),
-        pl.BlockSpec((1, blk), lambda qi, si, idx: (idx[qi, si], 0)),
-        pl.BlockSpec((1, blk), lambda qi, si, idx: (idx[qi, si], 0)),
-        pl.BlockSpec((query_tile, nlist), lambda qi, si, idx: (qi, 0)),
-        pl.BlockSpec((query_tile, 1), lambda qi, si, idx: (qi, si)),
-        pl.BlockSpec((query_tile, 1), lambda qi, si, idx: (qi, si)),
-    ]
-    operands = [lut, block_codes, block_ids.astype(jnp.int32),
-                block_other.astype(jnp.int32), rank_of.astype(jnp.int32),
-                slot_of.astype(jnp.int32), rank_u.astype(jnp.int32)]
-    if with_dead:
-        in_specs.append(
-            pl.BlockSpec((1, blk), lambda qi, si, idx: (idx[qi, si], 0)))
-        operands.append(dead.astype(jnp.uint8))
+    def row(x):   # per-block (TB, BLK) plane -> (TB, 1, BLK)
+        return x.reshape(tb, 1, blk)
 
-    kernel = pl.pallas_call(
-        _make_topk_kernel(query_tile, blk, f, with_dead, packed),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(qb, s),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((query_tile, f), lambda qi, si, idx: (qi, 0)),
-                pl.BlockSpec((query_tile, f), lambda qi, si, idx: (qi, 0)),
-                pl.BlockSpec((query_tile, f), lambda qi, si, idx: (qi, 0)),
-                pl.BlockSpec((query_tile, 1), lambda qi, si, idx: (qi, 0)),
-            ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, f), jnp.float32),
-            jax.ShapeDtypeStruct((b, f), jnp.int32),
-            jax.ShapeDtypeStruct((b, f), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    acc_d, acc_pos, acc_id, dco = kernel(tile_idx.astype(jnp.int32),
-                                         *operands)
-    return (acc_d[:, :fetch], acc_pos[:, :fetch], acc_id[:, :fetch],
-            dco[:, 0])
+    def paged(qi, si, idx):
+        return (idx[qi, si], 0, 0)
+
+    def tile(qi, si, idx):
+        return (qi, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((query_tile, m, k), tile),
+        pl.BlockSpec((1, blk, mb), paged),
+        pl.BlockSpec((1, 1, blk), paged),
+        pl.BlockSpec((1, 1, blk), paged),
+        pl.BlockSpec((query_tile, 1, nlist), tile),
+        pl.BlockSpec((query_tile, 1, s), tile),
+        pl.BlockSpec((query_tile, 1, s), tile),
+    ]
+    blocks = [block_codes, row(block_ids.astype(jnp.int32)),
+              row(block_other.astype(jnp.int32))]
+    per_query = [_even_odd_lut(lut, packed), rank_of.astype(jnp.int32).reshape(b, 1, nlist),
+                 slot_of.astype(jnp.int32).reshape(b, 1, s),
+                 rank_u.astype(jnp.int32).reshape(b, 1, s)]
+    if with_dead:
+        in_specs.append(pl.BlockSpec((1, 1, blk), paged))
+        dead_row = row(dead.astype(jnp.uint8))
+
+    def call(idx, lut_c, rank_c, slot_c, ranku_c):
+        bc = lut_c.shape[0]
+        kernel = pl.pallas_call(
+            _make_topk_kernel(query_tile, blk, f, with_dead, packed),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=idx.shape,
+                in_specs=in_specs,
+                out_specs=[pl.BlockSpec((query_tile, 1, f), tile)] * 3
+                + [pl.BlockSpec((query_tile, 1, 1), tile)]),
+            out_shape=[
+                jax.ShapeDtypeStruct((bc, 1, f), jnp.float32),
+                jax.ShapeDtypeStruct((bc, 1, f), jnp.int32),
+                jax.ShapeDtypeStruct((bc, 1, f), jnp.int32),
+                jax.ShapeDtypeStruct((bc, 1, 1), jnp.int32),
+            ],
+            interpret=interpret,
+        )
+        operands = [lut_c, *blocks, rank_c, slot_c, ranku_c]
+        if with_dead:
+            operands.append(dead_row)
+        return tuple(kernel(idx, *operands))
+
+    acc_d, acc_pos, acc_id, dco = _map_query_chunks(
+        call, tile_idx.astype(jnp.int32), per_query, query_tile)
+    return (acc_d[:, 0, :fetch], acc_pos[:, 0, :fetch], acc_id[:, 0, :fetch],
+            dco[:, 0, 0])

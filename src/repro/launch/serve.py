@@ -74,7 +74,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import (IndexConfig, RefineParams, SearchParams,
                         StreamConfig, StreamingIndex, available_strategies,
                         build_index, dco_summary, ground_truth, load_index,
@@ -154,6 +154,7 @@ def run_gateway(serving, args, q, compact_async: bool = False):
                         degrade=ladder,
                         telemetry_interval_s=args.telemetry_interval)
     sinks = (LogSink(),) if args.telemetry_interval > 0 else ()
+    faults = []
     with Gateway(serving, params, config=cfg, sinks=sinks) as gw:
         for point, qps in enumerate(args.offered_qps):
             handover = None
@@ -177,6 +178,7 @@ def run_gateway(serving, args, q, compact_async: bool = False):
                   f"mean_batch={out['mean_batch']:.1f} "
                   f"shed={out['shed']} levels={out['levels']} "
                   f"errors={out['errors']}")
+            faults += load_point_faults(qps, out)
             if handover is not None:
                 info = handover.wait(300)
                 print(f"  handover installed: epoch={info['epoch']} "
@@ -191,7 +193,22 @@ def run_gateway(serving, args, q, compact_async: bool = False):
               f"counters={tel['counters']}")
         # snapshot while the gateway (and any tracer) is still live so
         # --stats-format can render one unified stack-wide view
-        return obs.snapshot_all(gateway=gw, tracer=obs.tracer())
+        return obs.snapshot_all(gateway=gw, tracer=obs.tracer()), faults
+
+
+def load_point_faults(qps: float, out: dict) -> list:
+    """What went wrong at one load point: untyped dispatch errors, or a
+    broken accounting identity (every submission must resolve as ok,
+    shed, deadline-failed, closed or error)."""
+    faults = []
+    if out["errors"]:
+        faults.append(f"{qps:g} qps: {out['errors']} untyped request errors")
+    accounted = (out["n_ok"] + out["shed"] + out["deadline_failed"]
+                 + out["closed"] + out["errors"])
+    if accounted != out["n_requests"]:
+        faults.append(f"{qps:g} qps: accounted {accounted} of "
+                      f"{out['n_requests']} submitted requests")
+    return faults
 
 
 def main():
@@ -310,6 +327,7 @@ def main():
                          "aggregates) after serving, as pretty JSON or "
                          "Prometheus text exposition")
     args = ap.parse_args()
+    compile_cache.enable()
     try:
         args.offered_qps = [float(v) for v in
                             str(args.offered_qps).split(",") if v]
@@ -411,8 +429,11 @@ def main():
     if args.trace:
         obs.start(sample=args.trace_sample)
     if args.gateway:
-        snap = run_gateway(serving, args, q, compact_async=gateway_handover)
+        snap, faults = run_gateway(serving, args, q,
+                                   compact_async=gateway_handover)
         finish_obs(args, snap)
+        if faults:
+            sys.exit("gateway serving failed: " + "; ".join(faults))
         return
     searcher = serving.searcher(SearchParams(
         k=args.k, nprobe=args.nprobe, max_scan=args.max_scan,

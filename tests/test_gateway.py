@@ -362,3 +362,22 @@ def test_telemetry_observe_atomic_under_threads():
         tm.observe(counters={"responses": 1}, sums={"approx_dco": -1.0})
     after = tm.snapshot()
     assert after["counters"] == before["counters"]
+
+
+@pytest.mark.parametrize("field,delta,faults", [
+    (None, 0, 0),            # healthy point: nothing to report
+    ("errors", 1, 2),        # an untyped error also breaks the identity
+    ("shed", 1, 0),          # typed shedding is accounted, not a fault
+    ("n_ok", -1, 1),         # a lost request breaks the identity
+])
+def test_serve_cli_load_point_faults(field, delta, faults):
+    """The serve CLI exits non-zero on untyped errors or a broken
+    accounting identity (ok+shed+deadline+closed+errors == submitted)."""
+    from repro.launch.serve import load_point_faults
+    out = {"n_requests": 10, "n_ok": 10, "shed": 0, "deadline_failed": 0,
+           "closed": 0, "errors": 0}
+    if field is not None:
+        out[field] += delta
+    if field == "shed":
+        out["n_ok"] -= 1
+    assert len(load_point_faults(100.0, out)) == faults
