@@ -1,14 +1,14 @@
 """Perf-regression gate over the committed BENCH_*.json summaries.
 
 ``PYTHONPATH=src python -m benchmarks.check_regression``            # all
-``... check_regression plan=/tmp/BENCH_plan_unit.json trace=...``   # some
+``... check_regression plan=/tmp/BENCH_plan_unit.json fused=...``   # some
 
 Each committed benchmark summary carries machine-checkable invariants
 — per-stage DCO splits, union-cut ratios, plan reuse rates, modeled
-HBM traffic reductions, id-parity counts, stage-time attribution —
-that hold at ANY scale and on ANY machine.  This gate asserts those,
+HBM traffic reductions, id-parity counts — that hold at ANY scale and
+on ANY machine.  This gate asserts those,
 and deliberately never a wall-clock number: CI runners are noisy, but
-"the fused scan writes >= 4x fewer bytes", "the traced dispatch
+"the fused scan writes >= 4x fewer bytes", "refine_factor=1
 returned identical ids", and "the clustered tile union is a strict cut
 of the batch union" are exact at unit scale and at sift1m alike.
 
@@ -30,7 +30,7 @@ from typing import Callable, Dict
 
 _REPO = os.path.normpath(os.path.join(os.path.dirname(__file__), ".."))
 _SCHEMA_EXPECTED = {"engine": 1, "stream": 1, "dist": 1, "plan": 1,
-                    "fused": 1, "serve": 1, "trace": 1, "refine": 1,
+                    "fused": 1, "serve": 1, "refine": 1,
                     "overload": 1}
 
 
@@ -154,26 +154,6 @@ def check_serve(g: Gate, d: dict) -> None:
             f"{[round(pt.get('speedup', 0), 2) for pt in d.get('points', [])]}")
 
 
-def check_trace(g: Gate, d: dict) -> None:
-    g.check(d.get("traced_id_mismatch_points") == 0,
-            "trace: traced dispatch returns bitwise-identical ids",
-            f"traced_id_mismatch_points="
-            f"{d.get('traced_id_mismatch_points')}")
-    floor = d.get("min_attribution", 0.95)
-    for c in d.get("configs", []):
-        g.check(c.get("stage_attribution", 0) >= floor,
-                f"trace[{c.get('config')}]: stage spans attribute >= "
-                f"{floor:.0%} of dispatch time",
-                f"stage_attribution={c.get('stage_attribution')}")
-        g.check(c.get("fences", 0) > 0 and bool(c.get("dco_per_stage")),
-                f"trace[{c.get('config')}]: device fences + per-stage "
-                f"DCO recorded")
-    m = d.get("hbm_model", {}).get("bytes_per_query", {})
-    g.check(m.get("write_reduction_x", 0) >= 4.0,
-            "trace: session HBM model matches the fused-bench floor",
-            f"write_reduction_x={m.get('write_reduction_x')}")
-
-
 def check_refine(g: Gate, d: dict) -> None:
     g.check(d.get("rf1_id_mismatch_points") == 0,
             "refine: refine_factor=1 is bitwise-identical to single-tier",
@@ -263,7 +243,7 @@ def check_overload(g: Gate, d: dict) -> None:
 _CHECKERS: Dict[str, Callable[[Gate, dict], None]] = {
     "engine": check_engine, "stream": check_stream, "dist": check_dist,
     "plan": check_plan, "fused": check_fused, "serve": check_serve,
-    "trace": check_trace, "refine": check_refine,
+    "refine": check_refine,
     "overload": check_overload,
 }
 

@@ -19,11 +19,7 @@ before the run), and the fused scan->top-k bench with
 plus QPS per exec mode — the CI ``kernel-smoke`` guard), and the
 gateway serving bench with ``BENCH_serve.json`` (deadline-batched vs
 per-request throughput and p50/p99 latency per open-loop offered load
-point — the CI ``gateway-smoke`` guard), and the stage-trace bench
-with ``BENCH_trace.json`` (per-stage wall-time/DCO breakdown from
-tracer spans with >= 95% dispatch-time attribution asserted,
-single-host and sharded — the stage-attributed view of the
-BENCH_dist.json multi-device cliff; DESIGN.md §11), and the two-tier
+point — the CI ``gateway-smoke`` guard), and the two-tier
 quantization-ladder bench with ``BENCH_refine.json`` (backend x
 refine_factor x nprobe sweep: recall and the weighted total-ops model
 vs single-tier, rf=1 bitwise-parity count, and the frontier config —
@@ -60,8 +56,6 @@ FUSED_JSON_DEFAULT = os.path.join(
     os.path.dirname(__file__), "..", "BENCH_fused.json")
 SERVE_JSON_DEFAULT = os.path.join(
     os.path.dirname(__file__), "..", "BENCH_serve.json")
-TRACE_JSON_DEFAULT = os.path.join(
-    os.path.dirname(__file__), "..", "BENCH_trace.json")
 REFINE_JSON_DEFAULT = os.path.join(
     os.path.dirname(__file__), "..", "BENCH_refine.json")
 OVERLOAD_JSON_DEFAULT = os.path.join(
@@ -72,7 +66,6 @@ DIST_JSON_SCHEMA_VERSION = 1
 PLAN_JSON_SCHEMA_VERSION = 1
 FUSED_JSON_SCHEMA_VERSION = 1
 SERVE_JSON_SCHEMA_VERSION = 1
-TRACE_JSON_SCHEMA_VERSION = 1
 REFINE_JSON_SCHEMA_VERSION = 1
 OVERLOAD_JSON_SCHEMA_VERSION = 1
 
@@ -149,17 +142,6 @@ def write_serve_json(serve_out: dict, dataset: str, path: str) -> None:
                         dataset, path)
 
 
-def write_trace_json(trace_out: dict, dataset: str, path: str) -> None:
-    """Persist the stage-trace bench (per-stage time/DCO breakdown and
-    attribution, single-host + sharded — DESIGN.md §11)."""
-    import jax
-    _write_summary_json("trace", TRACE_JSON_SCHEMA_VERSION, {
-        "devices_available": len(jax.devices()),
-        "platform": jax.devices()[0].platform,
-        **trace_out,
-    }, dataset, path)
-
-
 def write_refine_json(refine_out: dict, dataset: str, path: str) -> None:
     """Persist the two-tier quantization-ladder bench (backend x
     refine_factor x nprobe sweep: recall vs modeled total-ops reduction
@@ -199,9 +181,6 @@ def main() -> None:
     ap.add_argument("--serve-json", type=str, default=SERVE_JSON_DEFAULT,
                     help="where the gateway serving bench writes its "
                          "machine-readable summary ('' disables)")
-    ap.add_argument("--trace-json", type=str, default=TRACE_JSON_DEFAULT,
-                    help="where the stage-trace bench writes its machine-"
-                         "readable summary ('' disables)")
     ap.add_argument("--refine-json", type=str, default=REFINE_JSON_DEFAULT,
                     help="where the quantization-ladder bench writes its "
                          "machine-readable summary ('' disables)")
@@ -237,8 +216,6 @@ def main() -> None:
                 write_fused_json(out, args.bench_dataset, args.fused_json)
             if name == "serve" and args.serve_json:
                 write_serve_json(out, args.bench_dataset, args.serve_json)
-            if name == "trace" and args.trace_json:
-                write_trace_json(out, args.bench_dataset, args.trace_json)
             if name == "refine" and args.refine_json:
                 write_refine_json(out, args.bench_dataset, args.refine_json)
             if name == "overload" and args.overload_json:
@@ -284,7 +261,6 @@ def _bench_list(args):
         ("dist", lambda: suite.bench_dist(dataset=args.bench_dataset)),
         ("fused", lambda: suite.bench_fused(dataset=args.bench_dataset)),
         ("serve", lambda: suite.bench_serve(dataset=args.bench_dataset)),
-        ("trace", lambda: suite.bench_trace(dataset=args.bench_dataset)),
         ("refine", lambda: suite.bench_refine(dataset=args.bench_dataset)),
         ("overload",
          lambda: suite.bench_overload(dataset=args.bench_dataset)),

@@ -967,95 +967,6 @@ def bench_refine(dataset="sift1m", k=10, nprobes=(8, 16, 32),
     return out
 
 
-def bench_trace(dataset="sift1m", k=10, nprobe=16, chunk=64,
-                min_attribution=0.95):
-    """Engine-deep trace bench (-> BENCH_trace.json): per-stage wall
-    time and DCO from tracer spans (DESIGN.md §11), single-host and
-    sharded.
-
-    For each config — the plain single-host Searcher and a
-    ``ShardedIndex`` session at ndev=1 and ndev=len(jax.devices()) —
-    the same query stream runs untraced (the reference) and then traced
-    with stage-boundary fencing, asserting bitwise-identical ids
-    (fencing changes when the host observes values, never the values)
-    and that >= ``min_attribution`` of end-to-end dispatch wall time
-    lands in named ``stage.*`` spans.  Under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` this commits
-    the first stage-attributed breakdown of the BENCH_dist.json
-    multi-device QPS cliff: the per-shard scan and the gather/merge
-    tail separately timed per dispatch.
-    """
-    from jax.sharding import Mesh
-
-    from repro import obs
-    from repro.core import SearchParams
-
-    ctx = get_context(dataset, n_queries=256)
-    idx = ctx.index("rair", True)
-    max_scan = idx.default_max_scan(nprobe)
-    params = SearchParams(k=k, nprobe=nprobe, max_scan=max_scan,
-                          batch_buckets=(chunk,))
-    devs = jax.devices()
-    sessions = [("host", 0, idx.searcher(params))]
-    for nd in sorted({1, len(devs)}):
-        mesh = Mesh(np.asarray(devs[:nd]), ("data",))
-        sessions.append(
-            (f"sharded_ndev{nd}", nd, idx.shard(mesh).searcher(params)))
-
-    def run_all(searcher):
-        t0 = time.perf_counter()
-        outs = [jax.tree.map(np.asarray, searcher(ctx.q[s:s + chunk]))
-                for s in range(0, ctx.q.shape[0], chunk)]
-        us = (time.perf_counter() - t0) / ctx.q.shape[0] * 1e6
-        return jax.tree.map(lambda *a: np.concatenate(a, 0), *outs), us
-
-    rows, mismatches = [], 0
-    for name, nd, searcher in sessions:
-        run_all(searcher)                       # compile the untraced path
-        ref, us_ref = run_all(searcher)
-        with obs.trace():
-            run_all(searcher)                   # compile the traced stages
-        with obs.trace() as tr:
-            res, us_tr = run_all(searcher)
-        mismatches += not np.array_equal(ref.ids, res.ids)
-        trace = obs.snapshot_all(searcher=searcher, tracer=tr)["trace"]
-        summary = tr.stage_summary()
-        disp_s = summary["searcher.dispatch"]["total_s"]
-        stages = {
-            n: {"count": v["count"], "total_ms": v["total_s"] * 1e3,
-                "share_of_dispatch": v["total_s"] / disp_s,
-                **({"counters": v["counters"]} if v["counters"] else {})}
-            for n, v in sorted(summary.items()) if n.startswith("stage.")}
-        rows.append({
-            "config": name, "ndev": nd,
-            "stage_attribution": trace["stage_attribution"],
-            "us_per_query_untraced": us_ref,
-            "us_per_query_traced": us_tr,
-            "traced_over_untraced": us_tr / us_ref,
-            "fences": trace["fences"],
-            "stages": stages,
-            "dco_per_stage": trace.get("dco", {}),
-        })
-        emit(f"trace/{dataset}/{name}", us_tr,
-             f"attribution={rows[-1]['stage_attribution']:.4f} "
-             f"stages={len(stages)} fences={trace['fences']} "
-             f"traced_overhead={us_tr / us_ref:.2f}x")
-    out = {"k": k, "nprobe": nprobe, "max_scan": max_scan, "chunk": chunk,
-           "min_attribution": min_attribution,
-           "traced_id_mismatch_points": mismatches,
-           "hbm_model": obs.session_traffic_model(sessions[0][2]),
-           "configs": rows}
-    save_json("trace_stages", out)
-    assert mismatches == 0, \
-        "traced dispatch must return bitwise-identical ids"
-    bad = [(r["config"], r["stage_attribution"]) for r in rows
-           if r["stage_attribution"] < min_attribution]
-    assert not bad, (
-        f"stage spans attribute < {min_attribution:.0%} of dispatch wall "
-        f"time at {bad} — unattributed host work crept into dispatch")
-    return out
-
-
 def bench_serve(dataset="sift1m", k=10, nprobe=4, max_scan=16,
                 load_factors=(1.5, 20.0), n_requests=384,
                 max_batch=32, max_delay_ms=2.0):

@@ -86,69 +86,73 @@ def build_serve_step(*, nprobe: int, bigk: int, k: int, max_scan_local: int,
                   queries):
         # -- replicated control path: list selection + dedup + local plan
         # (identical on every device; no collective needed)
-        selection = select_lists(queries, centroids, nprobe=nprobe,
-                                 metric=metric)
-        tables = ListTables(owned=owned, owned_other=owned_other, refs=refs,
-                            refs_other=refs_other, misc=misc)
-        plan = plan_blocks(tables, selection, max_scan=max_scan_local,
-                           local_lo=block_lo[0],
-                           local_count=block_ids.shape[0])
+        with jax.named_scope("select_lists"):
+            selection = select_lists(queries, centroids, nprobe=nprobe,
+                                     metric=metric)
+        with jax.named_scope("plan_blocks"):
+            tables = ListTables(owned=owned, owned_other=owned_other,
+                                refs=refs, refs_other=refs_other, misc=misc)
+            plan = plan_blocks(tables, selection, max_scan=max_scan_local,
+                               local_lo=block_lo[0],
+                               local_count=block_ids.shape[0])
+            cb = PQCodebook(codebooks)
+            lut = (pq_lut(cb, queries) if metric == "l2"
+                   else pq_lut_ip(cb, queries))
+        with jax.named_scope("scan"):
+            # -- local ADC scan over the device's block shard
+            store = BlockStore(block_codes=block_codes,
+                               block_ids=block_ids,
+                               block_other=block_other)
+            # sel feeds the clustered exec mode: the cluster order is derived
+            # from the replicated selection, so every device permutes its
+            # (locally windowed) plan identically — per-device plans ride the
+            # same clustering with their own per-tile local unions
+            if fused_topk:
+                # the fused scan's width-fetch output IS the per-device
+                # preselect — tombstones applied pre-selection via ``live``
+                scan = scan_blocks_topk(
+                    store, plan, lut, selection.rank_of, fetch=fetch,
+                    exec_mode=exec_mode, use_kernel=use_kernel,
+                    query_tile=query_tile, sel=selection.sel,
+                    live=live if streaming else None, packed=packed_codes)
+            else:
+                scan = scan_blocks(store, plan, lut, selection.rank_of,
+                                   exec_mode=exec_mode, use_kernel=use_kernel,
+                                   query_tile=query_tile, sel=selection.sel,
+                                   packed=packed_codes)
+            flat_d, flat_i = scan.flat_d, scan.flat_i
+            approx_dco = scan.approx_dco
 
-        # -- local ADC scan over the device's block shard (either mode)
-        cb = PQCodebook(codebooks)
-        lut = pq_lut(cb, queries) if metric == "l2" else pq_lut_ip(cb, queries)
-        store = BlockStore(block_codes=block_codes, block_ids=block_ids,
-                           block_other=block_other)
-        # sel feeds the clustered exec mode: the cluster order is derived
-        # from the replicated selection, so every device permutes its
-        # (locally windowed) plan identically — per-device plans ride the
-        # same clustering with their own per-tile local unions
-        if fused_topk:
-            # the fused scan's width-fetch output IS the per-device
-            # preselect — tombstones applied pre-selection via ``live``
-            scan = scan_blocks_topk(
-                store, plan, lut, selection.rank_of, fetch=fetch,
-                exec_mode=exec_mode, use_kernel=use_kernel,
-                query_tile=query_tile, sel=selection.sel,
-                live=live if streaming else None, packed=packed_codes)
-        else:
-            scan = scan_blocks(store, plan, lut, selection.rank_of,
-                               exec_mode=exec_mode, use_kernel=use_kernel,
-                               query_tile=query_tile, sel=selection.sel,
-                               packed=packed_codes)
-        flat_d, flat_i = scan.flat_d, scan.flat_i
-        approx_dco = scan.approx_dco
+            if streaming:
+                # delta scanned on every device (replicated compute, no extra
+                # collective) but each slot has one owner (slot % ndev) so the
+                # gathered candidate stream holds each delta id exactly once
+                # — and logical DCO is counted exactly once per live slot.
+                cap = delta_ids.shape[0]
+                alive = delta_ids >= 0
+                mine = alive & ((jnp.arange(cap, dtype=jnp.int32) % ndev)
+                                == dev_rank[0])
+                dd = jnp.where(mine[None, :], delta_adc(lut, delta_codes),
+                               jnp.inf)
+                di = jnp.broadcast_to(delta_ids[None, :], dd.shape)
+                flat_d = jnp.concatenate([flat_d, dd], axis=1)
+                flat_i = jnp.concatenate([flat_i, di], axis=1)
+                # tombstone mask over the whole id space, replicated (the
+                # fused base stream is already live-masked; re-masking it
+                # here is idempotent, and the delta needs it either way)
+                dead = (flat_i >= 0) & ~live[jnp.maximum(flat_i, 0)]
+                flat_d = jnp.where(dead, jnp.inf, flat_d)
+                approx_dco = approx_dco + jnp.sum(mine).astype(jnp.int32)
 
-        if streaming:
-            # delta scanned on every device (replicated compute, no extra
-            # collective) but each slot has one owner (slot % ndev) so the
-            # gathered candidate stream holds each delta id exactly once
-            # — and logical DCO is counted exactly once per live slot.
-            cap = delta_ids.shape[0]
-            alive = delta_ids >= 0
-            mine = alive & ((jnp.arange(cap, dtype=jnp.int32) % ndev)
-                            == dev_rank[0])
-            dd = jnp.where(mine[None, :], delta_adc(lut, delta_codes),
-                           jnp.inf)
-            di = jnp.broadcast_to(delta_ids[None, :], dd.shape)
-            flat_d = jnp.concatenate([flat_d, dd], axis=1)
-            flat_i = jnp.concatenate([flat_i, di], axis=1)
-            # tombstone mask over the whole id space, replicated (the
-            # fused base stream is already live-masked; re-masking it
-            # here is idempotent, and the delta needs it either way)
-            dead = (flat_i >= 0) & ~live[jnp.maximum(flat_i, 0)]
-            flat_d = jnp.where(dead, jnp.inf, flat_d)
-            approx_dco = approx_dco + jnp.sum(mine).astype(jnp.int32)
-
-        # -- collective 1 (first half): local stable top-fetch.  (With
-        # fused_topk + no streaming merge the stream is already the
-        # stable top-fetch; the preselect is then a width-preserving
-        # stable sort, harmless and shape-identical.)
-        l_d, l_ids = preselect_candidates(flat_d, flat_i, fetch=fetch)
-        return (l_d, l_ids,
-                jax.lax.psum(approx_dco, axes),
-                jax.lax.psum(scan.scanned_blocks, axes),
-                jax.lax.psum(plan.dropped, axes))
+            # -- collective 1 (first half): local stable top-fetch.  (With
+            # fused_topk + no streaming merge the stream is already the
+            # stable top-fetch; the preselect is then a width-preserving
+            # stable sort, harmless and shape-identical.)
+            l_d, l_ids = preselect_candidates(flat_d, flat_i, fetch=fetch)
+            return (l_d, l_ids,
+                    jax.lax.psum(approx_dco, axes),
+                    jax.lax.psum(scan.scanned_blocks, axes),
+                    jax.lax.psum(plan.dropped, axes))
 
     def tail_half(vectors, vec_lo, queries, l_d, l_ids):
         # -- collective 1 (second half): all_gather the candidate streams
@@ -156,10 +160,11 @@ def build_serve_step(*, nprobe: int, bigk: int, k: int, max_scan_local: int,
         g_ids = jax.lax.all_gather(l_ids, axes, axis=1, tiled=True)
         # -- shared finalize tail; collective 2: pmin of owner-scored
         # exact distances (vec_lo windows the row shard)
-        return finalize_candidates(
-            g_d, g_ids, bigk=bigk, k=k, vectors=vectors, queries=queries,
-            metric=metric, dedup_results=dedup_results,
-            oversample=oversample, vec_lo=vec_lo[0], reduce_axes=axes)
+        with jax.named_scope("finalize"):
+            return finalize_candidates(
+                g_d, g_ids, bigk=bigk, k=k, vectors=vectors, queries=queries,
+                metric=metric, dedup_results=dedup_results,
+                oversample=oversample, vec_lo=vec_lo[0], reduce_axes=axes)
 
     if stage == "scan":
         return scan_half

@@ -90,26 +90,32 @@ def seil_search(
     fused_topk: bool = False,
     packed_codes: bool = False,   # arrays carry a nibble-packed quant plane
 ) -> SearchResult:
-    selection = select_lists(queries, centroids, nprobe=nprobe, metric=metric)
-    plan = plan_blocks(tables_from_arrays(arrays), selection,
-                       max_scan=max_scan)
-    lut = (pq_lut(codebook, queries) if metric == "l2"
-           else pq_lut_ip(codebook, queries))                # (B, M, 16)
-    if fused_topk:
-        scan = scan_blocks_topk(
-            store_from_arrays(arrays), plan, lut, selection.rank_of,
-            fetch=finalize_fetch(bigk, oversample, dedup_results),
-            exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, sel=selection.sel, packed=packed_codes)
-    else:
-        scan = scan_blocks(store_from_arrays(arrays), plan, lut,
-                           selection.rank_of, exec_mode=exec_mode,
-                           use_kernel=use_kernel, query_tile=query_tile,
-                           sel=selection.sel, packed=packed_codes)
-    out_ids, out_d, refine_dco = finalize_candidates(
-        scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
-        queries=queries, metric=metric, dedup_results=dedup_results,
-        oversample=oversample)
+    with jax.named_scope("select_lists"):
+        selection = select_lists(queries, centroids, nprobe=nprobe,
+                                 metric=metric)
+    with jax.named_scope("plan_blocks"):
+        plan = plan_blocks(tables_from_arrays(arrays), selection,
+                           max_scan=max_scan)
+        lut = (pq_lut(codebook, queries) if metric == "l2"
+               else pq_lut_ip(codebook, queries))            # (B, M, 16)
+    with jax.named_scope("scan"):
+        if fused_topk:
+            scan = scan_blocks_topk(
+                store_from_arrays(arrays), plan, lut, selection.rank_of,
+                fetch=finalize_fetch(bigk, oversample, dedup_results),
+                exec_mode=exec_mode, use_kernel=use_kernel,
+                query_tile=query_tile, sel=selection.sel,
+                packed=packed_codes)
+        else:
+            scan = scan_blocks(store_from_arrays(arrays), plan, lut,
+                               selection.rank_of, exec_mode=exec_mode,
+                               use_kernel=use_kernel, query_tile=query_tile,
+                               sel=selection.sel, packed=packed_codes)
+    with jax.named_scope("finalize"):
+        out_ids, out_d, refine_dco = finalize_candidates(
+            scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
+            queries=queries, metric=metric, dedup_results=dedup_results,
+            oversample=oversample)
     return SearchResult(
         ids=out_ids, dists=out_d, approx_dco=scan.approx_dco,
         refine_dco=refine_dco, scanned_blocks=scan.scanned_blocks,
@@ -254,18 +260,21 @@ def probe_plan(
 ) -> PlanProbe:
     """Stages 1-2 + cluster order + this batch's own tile unions."""
     b = queries.shape[0]
-    selection = select_lists(queries, centroids, nprobe=nprobe, metric=metric)
-    plan = plan_blocks(tables_from_arrays(arrays), selection,
-                       max_scan=max_scan)
-    lut = (pq_lut(codebook, queries) if metric == "l2"
-           else pq_lut_ip(codebook, queries))
-    if exec_mode == "clustered":
-        perm = cluster_order(selection.sel)
-    else:
-        perm = jnp.arange(b, dtype=jnp.int32)
-    t, w = union_dims(b, plan.blocks.shape[1],
-                      arrays.block_codes.shape[0], exec_mode, query_tile)
-    unions = tile_unions(plan.blocks[perm], plan.valid[perm], t, w)
+    with jax.named_scope("select_lists"):
+        selection = select_lists(queries, centroids, nprobe=nprobe,
+                                 metric=metric)
+    with jax.named_scope("plan_blocks"):
+        plan = plan_blocks(tables_from_arrays(arrays), selection,
+                           max_scan=max_scan)
+        lut = (pq_lut(codebook, queries) if metric == "l2"
+               else pq_lut_ip(codebook, queries))
+        if exec_mode == "clustered":
+            perm = cluster_order(selection.sel)
+        else:
+            perm = jnp.arange(b, dtype=jnp.int32)
+        t, w = union_dims(b, plan.blocks.shape[1],
+                          arrays.block_codes.shape[0], exec_mode, query_tile)
+        unions = tile_unions(plan.blocks[perm], plan.valid[perm], t, w)
     return PlanProbe(sel=selection.sel, rank_of=selection.rank_of, lut=lut,
                      plan=plan, perm=perm, unions=unions)
 
@@ -294,23 +303,26 @@ def scan_finalize(
     packed_codes: bool = False,
 ) -> SearchResult:
     """Stages 3-4 against caller-provided (possibly reused) unions."""
-    if fused_topk:
-        scan = scan_blocks_topk(
-            store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
-            fetch=finalize_fetch(bigk, oversample, dedup_results),
-            exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, perm=probe.perm, unions=unions,
-            packed=packed_codes)
-    else:
-        scan = scan_blocks(store_from_arrays(arrays), probe.plan, probe.lut,
-                           probe.rank_of, exec_mode=exec_mode,
-                           use_kernel=use_kernel, query_tile=query_tile,
-                           perm=probe.perm, unions=unions,
-                           packed=packed_codes)
-    out_ids, out_d, refine_dco = finalize_candidates(
-        scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
-        queries=queries, metric=metric, dedup_results=dedup_results,
-        oversample=oversample)
+    with jax.named_scope("scan"):
+        if fused_topk:
+            scan = scan_blocks_topk(
+                store_from_arrays(arrays), probe.plan, probe.lut,
+                probe.rank_of,
+                fetch=finalize_fetch(bigk, oversample, dedup_results),
+                exec_mode=exec_mode, use_kernel=use_kernel,
+                query_tile=query_tile, perm=probe.perm, unions=unions,
+                packed=packed_codes)
+        else:
+            scan = scan_blocks(store_from_arrays(arrays), probe.plan,
+                               probe.lut, probe.rank_of, exec_mode=exec_mode,
+                               use_kernel=use_kernel, query_tile=query_tile,
+                               perm=probe.perm, unions=unions,
+                               packed=packed_codes)
+    with jax.named_scope("finalize"):
+        out_ids, out_d, refine_dco = finalize_candidates(
+            scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
+            queries=queries, metric=metric, dedup_results=dedup_results,
+            oversample=oversample)
     return SearchResult(
         ids=out_ids, dists=out_d, approx_dco=scan.approx_dco,
         refine_dco=refine_dco, scanned_blocks=scan.scanned_blocks,

@@ -258,13 +258,14 @@ class Searcher:
 
     def _dispatch(self, bucket: int, qc: jnp.ndarray) -> SearchResult:
         """One padded chunk through either the monolithic executable or
-        the incremental probe -> merge -> scan pipeline.  With a tracer
-        active (repro/obs/) the monolithic path reroutes through the
-        stage-fenced ``_dispatch_traced`` and the plan_reuse path fences
-        its (already natural) probe / host-merge / scan boundaries."""
+        the incremental probe -> merge -> scan pipeline.  With a fenced
+        tracer active (repro/obs/) the monolithic path reroutes through
+        the stage-fenced ``_dispatch_traced`` and the plan_reuse path
+        fences its (already natural) probe / host-merge / scan
+        boundaries; a profiler-mode tracer changes neither."""
         self.stats.dispatches += 1
         if not self.params.plan_reuse:
-            if obs.enabled():
+            if obs.fencing():
                 r = self._dispatch_traced(bucket, qc)
                 if r is not NotImplemented:
                     return r
@@ -406,13 +407,16 @@ class Searcher:
                           bucket=bucket, rows=b, pad=bucket - b):
                 qc = q[s:s + b]
                 if b < bucket:
-                    qc = jnp.concatenate(
-                        [qc, jnp.zeros((bucket - b, q.shape[1]), q.dtype)],
-                        axis=0)
+                    with obs.span("searcher.pad", cat="searcher"):
+                        qc = jnp.concatenate(
+                            [qc, jnp.zeros((bucket - b, q.shape[1]),
+                                           q.dtype)], axis=0)
                     self.stats.padded_rows += bucket - b
-                r = self._dispatch(bucket, qc)
+                with obs.span("searcher.execute", cat="searcher"):
+                    r = self._dispatch(bucket, qc)
                 if b < bucket:
-                    r = jax.tree.map(lambda a: a[:b], r)
+                    with obs.span("searcher.slice", cat="searcher"):
+                        r = jax.tree.map(lambda a: a[:b], r)
             outs.append(r)
             s += b
         self.stats.calls += 1

@@ -146,32 +146,38 @@ def streaming_search(
     fused_topk: bool = False,
     packed_codes: bool = False,   # arrays carry a nibble-packed quant plane
 ) -> SearchResult:
-    selection = select_lists(queries, centroids, nprobe=nprobe, metric=metric)
-    plan = plan_blocks(tables_from_arrays(arrays), selection,
-                       max_scan=max_scan)
-    lut = (pq_lut(codebook, queries) if metric == "l2"
-           else pq_lut_ip(codebook, queries))                # (B, M, 16)
-    if fused_topk:
-        # live is applied pre-selection so tombstoned base candidates
-        # cannot occupy top-fetch slots; finalize's re-mask is idempotent
-        scan = scan_blocks_topk(
-            store_from_arrays(arrays), plan, lut, selection.rank_of,
-            fetch=finalize_fetch(bigk, oversample, dedup_results),
-            exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, sel=selection.sel, live=live,
-            packed=packed_codes)
-    else:
-        scan = scan_blocks(store_from_arrays(arrays), plan, lut,
-                           selection.rank_of, exec_mode=exec_mode,
-                           use_kernel=use_kernel, query_tile=query_tile,
-                           sel=selection.sel, packed=packed_codes)
-    dd, di, delta_dco = _delta_candidates(
-        lut, delta_codes, delta_ids, delta_post, delta_assigns,
-        selection.sel, selection.rank_of, route_delta)
-    out_ids, out_d, refine_dco = finalize_candidates(
-        scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
-        queries=queries, metric=metric, dedup_results=dedup_results,
-        oversample=oversample, extra_d=dd, extra_i=di, live=live)
+    with jax.named_scope("select_lists"):
+        selection = select_lists(queries, centroids, nprobe=nprobe,
+                                 metric=metric)
+    with jax.named_scope("plan_blocks"):
+        plan = plan_blocks(tables_from_arrays(arrays), selection,
+                           max_scan=max_scan)
+        lut = (pq_lut(codebook, queries) if metric == "l2"
+               else pq_lut_ip(codebook, queries))            # (B, M, 16)
+    with jax.named_scope("scan"):
+        if fused_topk:
+            # live is applied pre-selection so tombstoned base candidates
+            # cannot occupy top-fetch slots; finalize's re-mask is
+            # idempotent
+            scan = scan_blocks_topk(
+                store_from_arrays(arrays), plan, lut, selection.rank_of,
+                fetch=finalize_fetch(bigk, oversample, dedup_results),
+                exec_mode=exec_mode, use_kernel=use_kernel,
+                query_tile=query_tile, sel=selection.sel, live=live,
+                packed=packed_codes)
+        else:
+            scan = scan_blocks(store_from_arrays(arrays), plan, lut,
+                               selection.rank_of, exec_mode=exec_mode,
+                               use_kernel=use_kernel, query_tile=query_tile,
+                               sel=selection.sel, packed=packed_codes)
+        dd, di, delta_dco = _delta_candidates(
+            lut, delta_codes, delta_ids, delta_post, delta_assigns,
+            selection.sel, selection.rank_of, route_delta)
+    with jax.named_scope("finalize"):
+        out_ids, out_d, refine_dco = finalize_candidates(
+            scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
+            queries=queries, metric=metric, dedup_results=dedup_results,
+            oversample=oversample, extra_d=dd, extra_i=di, live=live)
     return SearchResult(
         ids=out_ids, dists=out_d, approx_dco=scan.approx_dco + delta_dco,
         refine_dco=refine_dco, scanned_blocks=scan.scanned_blocks,
@@ -281,26 +287,29 @@ def scan_finalize_stream(
     """Streaming stages 3-4 against caller-provided (reused) unions —
     the probe half is the base ``probe_plan`` (the delta needs no block
     planning), so incremental plans compose with churn unchanged."""
-    if fused_topk:
-        scan = scan_blocks_topk(
-            store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
-            fetch=finalize_fetch(bigk, oversample, dedup_results),
-            exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, perm=probe.perm, unions=unions,
-            live=live, packed=packed_codes)
-    else:
-        scan = scan_blocks(store_from_arrays(arrays), probe.plan, probe.lut,
-                           probe.rank_of, exec_mode=exec_mode,
-                           use_kernel=use_kernel, query_tile=query_tile,
-                           perm=probe.perm, unions=unions,
-                           packed=packed_codes)
-    dd, di, delta_dco = _delta_candidates(
-        probe.lut, delta_codes, delta_ids, delta_post, delta_assigns,
-        probe.sel, probe.rank_of, route_delta)
-    out_ids, out_d, refine_dco = finalize_candidates(
-        scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
-        queries=queries, metric=metric, dedup_results=dedup_results,
-        oversample=oversample, extra_d=dd, extra_i=di, live=live)
+    with jax.named_scope("scan"):
+        if fused_topk:
+            scan = scan_blocks_topk(
+                store_from_arrays(arrays), probe.plan, probe.lut,
+                probe.rank_of,
+                fetch=finalize_fetch(bigk, oversample, dedup_results),
+                exec_mode=exec_mode, use_kernel=use_kernel,
+                query_tile=query_tile, perm=probe.perm, unions=unions,
+                live=live, packed=packed_codes)
+        else:
+            scan = scan_blocks(store_from_arrays(arrays), probe.plan,
+                               probe.lut, probe.rank_of, exec_mode=exec_mode,
+                               use_kernel=use_kernel, query_tile=query_tile,
+                               perm=probe.perm, unions=unions,
+                               packed=packed_codes)
+        dd, di, delta_dco = _delta_candidates(
+            probe.lut, delta_codes, delta_ids, delta_post, delta_assigns,
+            probe.sel, probe.rank_of, route_delta)
+    with jax.named_scope("finalize"):
+        out_ids, out_d, refine_dco = finalize_candidates(
+            scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
+            queries=queries, metric=metric, dedup_results=dedup_results,
+            oversample=oversample, extra_d=dd, extra_i=di, live=live)
     return SearchResult(
         ids=out_ids, dists=out_d, approx_dco=scan.approx_dco + delta_dco,
         refine_dco=refine_dco, scanned_blocks=scan.scanned_blocks,

@@ -522,10 +522,13 @@ class Gateway:
                 due = self.queue.oldest_flush_at(
                     self.config.max_delay_ms / 1e3)
                 if due is None:
-                    self.queue.wait_for_work(0.05)   # idle tick
+                    with obs.span("gateway.wait", cat="gateway"):
+                        self.queue.wait_for_work(0.05)   # idle tick
                     continue
                 if not self._closed.is_set():        # draining flushes now
-                    self.queue.wait_for_flush(self.config.max_batch, due)
+                    with obs.span("gateway.wait", cat="gateway"):
+                        self.queue.wait_for_flush(self.config.max_batch,
+                                                  due)
                 self._adjust_level()
                 batch = self.queue.take_batch(self.config.max_batch)
                 if batch:
@@ -615,63 +618,71 @@ class Gateway:
         level = self._level
         with obs.span("gateway.flush", cat="gateway",
                       batch=len(batch)) as fsp:
-            q = np.stack([r.query for r in batch])
+            with obs.span("gateway.stack", cat="gateway"):
+                q = np.stack([r.query for r in batch])
             try:
                 faults.injected("gateway.dispatch")
                 with self._lock:
                     res, epoch = self._search_locked(q)
-                    ids = np.asarray(res.ids)
-                    if self._is_stream:
-                        # responses carry stable external ids so clients
-                        # survive epoch handovers (resolve_ids maps back)
-                        ids = self.index.external_ids(ids)
-                    else:
-                        ids = ids.astype(np.int64)
-                    dists = np.asarray(res.dists)
-                    approx = float(np.sum(np.asarray(res.approx_dco)))
-                    refine = float(np.sum(np.asarray(res.refine_dco)))
+                    with obs.span("gateway.fetch", cat="gateway"):
+                        ids = np.asarray(res.ids)
+                        if self._is_stream:
+                            # responses carry stable external ids so
+                            # clients survive epoch handovers
+                            # (resolve_ids maps back)
+                            ids = self.index.external_ids(ids)
+                        else:
+                            ids = ids.astype(np.int64)
+                        dists = np.asarray(res.dists)
+                        approx = float(np.sum(np.asarray(res.approx_dco)))
+                        refine = float(np.sum(np.asarray(res.refine_dco)))
             except BaseException as e:
                 tm.inc("errors", len(batch))
                 for r in batch:
                     r._fail(e)
                 return
             fsp.add(approx_dco=approx, refine_dco=refine)
-        t_done = time.perf_counter()
-        counters = {
-            "batches": 1,
-            "responses": len(batch),
-            "bucket_rows": self.params.bucket_for(
-                min(len(batch), self.params.max_chunk)),
-        }
-        if len(self._ladder) > 1:
-            counters[f"responses_level_{level}"] = len(batch)
-        # one atomic multi-metric update per dispatch: a snapshot racing
-        # this sees the batch fully counted or not at all, so derived
-        # cross-metric invariants (latency.count == responses) are exact
-        tm.observe(
-            counters=counters,
-            sums={"approx_dco": approx, "refine_dco": refine,
-                  "result_slots": float(ids.size),
-                  "result_filled": float((ids >= 0).sum())},
-            # exact top-1 distances are signed under the ip metric
-            # (finalize scores are negated inner products) — not monotone
-            signed={"top1_dist": float(dists[:, 0].sum())},
-            latencies=[(tm.dispatch, t_done - t_take)]
-                      + [(tm.latency, t_done - r.t_enqueue)
-                         for r in batch])
-        tr = obs.tracer()
-        for i, r in enumerate(batch):
-            if tr is not None and tr.sampled():
-                # one exemplar complete-event per sampled request,
-                # spanning enqueue -> fulfill on a virtual request track
-                tr.event("gateway.request", r.t_enqueue,
-                         t_done - r.t_enqueue,
-                         queued_ms=(t_take - r.t_enqueue) * 1e3,
-                         batch=len(batch), epoch=epoch)
-            r._fulfill(RequestResult(
-                ids=ids[i], dists=dists[i], latency_s=t_done - r.t_enqueue,
-                queued_s=t_take - r.t_enqueue, batch=len(batch),
-                epoch=epoch, level=level))
+            t_done = time.perf_counter()
+            with obs.span("gateway.fulfill", cat="gateway"):
+                counters = {
+                    "batches": 1,
+                    "responses": len(batch),
+                    "bucket_rows": self.params.bucket_for(
+                        min(len(batch), self.params.max_chunk)),
+                }
+                if len(self._ladder) > 1:
+                    counters[f"responses_level_{level}"] = len(batch)
+                # one atomic multi-metric update per dispatch: a snapshot
+                # racing this sees the batch fully counted or not at all,
+                # so derived cross-metric invariants (latency.count ==
+                # responses) are exact
+                tm.observe(
+                    counters=counters,
+                    sums={"approx_dco": approx, "refine_dco": refine,
+                          "result_slots": float(ids.size),
+                          "result_filled": float((ids >= 0).sum())},
+                    # exact top-1 distances are signed under the ip
+                    # metric (finalize scores are negated inner
+                    # products) — not monotone
+                    signed={"top1_dist": float(dists[:, 0].sum())},
+                    latencies=[(tm.dispatch, t_done - t_take)]
+                              + [(tm.latency, t_done - r.t_enqueue)
+                                 for r in batch])
+                tr = obs.tracer()
+                for i, r in enumerate(batch):
+                    if tr is not None and tr.sampled():
+                        # one exemplar complete-event per sampled
+                        # request, spanning enqueue -> fulfill on a
+                        # virtual request track
+                        tr.event("gateway.request", r.t_enqueue,
+                                 t_done - r.t_enqueue,
+                                 queued_ms=(t_take - r.t_enqueue) * 1e3,
+                                 batch=len(batch), epoch=epoch)
+                    r._fulfill(RequestResult(
+                        ids=ids[i], dists=dists[i],
+                        latency_s=t_done - r.t_enqueue,
+                        queued_s=t_take - r.t_enqueue, batch=len(batch),
+                        epoch=epoch, level=level))
 
     def _search_locked(self, q: np.ndarray):
         """Dispatch through the current session; a session staled by an

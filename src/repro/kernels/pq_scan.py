@@ -188,6 +188,7 @@ def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
             out_shape=jax.ShapeDtypeStruct((lut_c.shape[0], s, 1, blk),
                                            jnp.float32),
             interpret=interpret,
+            name="pq_scan",
         )
         return kernel(idx, lut_c, block_codes)
 
@@ -411,6 +412,7 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
                 jax.ShapeDtypeStruct((bc, 1, 1), jnp.int32),
             ],
             interpret=interpret,
+            name="pq_scan_topk",
         )
         operands = [lut_c, *blocks, rank_c, slot_c, ranku_c]
         if with_dead:

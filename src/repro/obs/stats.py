@@ -111,7 +111,8 @@ def _trace_section(tracer: Tracer) -> Dict[str, Any]:
     }
     if disp and disp["total_s"] > 0:
         # fraction of end-to-end dispatch wall time attributed to named
-        # engine stages — the bench_trace acceptance metric
+        # engine stages (fenced mode; near 0 in profiler mode, where the
+        # stages run inside one executable)
         section["stage_attribution"] = stage_s / disp["total_s"]
     # per-stage DCO: the delta-vs-base scan split plus refine, straight
     # from span counters

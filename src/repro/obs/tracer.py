@@ -25,9 +25,19 @@ identical to untraced ones (asserted).
 ``Tracer.event`` records cross-thread exemplar events (e.g. one span
 per sampled gateway request, spanning enqueue→fulfill) on virtual
 request tracks; these carry no nesting contract.
+
+Profiler mode (``start(profiler=True)``) is the mode for measuring
+speed.  Each span also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so a JAX profiler capture taken meanwhile holds the spans on
+its own clock, on the thread that ran them, beside the device's ops.
+``fence()`` does nothing and sessions keep their production
+executables: the traced program is the served one.  While a
+profiler-mode tracer is active a ``gc.callbacks`` hook records every
+garbage collection as a ``python.gc`` span.
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -68,7 +78,7 @@ _NOOP = _NoopSpan()
 class _Span:
     """A live span; created by ``Tracer.span`` and recorded on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "t0", "depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0", "depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -78,6 +88,7 @@ class _Span:
         self.args = args
         self.t0 = 0.0
         self.depth = 0
+        self._ann = None
 
     def add(self, **counters) -> "_Span":
         """Attach counters to the span (merged into its args)."""
@@ -88,11 +99,16 @@ class _Span:
         stack = self._tracer._stack()
         self.depth = len(stack)
         stack.append(self)
+        if self._tracer.profiler:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._tracer._stack().pop()
         self._tracer._record(self.name, self.cat, threading.get_ident(),
                              self.t0, t1 - self.t0, self.depth, self.args,
@@ -105,22 +121,49 @@ class Tracer:
 
     ``sample`` thins exemplar events (``sampled()`` is true once every
     ``sample`` calls); ``max_events`` bounds memory — past it, records
-    are counted in ``dropped`` instead of stored.
+    are counted in ``dropped`` instead of stored.  ``profiler`` selects
+    profiler mode (module docstring): spans also go to the JAX
+    profiler's trace, and nothing is fenced.
     """
 
-    def __init__(self, sample: int = 1, max_events: int = 200_000):
+    def __init__(self, sample: int = 1, max_events: int = 200_000,
+                 profiler: bool = False):
         if sample < 1:
             raise ValueError(f"sample must be >= 1, got {sample}")
         self.t0 = time.perf_counter()
         self.sample = sample
         self.max_events = max_events
+        self.profiler = profiler
         self.records: List[Dict[str, Any]] = []
         self.fences = 0
         self.dropped = 0
-        self._lock = threading.Lock()
+        # re-entrant: a collection may start, and record its span, on a
+        # thread that is inside a locked section
+        self._lock = threading.RLock()
         self._tls = threading.local()
         self._sample_ctr = 0
         self._req_slot = 0
+        self._gc_open: Dict[int, Any] = {}
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """``gc.callbacks`` hook (profiler mode): one ``python.gc`` span
+        per collection, on the thread whose allocation triggered it."""
+        tid = threading.get_ident()
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation("python.gc")
+            ann.__enter__()
+            self._gc_open[tid] = (ann, time.perf_counter())
+            return
+        opened = self._gc_open.pop(tid, None)
+        if opened is None:
+            return
+        ann, t0 = opened
+        t1 = time.perf_counter()
+        ann.__exit__(None, None, None)
+        self._record("python.gc", "gc", tid, t0, t1 - t0,
+                     len(self._stack()),
+                     {"generation": info.get("generation"),
+                      "collected": info.get("collected")}, kind="span")
 
     # -- recording ------------------------------------------------------
     def _stack(self) -> list:
@@ -215,11 +258,19 @@ def span(name: str, cat: str = "host", **args):
     return t.span(name, cat, **args)
 
 
-def fence(x):
-    """Block until ``x``'s device buffers are ready — only while tracing
-    (the production path never synchronizes).  Returns ``x``."""
+def fencing() -> bool:
+    """True while a fenced (not profiler-mode) tracer is active: sessions
+    then dispatch their stage-fenced programs."""
     t = _ACTIVE
-    if t is not None:
+    return t is not None and not t.profiler
+
+
+def fence(x):
+    """Block until ``x``'s device buffers are ready — only while a fenced
+    tracer is active (the production path and profiler mode never
+    synchronize).  Returns ``x``."""
+    t = _ACTIVE
+    if t is not None and not t.profiler:
         global _WORK
         jax.block_until_ready(x)
         with t._lock:
@@ -228,13 +279,18 @@ def fence(x):
     return x
 
 
-def start(sample: int = 1, max_events: int = 200_000) -> Tracer:
-    """Install a fresh active tracer (errors if one is already active)."""
+def start(sample: int = 1, max_events: int = 200_000,
+          profiler: bool = False) -> Tracer:
+    """Install a fresh active tracer (errors if one is already active).
+    ``profiler=True`` selects profiler mode (module docstring)."""
     global _ACTIVE
     with _ACTIVE_LOCK:
         if _ACTIVE is not None:
             raise RuntimeError("a tracer is already active; stop() it first")
-        _ACTIVE = Tracer(sample=sample, max_events=max_events)
+        _ACTIVE = Tracer(sample=sample, max_events=max_events,
+                         profiler=profiler)
+        if profiler:
+            gc.callbacks.append(_ACTIVE._on_gc)
         return _ACTIVE
 
 
@@ -246,14 +302,18 @@ def stop() -> Tracer:
             raise RuntimeError("no active tracer")
         t = _ACTIVE
         _ACTIVE = None
+        if t.profiler:
+            gc.callbacks.remove(t._on_gc)
         return t
 
 
 class trace:
     """``with obs.trace() as tr: ...`` — start/stop scoped to a block."""
 
-    def __init__(self, sample: int = 1, max_events: int = 200_000):
-        self._kw = {"sample": sample, "max_events": max_events}
+    def __init__(self, sample: int = 1, max_events: int = 200_000,
+                 profiler: bool = False):
+        self._kw = {"sample": sample, "max_events": max_events,
+                    "profiler": profiler}
 
     def __enter__(self) -> Tracer:
         self._t = start(**self._kw)

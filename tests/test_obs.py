@@ -12,6 +12,9 @@ Key invariants:
     ids/dists traced vs untraced;
   * spans are well-nested per thread even under concurrent gateway
     submits (request exemplars live on separate virtual tracks);
+  * profiler mode runs the production executables (no compile, no staged
+    program, no fence, bitwise-equal results) and its spans land in the
+    JAX profiler's ``.xplane.pb`` on the thread that ran them;
   * the exported document is schema-valid Chrome/Perfetto trace-event
     JSON, and ``snapshot_all``/``to_prometheus`` carry the documented
     layout.
@@ -325,3 +328,156 @@ def test_snapshot_all_with_gateway(rairs_index, unit_data):
     assert {"schema_version", "gateway", "session", "hbm_model"} <= set(snap)
     assert snap["gateway"]["telemetry"]["counters"]["responses"] == 8
     assert "trace" not in snap              # no tracer supplied
+
+
+# ---------------------------------------------------------------------------
+# profiler mode: spans on the profiler's clock, production executables
+# ---------------------------------------------------------------------------
+
+def test_disabled_tracing_does_no_work_through_the_gateway(rairs_index,
+                                                            unit_data):
+    """With tracing off, the dispatcher's spans (wait, flush, stack,
+    fetch, fulfill) and the session's (pad, execute, slice) do no work."""
+    _, q, _ = unit_data
+    with Gateway(rairs_index, k=10, nprobe=8,
+                 config=GatewayConfig(max_batch=8, max_delay_ms=2.0)) as gw:
+        gw.search(q[0], timeout=60.0)       # compile outside the window
+        w0 = obs.work_count()
+        for i in range(4):
+            gw.search(q[i], timeout=60.0)   # one row, padded to a bucket
+        assert obs.work_count() == w0
+    searcher = rairs_index.searcher(SearchParams(k=10, nprobe=8))
+    _run(searcher, q, n=3)
+    w0 = obs.work_count()
+    _run(searcher, q, n=3)                  # padded: pad, execute, slice
+    assert obs.work_count() == w0
+
+
+def _stage_cache_sizes():
+    from repro.core import search as search_mod
+    from repro.core.stream import search as stream_search
+    return [f._cache_size() for f in (
+        search_mod._stage_select, search_mod._stage_plan,
+        search_mod._stage_scan, search_mod._stage_finalize,
+        stream_search._stage_delta, stream_search._stage_finalize_stream)]
+
+
+def _session(label, rairs_index, unit_data, shared_trained):
+    p = SearchParams(k=10, nprobe=8)
+    if label == "sharded":
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        return rairs_index.shard(mesh).searcher(p)
+    if label == "streaming":
+        x, _, _ = unit_data
+        cents, cb = shared_trained
+        base = build_index(jax.random.PRNGKey(0), x[:4000],
+                           IndexConfig(nlist=64, strategy="rair", seil=True),
+                           centroids=cents, codebook=cb)
+        stream = StreamingIndex(base, StreamConfig(delta_pad=512))
+        stream.insert(x[4000:4256])
+        return stream.searcher(p)
+    return rairs_index.searcher({
+        "paged": p,
+        "fused": SearchParams(k=10, nprobe=8, fused_topk=True),
+        "plan_reuse": SearchParams(k=10, nprobe=8, exec_mode="clustered",
+                                   plan_reuse=True)}[label])
+
+
+@pytest.mark.parametrize("label", ["paged", "fused", "plan_reuse",
+                                   "sharded", "streaming"])
+def test_profiler_mode_runs_the_production_executables(
+        rairs_index, unit_data, shared_trained, label):
+    _, q, _ = unit_data
+    searcher = _session(label, rairs_index, unit_data, shared_trained)
+    ref = _run(searcher, q, n=5)            # compiles its bucket
+    compiles = searcher.stats.compiles
+    stages = _stage_cache_sizes()
+    with obs.trace(profiler=True) as tr:
+        res = _run(searcher, q, n=5)
+    assert searcher.stats.compiles == compiles
+    assert _stage_cache_sizes() == stages   # no _stage_* program built
+    assert tr.fences == 0
+    np.testing.assert_array_equal(ref.ids, res.ids)
+    np.testing.assert_array_equal(ref.dists, res.dists)
+    names = {r["name"] for r in tr.records}
+    assert {"searcher.dispatch", "searcher.pad", "searcher.execute",
+            "searcher.slice"} <= names
+    if label != "plan_reuse":               # its stages are its programs
+        assert not any(n.startswith("stage.") for n in names)
+
+
+def _xplane_spans(path):
+    """{line index: [(start, end, name)]} of the host lines' events."""
+    pd = jax.profiler.ProfileData.from_file(path)
+    out = {}
+    i = 0
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out[i] = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                      for e in line.events]
+            i += 1
+    return out
+
+
+def _contains(outer, inner):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_profiler_mode_spans_land_in_the_xplane(rairs_index, unit_data,
+                                                tmp_path):
+    import glob
+    import os
+    _, q, _ = unit_data
+    with Gateway(rairs_index, k=10, nprobe=8,
+                 config=GatewayConfig(max_batch=8, max_delay_ms=2.0)) as gw:
+        gw.search(q[0], timeout=60.0)       # compile outside the capture
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.trace(profiler=True) as tr:
+                for i in range(6):
+                    gw.search(q[i], timeout=60.0)
+        finally:
+            jax.profiler.stop_trace()
+    assert tr.fences == 0
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    lines = _xplane_spans(path)
+    flushes = {i: [e for e in evs if e[2] == "gateway.flush"]
+               for i, evs in lines.items()}
+    (disp,) = [i for i, f in flushes.items() if f]   # one dispatcher line
+    evs = lines[disp]
+    subs = [i for i, e in lines.items()
+            if any(x[2] == "gateway.submit" for x in e)]
+    assert subs and disp not in subs         # clients submit elsewhere
+    for name in ("gateway.wait", "gateway.stack", "gateway.fetch",
+                 "gateway.fulfill"):
+        assert any(e[2] == name for e in evs), name
+    # gateway.flush > searcher.dispatch > searcher.execute, on this thread
+    nested = 0
+    for f in flushes[disp]:
+        for d in (e for e in evs if e[2] == "searcher.dispatch"
+                  and _contains(f, e)):
+            nested += any(e[2] == "searcher.execute" and _contains(d, e)
+                          for e in evs)
+    assert nested >= len(flushes[disp]) >= 1
+    # the in-memory record holds the same spans
+    assert {"gateway.flush", "searcher.execute", "gateway.wait"} <= {
+        r["name"] for r in tr.records}
+
+
+def test_profiler_mode_records_collections_as_spans():
+    import gc
+    with obs.trace(profiler=True) as tr:
+        hook = tr._on_gc
+        assert hook in gc.callbacks
+        gc.collect()
+    assert hook not in gc.callbacks          # removed with the tracer
+    recs = [r for r in tr.records if r["name"] == "python.gc"]
+    assert all(r["kind"] == "span" for r in recs)
+    assert any(r["args"]["generation"] == 2 for r in recs)
+    with obs.trace() as fenced:              # the default mode: no hook
+        assert fenced._on_gc not in gc.callbacks
+        gc.collect()
+    assert not fenced.records
