@@ -8,7 +8,13 @@ with no per-item scalar work) to the MXU:
   * the 4-bit code of item i, subspace m selects ``lut[m, code]``; we
     materialize the selection as a one-hot tile and contract
     ``(BLK, M*K) @ (M*K, 1)`` on the MXU — one systolic pass scores a
-    whole block (the TPU idiom for small-table gathers);
+    whole block (the TPU idiom for small-table gathers).  The one-hot
+    is built directly in the lane layout the contraction reads (codes
+    spread to lanes by an exact 0/1 bf16 contraction, then compared
+    with ``lane % K``) and the LUT arrives flattened to ``(B, 1, M*K)``
+    from the wrappers, so no grid step reshapes a ``(.., M, K)`` tile
+    to ``M*K`` lanes: Mosaic lowers that lane merge as sublane rotates
+    and shuffles, which cost more than the scoring itself;
   * SEIL's reference-entry indirection becomes *paging*: the per-query
     deduplicated block-id list is scalar-prefetched
     (``PrefetchScalarGridSpec``) and drives the BlockSpec ``index_map``,
@@ -90,7 +96,7 @@ def _tile_codes(codes_ref, packed: bool) -> jnp.ndarray:
     the nibbles back does not lower on TPU, so the unpacked tile is
     ``[lo | hi]`` — every even subquantizer, then every odd one — and
     the kernel wrappers permute the LUT rows to match
-    (``_even_odd_lut``).  Callers guarantee 2*MB == lut M (ops wrappers
+    (``_kernel_lut``).  Callers guarantee 2*MB == lut M (ops wrappers
     zero-pad the LUT so a padded byte's two zero codes select zero rows
     and contribute nothing).
     """
@@ -100,31 +106,49 @@ def _tile_codes(codes_ref, packed: bool) -> jnp.ndarray:
     return jnp.concatenate([raw & 15, raw >> 4], axis=-1)
 
 
-def _even_odd_lut(lut: jnp.ndarray, packed: bool) -> jnp.ndarray:
-    """(B, M, K) LUT in the row order ``_tile_codes`` unpacks to."""
-    if not packed:
-        return lut
-    return jnp.concatenate([lut[:, 0::2], lut[:, 1::2]], axis=1)
+def _kernel_lut(lut: jnp.ndarray, packed: bool) -> jnp.ndarray:
+    """(B, M, K) LUT -> the (B, 1, M*K) rows the kernels read.
+
+    Flattened once per call in XLA, so no grid step merges the K lanes
+    of the table; for a packed plane the rows are first put in the
+    order ``_tile_codes`` unpacks to (every even subquantizer, then
+    every odd one)."""
+    if packed:
+        lut = jnp.concatenate([lut[:, 0::2], lut[:, 1::2]], axis=1)
+    b, m, k = lut.shape
+    return lut.reshape(b, 1, m * k)
 
 
 def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
     """(QT, BLK) ADC distances of one paged code block for a query tile.
 
     The 4-bit code of item i, subspace m selects ``lut[m, code]``; the
-    selection is a one-hot tile over the K table entries, flattened
-    (M, K) -> MK and contracted on the MXU, so every query of the tile
-    scores the whole block in one pass.  Both kernels score through
-    here, so their distances are bitwise identical.  The contraction is
-    pinned to full f32 (HIGHEST): the one-hot is exact in bf16 but the
-    LUT is not, and the distances must match the jnp reference's."""
-    qt, m, k = lut_ref.shape
+    selection is a (BLK, M*K) one-hot over the flat table, contracted on
+    the MXU, so every query of the tile scores the whole block in one
+    pass.  The one-hot is built in the lane layout the contraction reads:
+    an exact bf16 contraction with the 0/1 matrix ``R[m, j] = (j // K ==
+    m)`` copies code (i, m) to the K lanes of subspace m (one nonzero
+    product per output, codes < 256), and a compare with the lane
+    constant ``j % K`` selects.  A (BLK, M, K) tile reshaped to
+    (BLK, M*K) would merge lanes, which Mosaic lowers as sublane
+    rotates and shuffles that spill on every grid step.  Both kernels
+    score through here, so their distances are bitwise identical.  The
+    scoring contraction is pinned to full f32 (HIGHEST): the one-hot is
+    exact in bf16 but the LUT is not, and the distances must match the
+    jnp reference's."""
     codes = _tile_codes(codes_ref, packed)                     # (BLK, M)
-    blk = codes.shape[0]
-    sel = (codes[:, :, None]
-           == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2))
-    oh = sel.astype(jnp.float32).reshape(blk, m * k)           # (BLK, MK)
-    lut = lut_ref[...].reshape(qt, m * k)                      # (QT, MK)
-    return jax.lax.dot_general(lut, oh, (((1,), (1,)), ((), ())),
+    m = codes.shape[1]
+    mk = lut_ref.shape[-1]
+    k = mk // m
+    col = jax.lax.broadcasted_iota(jnp.int32, (m, mk), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (m, mk), 0)
+    spread = (col // k == row).astype(jnp.bfloat16)            # (M, MK)
+    lanes = jnp.dot(codes.astype(jnp.bfloat16), spread,
+                    preferred_element_type=jnp.float32)        # (BLK, MK)
+    entry = jax.lax.broadcasted_iota(jnp.int32, (1, mk), 1) % k
+    oh = (lanes == entry.astype(jnp.float32)).astype(jnp.float32)
+    return jax.lax.dot_general(lut_ref[:, 0, :], oh,
+                               (((1,), (1,)), ((), ())),
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
@@ -135,7 +159,7 @@ def _make_kernel(packed: bool):
     def _kernel(idx_ref, lut_ref, codes_ref, out_ref):
         """One grid step: score one code block for QT queries.
 
-        lut_ref:   (QT, M, K) f32 in VMEM
+        lut_ref:   (QT, 1, M*K) f32 in VMEM (``_kernel_lut``)
         codes_ref: (1, BLK, MB) uint8 in VMEM (the paged block; MB = M,
                    or M/2 when nibble-packed)
         out_ref:   (QT, 1, 1, BLK) f32
@@ -168,7 +192,7 @@ def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     tb, blk, mb = block_codes.shape
     assert (2 * mb if packed else mb) == m, (mb, m, packed)
     assert b == qb * query_tile, (b, qb, query_tile)
-    lut = _even_odd_lut(lut, packed)
+    lut = _kernel_lut(lut, packed)
 
     def call(idx, lut_c):
         kernel = pl.pallas_call(
@@ -177,7 +201,7 @@ def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
                 num_scalar_prefetch=1,
                 grid=idx.shape,
                 in_specs=[
-                    pl.BlockSpec((query_tile, m, k),
+                    pl.BlockSpec((query_tile, 1, m * k),
                                  lambda qi, si, idx: (qi, 0, 0)),
                     pl.BlockSpec((1, blk, mb),
                                  lambda qi, si, idx: (idx[qi, si], 0, 0)),
@@ -378,7 +402,7 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
         return (qi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((query_tile, m, k), tile),
+        pl.BlockSpec((query_tile, 1, m * k), tile),
         pl.BlockSpec((1, blk, mb), paged),
         pl.BlockSpec((1, 1, blk), paged),
         pl.BlockSpec((1, 1, blk), paged),
@@ -388,7 +412,8 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     ]
     blocks = [block_codes, row(block_ids.astype(jnp.int32)),
               row(block_other.astype(jnp.int32))]
-    per_query = [_even_odd_lut(lut, packed), rank_of.astype(jnp.int32).reshape(b, 1, nlist),
+    per_query = [_kernel_lut(lut, packed),
+                 rank_of.astype(jnp.int32).reshape(b, 1, nlist),
                  slot_of.astype(jnp.int32).reshape(b, 1, s),
                  rank_u.astype(jnp.int32).reshape(b, 1, s)]
     if with_dead:

@@ -6,8 +6,15 @@ import numpy as np
 import pytest
 from hypothesis_stub import given, settings, st
 
-from repro.kernels.ops import pq_scan_grouped, pq_scan_paged, pq_scan_tiled
+from jax.experimental import pallas as pl
+from jax.extend import core as jcore
+
+from repro.kernels.ops import (_align, pq_scan_grouped, pq_scan_paged,
+                               pq_scan_tiled)
+from repro.kernels.pq_scan import (_kernel_lut, _score_block,
+                                   pq_scan_tiled_kernel, pq_scan_topk_kernel)
 from repro.kernels.ref import onehot_lut_ref, pq_scan_paged_ref
+from repro.quant.nibbles import pack_nibbles
 
 
 @pytest.mark.parametrize("b,m,k,tb,blk,s", [
@@ -79,16 +86,90 @@ def test_tiled_mode_per_tile_lists():
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_onehot_identity_vs_gather():
-    """The MXU one-hot contraction is exactly the LUT gather."""
-    key = jax.random.PRNGKey(11)
+def _score_one_block(lut, codes, packed):
+    """``_score_block`` over one code block, interpreted: lut (QT, M, K),
+    codes (BLK, MB) -> (QT, BLK), fed the flat LUT the kernels read."""
+    qt, blk = lut.shape[0], codes.shape[0]
+
+    def body(lut_ref, codes_ref, out_ref):
+        out_ref[...] = _score_block(lut_ref, codes_ref, packed)
+
+    return pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((qt, blk), jnp.float32),
+        interpret=True)(_kernel_lut(lut, packed), codes[None])
+
+
+@pytest.mark.parametrize("qt", [1, 4, 8])
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("m,m_kernel", [(16, 16), (64, 64), (64, 128)])
+@pytest.mark.parametrize("blk", [32, 128])
+def test_onehot_identity_vs_gather(blk, m, m_kernel, packed, qt):
+    """The kernel's one-hot contraction (codes spread to lanes, flat LUT)
+    is exactly the LUT gather, and the one-hot oracle.  ``m_kernel`` 128
+    is the chip's width: 64 subspaces zero-padded by ``ops._align``."""
+    key = jax.random.PRNGKey(11 + blk + m_kernel + qt)
     k1, k2 = jax.random.split(key)
-    lut = jax.random.normal(k1, (16, 16), jnp.float32)
-    codes = jax.random.randint(k2, (64, 16), 0, 16, jnp.int32)
-    oh = onehot_lut_ref(lut, codes)
-    gather = lut[jnp.arange(16)[None, :], codes].sum(-1)
-    np.testing.assert_allclose(np.asarray(oh), np.asarray(gather),
-                               rtol=1e-5, atol=1e-5)
+    lut = jax.random.normal(k1, (qt, m, 16), jnp.float32)
+    codes = jax.random.randint(k2, (blk, m), 0, 16, jnp.int32)
+    plane = (jnp.asarray(pack_nibbles(np.asarray(codes, np.uint8)))
+             if packed else codes.astype(jnp.uint8))
+    klut, kcodes = _align(lut, plane[None], packed,
+                          on_tpu=m_kernel > m)
+    assert klut.shape[1] == m_kernel, klut.shape
+    out = np.asarray(_score_one_block(klut, kcodes[0], packed))
+    gather = lut[:, jnp.arange(m)[None, :], codes].sum(-1)     # (QT, BLK)
+    for q in range(qt):
+        np.testing.assert_allclose(
+            out[q], np.asarray(onehot_lut_ref(lut[q], codes)),
+            rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, np.asarray(gather), rtol=1e-5,
+                               atol=1e-5)
+
+
+def _kernel_jaxprs(closed):
+    """Every jaxpr nested in a pallas_call of ``closed``."""
+    found = []
+
+    def walk(jaxpr, inside):
+        if inside:
+            found.append(jaxpr)
+        for eqn in jaxpr.eqns:
+            for sub in eqn.params.values():
+                if isinstance(sub, (jcore.Jaxpr, jcore.ClosedJaxpr)):
+                    walk(getattr(sub, "jaxpr", sub),
+                         inside or eqn.primitive.name == "pallas_call")
+
+    walk(closed.jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("fused", [False, True])
+def test_kernel_bodies_merge_no_lanes(fused, packed):
+    """No reshape inside either kernel body changes the minor (lane)
+    dimension: a lane-merging reshape of the one-hot or the LUT lowers
+    on TPU as sublane rotates and shuffles on every grid step."""
+    b, m, kk, tb, blk, s, nlist = 8, 16, 16, 4, 32, 3, 8
+    mb = m // 2 if packed else m
+    lut = jnp.zeros((b, m, kk), jnp.float32)
+    codes = jnp.zeros((tb, blk, mb), jnp.uint8)
+    idx = jnp.zeros((b, s), jnp.int32)
+    if fused:
+        plane = jnp.zeros((tb, blk), jnp.int32)
+        closed = jax.make_jaxpr(lambda *a: pq_scan_topk_kernel(
+            *a, query_tile=1, fetch=16, interpret=True, packed=packed))(
+            lut, codes, plane, plane, idx, jnp.zeros((b, nlist), jnp.int32),
+            idx, idx)
+    else:
+        closed = jax.make_jaxpr(lambda *a: pq_scan_tiled_kernel(
+            *a, query_tile=1, interpret=True, packed=packed))(lut, codes, idx)
+    bodies = _kernel_jaxprs(closed)
+    assert bodies
+    for jaxpr in bodies:
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "reshape":
+                (src,), (dst,) = eqn.invars, eqn.outvars
+                assert src.aval.shape[-1] == dst.aval.shape[-1], eqn
 
 
 @settings(max_examples=10, deadline=None)

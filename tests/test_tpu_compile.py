@@ -5,8 +5,9 @@ gathers that the TPU lowering refuses, so these tests compile the two
 Pallas kernels for a *described* v5e chip — no chip needed — at the
 widths the chip runs: M=64 at 4 bits padded to 128, K=16, fetch=128,
 nlist=4096, blocks 32 and 128, unpacked and nibble-packed, with and
-without the tombstone plane, query tiles 1 to 8, and a 1024-query
-batch whose scan lists need the SMEM chunking.  Shapes only; nothing
+without the tombstone plane, query tiles 1 to 8, a 1024-query batch
+whose scan lists need the SMEM chunking, and the fused kernel at the
+exact shapes of the SIFT1M paged deployment.  Shapes only; nothing
 runs.  The topology is described inside a module fixture (never at
 import), and the persistent compilation cache is off around the
 compiles: an entry written for a described chip cannot be read back.
@@ -89,3 +90,24 @@ def test_topk_kernel_compiles(one_chip, blk, packed, dead, qt, b):
         lambda *a: pq_scan_topk_kernel(*a, query_tile=qt, fetch=FETCH,
                                        packed=packed),
         *args)
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_topk_kernel_compiles_at_sift1m_paged_shape(one_chip, packed):
+    """The fused kernel as the SIFT1M paged deployment runs it: a flat
+    (B, 1, M*K) LUT from the wrapper, query tile 1, block 32, M 64
+    padded to 128, nlist 4096, 557 scan positions, fetch 100, the
+    index's 54,140 blocks and a 1024-query batch; packed or not."""
+    b, blk, tb, s, fetch = 1024, 32, 54140, 557, 100
+    mb = M // 2 if packed else M
+    _compiled_kernel(
+        lambda *a: pq_scan_topk_kernel(*a, query_tile=1, fetch=fetch,
+                                       packed=packed),
+        _spec(one_chip, (b, M, K), jnp.float32),
+        _spec(one_chip, (tb, blk, mb), jnp.uint8),
+        _spec(one_chip, (tb, blk), jnp.int32),
+        _spec(one_chip, (tb, blk), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32),
+        _spec(one_chip, (b, NLIST), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32))
