@@ -26,18 +26,19 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..kernels.topk import pow2_ceil
 from .engine import (BIG, merge_unions_host, plan_width, tile_signatures,
                      union_live, width_buckets)
 from .params import SearchParams
-from .search import (SearchResult, probe_plan, scan_finalize, seil_search,
-                     seil_search_traced)
+from .search import (SearchResult, finalize_fetch, probe_plan, scan_finalize,
+                     seil_search, seil_search_traced)
 
 
 @dataclasses.dataclass
@@ -51,6 +52,8 @@ class SearcherStats:
     cache_hits: int = 0      # executable fetches served from the cache
                              # (plan_reuse chunks fetch two: probe + scan)
     padded_rows: int = 0     # total pad rows added across dispatches
+    refined_rows: int = 0    # candidate rows the exact re-rank scores:
+                             # padded batch x bigk_eff per dispatch
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -132,9 +135,23 @@ class Searcher:
     def compile_stats(self) -> Dict[str, Any]:
         d = self.stats.as_dict()
         d["buckets"] = list(self.buckets)
+        d["topk_width"] = self.topk_width()
         if self.params.plan_reuse:
             d["plan"] = self.plan_stats.summary()
         return d
+
+    def topk_width(self) -> Optional[int]:
+        """Lane width F of the fused kernel's top-k accumulator (the
+        power of two covering the candidates finalize fetches, capped by
+        the scan width), or None when the session does not run it."""
+        p = self.params
+        if not (p.use_kernel and p.fused_topk):
+            return None
+        idx = self.index
+        fetch = finalize_fetch(p.bigk_eff, idx.result_oversample,
+                               idx.needs_result_dedup)
+        return pow2_ceil(min(fetch,
+                             p.max_scan * idx.arrays.block_codes.shape[1]))
 
     # -- overridable hooks (core/stream/ swaps in the streaming pipeline) --
     def _check_current(self) -> None:
@@ -264,6 +281,7 @@ class Searcher:
         fences its (already natural) probe / host-merge / scan
         boundaries; a profiler-mode tracer changes neither."""
         self.stats.dispatches += 1
+        self.stats.refined_rows += bucket * self.params.bigk_eff
         if not self.params.plan_reuse:
             if obs.fencing():
                 r = self._dispatch_traced(bucket, qc)

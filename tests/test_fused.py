@@ -132,12 +132,19 @@ def test_paged_kernel_tile_row_invariant():
 # ---------------------------------------------------------------------------
 
 def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, nid=200,
-           tie_heavy=False):
+           tie_heavy=False, ip=False):
     """A consistent (store, plan, lut, rank_of, sel, live) with duplicate
     ids, invalid items, misc co-assignments, and (optionally) integer
-    luts so exact distance ties are everywhere."""
+    luts so exact distance ties are everywhere, or inner-product tables
+    (``pq_lut_ip``: negative entries, 2-dim subspaces)."""
     rng = np.random.default_rng(seed)
-    if tie_heavy:
+    if ip:
+        from repro.core.pq import PQCodebook, pq_lut_ip
+        books = rng.standard_normal((m, k, 2)).astype(np.float32)
+        q = rng.standard_normal((b, 2 * m)).astype(np.float32)
+        lut = np.asarray(pq_lut_ip(PQCodebook(jnp.asarray(books)),
+                                   jnp.asarray(q)))
+    elif tie_heavy:
         lut = rng.integers(0, 3, (b, m, k)).astype(np.float32)
     else:
         lut = rng.standard_normal((b, m, k)).astype(np.float32)
@@ -182,12 +189,17 @@ def _unfused_reference(store, plan, lut, rank_of, sel, live, fetch,
 @pytest.mark.parametrize("exec_mode", EXEC_MODES)
 @pytest.mark.parametrize("use_kernel", [False, True])
 @pytest.mark.parametrize("with_live", [False, True])
+@pytest.mark.parametrize("fetch,ip", [(16, False), (512, True), (1000, True)])
 def test_scan_blocks_topk_matches_preselect(exec_mode, use_kernel,
-                                            with_live):
+                                            with_live, fetch, ip):
+    """Tie-heavy tables at fetch 16 (F 16), and inner-product tables at
+    fetch 512 and 1000 (F 512 and 1024: the t2i1m deployment's
+    accumulator at k_factor 50 and 100) over a 40-block plan wider than
+    the fetch."""
+    wide = {} if fetch == 16 else {"s": 40, "tb": 48}
     store, plan, lut, rank_of, sel, live = _synth(
-        17 + hash(exec_mode) % 100, tie_heavy=True)
+        17 + hash(exec_mode) % 100, tie_heavy=not ip, ip=ip, **wide)
     live = live if with_live else None
-    fetch = 16
     ref_d, ref_i, ref_dco = _unfused_reference(
         store, plan, lut, rank_of, sel, live, fetch, exec_mode,
         use_kernel=use_kernel)

@@ -102,6 +102,48 @@ def test_searcher_chunks_oversize_batches(rairs_index, unit_data):
     _assert_results_identical(res, legacy)
 
 
+def test_searcher_counts_refined_rows_per_padded_dispatch(rairs_index,
+                                                         unit_data):
+    """``refined_rows`` is the host's count of candidate rows the exact
+    re-rank scores: padded batch x bigk_eff per dispatch, chunks and
+    bucket padding included."""
+    from repro.core.params import RefineParams
+    _, q, _ = unit_data
+    searcher = rairs_index.searcher(
+        SearchParams(k=10, nprobe=4, k_factor=4, batch_buckets=(4, 8)))
+    searcher(q[:3])                              # one dispatch of 4
+    assert searcher.stats.refined_rows == 4 * 40
+    searcher(q[:13])                             # 8 + pad(5 -> 8)
+    assert searcher.stats.refined_rows == (4 + 16) * 40
+    assert searcher.compile_stats()["refined_rows"] == 20 * 40
+    wide = rairs_index.searcher(SearchParams(
+        k=10, nprobe=4, k_factor=4,
+        refine=RefineParams(plane="full", refine_factor=3)))
+    wide(q[:5])                                  # bucket 8, bigk_eff 120
+    assert wide.stats.refined_rows == 8 * 120
+
+
+def test_searcher_reports_fused_topk_width(rairs_index):
+    """``compile_stats()["topk_width"]`` is the fused kernel's
+    accumulator width F = pow2_ceil(fetch), the fetch capped by the scan
+    width; None for a session that does not run the fused kernel."""
+    from repro.kernels.topk import pow2_ceil
+    blk = rairs_index.arrays.block_codes.shape[1]
+    over = (rairs_index.result_oversample
+            if rairs_index.needs_result_dedup else 1)
+    assert rairs_index.searcher(SearchParams(
+        k=10, nprobe=4, use_kernel=True)).compile_stats()["topk_width"] is None
+    assert rairs_index.searcher(SearchParams(
+        k=10, nprobe=4, fused_topk=True)).compile_stats()["topk_width"] is None
+    for k_factor, max_scan in ((10, None), (50, None), (100, 8)):
+        sess = rairs_index.searcher(SearchParams(
+            k=10, nprobe=4, k_factor=k_factor, max_scan=max_scan,
+            use_kernel=True, fused_topk=True))
+        fetch = min(10 * k_factor * over, sess.params.max_scan * blk)
+        assert sess.compile_stats()["topk_width"] == pow2_ceil(fetch)
+    assert sess.compile_stats()["topk_width"] == pow2_ceil(8 * blk)
+
+
 def test_index_search_wrapper_reuses_sessions(rairs_index, unit_data):
     """The kwarg path is a thin wrapper: identical kwargs -> one cached
     session, so repeat calls are compile-free."""
@@ -294,3 +336,43 @@ def test_insert_batch_does_not_reuse_stale_sessions(rairs_index, unit_data):
     assert getattr(grown, "_searcher_cache", None) in (None, {})
     r = grown.search(q[:8], k=10, nprobe=4)
     assert np.asarray(r.ids).shape == (8, 10)
+
+
+# ---------------------------------------------------------------------------
+# inner product at a wide refine budget (the t2i1m deployment, tiny)
+# ---------------------------------------------------------------------------
+def test_ip_searcher_wide_refine_matches_exact_reference():
+    """A SOAR+SEIL inner-product index over the benchmark's out-of-
+    distribution generator (``modality_gap``), M = d/2, searched through
+    the session with the fused kernel at F >= 512: returned distances
+    are the exact inner products of the returned ids (within 1e-4 of
+    each query's scale, the benchmark's ``dist_gap``), and 1,000
+    re-ranked candidates recall more than 100 do on the same index."""
+    from bench import reference
+    from bench.corpus import make_corpus
+    from repro.kernels.topk import pow2_ceil
+    cfg = {"name": "tiny_t2i", "n": 4000, "d": 16, "n_queries": 48,
+           "metric": "ip", "n_components": 16, "latent": 8,
+           "modality_gap": True, "corpus_seed": 0}
+    x, q = make_corpus(cfg, 7)
+    index = build_index(jax.random.PRNGKey(0), x, IndexConfig(
+        nlist=16, strategy="soar", seil=True, m_pq=8, metric="ip",
+        kmeans_iters=4, pq_iters=4))
+    gt = reference.exact_topk(x, q, 10, "ip")
+    x_host, q_host = np.asarray(x), np.asarray(q)
+    recall = {}
+    for k_factor in (10, 100):
+        sess = index.searcher(SearchParams(
+            k=10, nprobe=4, k_factor=k_factor, use_kernel=True,
+            fused_topk=True))
+        res = sess(q)
+        ids, dists = np.asarray(res.ids), np.asarray(res.dists)
+        assert sess.compile_stats()["topk_width"] == pow2_ceil(
+            min(10 * k_factor, sess.params.max_scan * 32))
+        ref = reference.exact_dists(x_host, q_host, ids, "ip")
+        scale = np.abs(ref).max(axis=1, keepdims=True)
+        assert (ids >= 0).all()
+        np.testing.assert_array_less(np.abs(dists - ref) / scale, 1e-4)
+        recall[k_factor] = (ids[:, :, None] == gt[:, None, :]).any(1).mean()
+    assert sess.compile_stats()["topk_width"] >= 512
+    assert recall[100] > recall[10], recall

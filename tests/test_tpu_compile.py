@@ -7,7 +7,7 @@ widths the chip runs: M=64 at 4 bits padded to 128, K=16, fetch=128,
 nlist=4096, blocks 32 and 128, unpacked and nibble-packed, with and
 without the tombstone plane, query tiles 1 to 8, a 1024-query batch
 whose scan lists need the SMEM chunking, and the fused kernel at the
-exact shapes of the SIFT1M paged deployment.  Shapes only; nothing
+exact shapes of the SIFT1M and Text-to-Image 1M paged deployments.  Shapes only; nothing
 runs.  The topology is described inside a module fixture (never at
 import), and the persistent compilation cache is off around the
 compiles: an entry written for a described chip cannot be read back.
@@ -105,6 +105,26 @@ def test_topk_kernel_compiles_at_sift1m_paged_shape(one_chip, packed):
                                        packed=packed),
         _spec(one_chip, (b, M, K), jnp.float32),
         _spec(one_chip, (tb, blk, mb), jnp.uint8),
+        _spec(one_chip, (tb, blk), jnp.int32),
+        _spec(one_chip, (tb, blk), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32),
+        _spec(one_chip, (b, NLIST), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32),
+        _spec(one_chip, (b, s), jnp.int32))
+
+
+@pytest.mark.parametrize("fetch", [500, 1000])
+def test_topk_kernel_compiles_at_t2i1m_paged_width(one_chip, fetch):
+    """The fused kernel at the Text-to-Image deployment's shapes: fetch
+    500 (its k_factor 50, a 512-lane accumulator) and 1,000 (k_factor
+    100, 1,024 lanes, ten merge stages per grid step), M 100 padded to
+    128, 604 scan positions, the index's 58,706 blocks, a 1024-query
+    batch."""
+    b, blk, tb, s = 1024, 32, 58706, 604
+    _compiled_kernel(
+        lambda *a: pq_scan_topk_kernel(*a, query_tile=1, fetch=fetch),
+        _spec(one_chip, (b, M, K), jnp.float32),
+        _spec(one_chip, (tb, blk, M), jnp.uint8),
         _spec(one_chip, (tb, blk), jnp.int32),
         _spec(one_chip, (tb, blk), jnp.int32),
         _spec(one_chip, (b, s), jnp.int32),
