@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..kernels.pq_scan import blocks_per_step
 from ..kernels.topk import pow2_ceil
 from .engine import (BIG, merge_unions_host, plan_width, tile_signatures,
                      union_live, width_buckets)
@@ -136,6 +137,7 @@ class Searcher:
         d = self.stats.as_dict()
         d["buckets"] = list(self.buckets)
         d["topk_width"] = self.topk_width()
+        d.update(self.scan_grouping())
         if self.params.plan_reuse:
             d["plan"] = self.plan_stats.summary()
         return d
@@ -152,6 +154,22 @@ class Searcher:
                                idx.needs_result_dedup)
         return pow2_ceil(min(fetch,
                              p.max_scan * idx.arrays.block_codes.shape[1]))
+
+    def scan_grouping(self) -> Dict[str, Any]:
+        """``blocks_per_step``: scan positions G one grid step of the
+        session's scan kernel pages (``kernels/pq_scan.py::
+        blocks_per_step``), and ``padded_positions_share``: (S' - S) / S'
+        of the paged scan list, S = ``max_scan`` padded to S', a multiple
+        of G.  Both None when the session runs no kernel; the share is
+        None too outside paged mode, whose union widths vary per batch."""
+        p = self.params
+        if not p.use_kernel:
+            return {"blocks_per_step": None, "padded_positions_share": None}
+        g = blocks_per_step(self.index.arrays.block_codes.shape[1])
+        padded = -(-p.max_scan // g) * g
+        share = ((padded - p.max_scan) / padded if p.exec_mode == "paged"
+                 else None)
+        return {"blocks_per_step": g, "padded_positions_share": share}
 
     # -- overridable hooks (core/stream/ swaps in the streaming pipeline) --
     def _check_current(self) -> None:

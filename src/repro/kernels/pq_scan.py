@@ -24,7 +24,12 @@ with no per-item scalar work) to the MXU:
   * grid order is (query-block, scan-position): consecutive grid steps
     for the *same* scan position across the query tile reuse the code
     tile already resident in VMEM — the TPU analogue of the paper's
-    "group tasks by list" cache optimization (§5.3).
+    "group tasks by list" cache optimization (§5.3);
+  * a grid step pages G = ``blocks_per_step(BLK)`` consecutive scan
+    positions (four blocks of 32, one block from 128 up), one paged
+    operand per block, and scores them as one (G*BLK)-row tile: a
+    step's fixed cost (pipeline bookkeeping, the small paged DMAs)
+    outweighs its work at 32 items, and G*BLK fills the 128 lanes.
 
 Production tiling notes (TPU v5e): native block size 128 (lane width)
 instead of the paper's 32 — ``block`` stays a config knob and the
@@ -59,6 +64,8 @@ from .topk import PAD_POS, bitonic_sort, merge_topf, pow2_ceil
 # bytes of scalar-prefetched scan lists one kernel call may hold in SMEM
 # (1 MiB on v5e, shared with the kernel's own scalars)
 SMEM_PREFETCH_BYTES = 256 * 1024
+# lanes of a vreg: the width one grid step fills with candidates
+_LANES = 128
 
 
 def _tiles_per_call(qb: int, s: int) -> int:
@@ -87,20 +94,27 @@ def _map_query_chunks(call, tile_idx, per_query, query_tile: int):
     return jax.tree.map(lambda y: y.reshape((-1,) + y.shape[2:]), out)
 
 
-def _tile_codes(codes_ref, packed: bool) -> jnp.ndarray:
-    """Code tile -> (BLK, M) int32 codes, unpacking nibble pairs in-VMEM.
+def _cat(parts, axis: int):
+    """Concatenate the per-block pieces of one grid step; a one-block step
+    takes its piece as is, so it lowers exactly as a single block."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
 
-    A packed tile (quant plane, two 4-bit codes per byte) carries MB =
-    M/2 bytes; the lo nibble is the even subquantizer, hi the odd —
-    the single layout defined by ``quant/nibbles.py``.  Interleaving
-    the nibbles back does not lower on TPU, so the unpacked tile is
-    ``[lo | hi]`` — every even subquantizer, then every odd one — and
-    the kernel wrappers permute the LUT rows to match
-    (``_kernel_lut``).  Callers guarantee 2*MB == lut M (ops wrappers
-    zero-pad the LUT so a padded byte's two zero codes select zero rows
-    and contribute nothing).
+
+def _tile_codes(codes_refs, packed: bool) -> jnp.ndarray:
+    """Code tiles -> (G*BLK, M) int32 codes, unpacking nibble pairs in-VMEM.
+
+    The G paged tiles of a grid step are stacked along rows (sublanes),
+    block ``g`` in rows ``g*BLK .. (g+1)*BLK``.  A packed tile (quant
+    plane, two 4-bit codes per byte) carries MB = M/2 bytes; the lo
+    nibble is the even subquantizer, hi the odd — the single layout
+    defined by ``quant/nibbles.py``.  Interleaving the nibbles back does
+    not lower on TPU, so the unpacked tile is ``[lo | hi]`` — every even
+    subquantizer, then every odd one — and the kernel wrappers permute
+    the LUT rows to match (``_kernel_lut``).  Callers guarantee 2*MB ==
+    lut M (ops wrappers zero-pad the LUT so a padded byte's two zero
+    codes select zero rows and contribute nothing).
     """
-    raw = codes_ref[0].astype(jnp.int32)                       # (BLK, MB)
+    raw = _cat([r[0].astype(jnp.int32) for r in codes_refs], 0)
     if not packed:
         return raw
     return jnp.concatenate([raw & 15, raw >> 4], axis=-1)
@@ -119,13 +133,14 @@ def _kernel_lut(lut: jnp.ndarray, packed: bool) -> jnp.ndarray:
     return lut.reshape(b, 1, m * k)
 
 
-def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
-    """(QT, BLK) ADC distances of one paged code block for a query tile.
+def _score_block(lut_ref, codes_refs, packed: bool) -> jnp.ndarray:
+    """(QT, G*BLK) ADC distances of a grid step's G paged code blocks
+    for a query tile, block ``g`` in lanes ``g*BLK .. (g+1)*BLK``.
 
     The 4-bit code of item i, subspace m selects ``lut[m, code]``; the
-    selection is a (BLK, M*K) one-hot over the flat table, contracted on
-    the MXU, so every query of the tile scores the whole block in one
-    pass.  The one-hot is built in the lane layout the contraction reads:
+    selection is a (G*BLK, M*K) one-hot over the flat table, contracted
+    on the MXU, so every query of the tile scores the step's blocks in
+    one pass.  The one-hot is built in the lane layout the contraction reads:
     an exact bf16 contraction with the 0/1 matrix ``R[m, j] = (j // K ==
     m)`` copies code (i, m) to the K lanes of subspace m (one nonzero
     product per output, codes < 256), and a compare with the lane
@@ -136,7 +151,7 @@ def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
     scoring contraction is pinned to full f32 (HIGHEST): the one-hot is
     exact in bf16 but the LUT is not, and the distances must match the
     jnp reference's."""
-    codes = _tile_codes(codes_ref, packed)                     # (BLK, M)
+    codes = _tile_codes(codes_refs, packed)                    # (G*BLK, M)
     m = codes.shape[1]
     mk = lut_ref.shape[-1]
     k = mk // m
@@ -144,7 +159,7 @@ def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
     row = jax.lax.broadcasted_iota(jnp.int32, (m, mk), 0)
     spread = (col // k == row).astype(jnp.bfloat16)            # (M, MK)
     lanes = jnp.dot(codes.astype(jnp.bfloat16), spread,
-                    preferred_element_type=jnp.float32)        # (BLK, MK)
+                    preferred_element_type=jnp.float32)        # (G*BLK, MK)
     entry = jax.lax.broadcasted_iota(jnp.int32, (1, mk), 1) % k
     oh = (lanes == entry.astype(jnp.float32)).astype(jnp.float32)
     return jax.lax.dot_general(lut_ref[:, 0, :], oh,
@@ -156,18 +171,46 @@ def _score_block(lut_ref, codes_ref, packed: bool) -> jnp.ndarray:
 def _make_kernel(packed: bool):
     """Body factory for the unfused scan (packed-ness is static)."""
 
-    def _kernel(idx_ref, lut_ref, codes_ref, out_ref):
-        """One grid step: score one code block for QT queries.
+    def _kernel(idx_ref, lut_ref, *refs):
+        """One grid step: score a step's G code blocks for QT queries.
 
         lut_ref:   (QT, 1, M*K) f32 in VMEM (``_kernel_lut``)
-        codes_ref: (1, BLK, MB) uint8 in VMEM (the paged block; MB = M,
-                   or M/2 when nibble-packed)
-        out_ref:   (QT, 1, 1, BLK) f32
+        refs:      G (1, BLK, MB) uint8 paged blocks in VMEM (MB = M, or
+                   M/2 when nibble-packed), then the (QT, 1, 1, G*BLK)
+                   f32 output block
         """
-        d = _score_block(lut_ref, codes_ref, packed)
+        *codes_refs, out_ref = refs
+        d = _score_block(lut_ref, codes_refs, packed)
         out_ref[...] = d[:, None, None, :]
 
     return _kernel
+
+
+def blocks_per_step(blk: int) -> int:
+    """Scan positions G that one grid step of either scan kernel pages
+    and scores: as many blocks of ``blk`` items as fill the 128 lanes of
+    a vreg, so the step's fixed cost (pipeline bookkeeping, the small
+    paged DMAs) is paid once per 128 candidates.  One at ``blk >= 128``."""
+    return max(1, _LANES // blk)
+
+
+def _pad_positions(x: jnp.ndarray, g: int, value: int = 0) -> jnp.ndarray:
+    """Pad the scan-position axis of a (rows, S) list to a multiple of g."""
+    pad = -x.shape[1] % g
+    return jnp.pad(x, ((0, 0), (0, pad)), constant_values=value) if pad else x
+
+
+def _position(si, j: int, g: int):
+    """Scan position of block j of grid step ``si``: ``si * g + j``, and
+    ``si`` itself at g == 1, so a one-block step lowers as it always did."""
+    return si * g + j if g > 1 else si
+
+
+def _paged_specs(shape, g: int):
+    """BlockSpecs of a grid step's g paged blocks of one plane."""
+    def paged(j):
+        return lambda qi, si, idx: (idx[qi, _position(si, j, g)], 0, 0)
+    return [pl.BlockSpec(shape, paged(j)) for j in range(g)]
 
 
 @functools.partial(jax.jit,
@@ -193,31 +236,33 @@ def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     assert (2 * mb if packed else mb) == m, (mb, m, packed)
     assert b == qb * query_tile, (b, qb, query_tile)
     lut = _kernel_lut(lut, packed)
+    g = blocks_per_step(blk)
 
     def call(idx, lut_c):
+        steps = idx.shape[1] // g
         kernel = pl.pallas_call(
             _make_kernel(packed),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=idx.shape,
+                grid=(idx.shape[0], steps),
                 in_specs=[
                     pl.BlockSpec((query_tile, 1, m * k),
                                  lambda qi, si, idx: (qi, 0, 0)),
-                    pl.BlockSpec((1, blk, mb),
-                                 lambda qi, si, idx: (idx[qi, si], 0, 0)),
+                    *_paged_specs((1, blk, mb), g),
                 ],
-                out_specs=pl.BlockSpec((query_tile, 1, 1, blk),
+                out_specs=pl.BlockSpec((query_tile, 1, 1, g * blk),
                                        lambda qi, si, idx: (qi, si, 0, 0)),
             ),
-            out_shape=jax.ShapeDtypeStruct((lut_c.shape[0], s, 1, blk),
-                                           jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((lut_c.shape[0], steps, 1,
+                                            g * blk), jnp.float32),
             interpret=interpret,
             name="pq_scan",
         )
-        return kernel(idx, lut_c, block_codes)
+        return kernel(idx, lut_c, *[block_codes] * g)
 
-    out = _map_query_chunks(call, tile_idx, [lut], query_tile)
-    return out.reshape(b, s, blk)
+    out = _map_query_chunks(call, _pad_positions(tile_idx, g), [lut],
+                            query_tile)
+    return out.reshape(b, -1, blk)[:, :s]
 
 
 def pq_scan_paged_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
@@ -261,16 +306,22 @@ def pq_scan_paged_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
                                 packed=packed)
 
 
-def _make_topk_kernel(query_tile: int, blk: int, f: int, with_dead: bool,
+def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
                       packed: bool = False):
-    """Kernel body factory for the fused scan->top-k (shapes are static)."""
+    """Kernel body factory for the fused scan->top-k (shapes are static).
 
-    def kernel(idx_ref, lut_ref, codes_ref, bids_ref, bother_ref, rank_ref,
-               slot_ref, ranku_ref, *rest):
-        if with_dead:
-            (dead_ref, acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref) = rest
-        else:
-            (acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref) = rest
+    One grid step scores the ``g`` scan positions ``si*g .. si*g+g-1``:
+    their blocks arrive as ``g`` paged operands per plane, and lane
+    ``l`` of the step's (QT, g*BLK) rows is item ``l % BLK`` of block
+    ``l // BLK``."""
+    n = g * blk
+
+    def kernel(idx_ref, lut_ref, *refs):
+        codes_refs, bids_refs, bother_refs = (refs[:g], refs[g:2 * g],
+                                              refs[2 * g:3 * g])
+        rank_ref, slot_ref, ranku_ref = refs[3 * g:3 * g + 3]
+        dead_refs = refs[3 * g + 3:4 * g + 3] if with_dead else ()
+        acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref = refs[-4:]
         qt = lut_ref.shape[0]
         si = pl.program_id(1)
 
@@ -284,55 +335,69 @@ def _make_topk_kernel(query_tile: int, blk: int, f: int, with_dead: bool,
             acc_id_ref[...] = jnp.full((qt, 1, f), -1, jnp.int32)
             dco_ref[...] = jnp.zeros((qt, 1, 1), jnp.int32)
 
-        # -- score the paged block: the unfused kernel's contraction, so
+        # -- score the paged blocks: the unfused kernel's contraction, so
         # distances are bitwise identical
-        d = _score_block(lut_ref, codes_ref, packed)           # (QT, BLK)
+        d = _score_block(lut_ref, codes_refs, packed)          # (QT, n)
 
         # -- in-kernel keep mask (Alg. 5 L15-16, scan_blocks' post-hoc
         # logic moved here): invalid slots/absent union positions
         # (slot < 0), invalid items (id < 0), and misc duplicates whose
         # co-assigned list was scanned at an earlier probe rank
-        ids = bids_ref[0]                                      # (1, BLK)
-        other = bother_ref[0]                                  # (1, BLK)
-        # this scan position's column of the resident (QT, S) sidecars
+        ids = _cat([r[0] for r in bids_refs], 1)               # (1, n)
+        other = _cat([r[0] for r in bother_refs], 1)           # (1, n)
+        # each scan position's column of the resident (QT, S) sidecars,
+        # spread over its block's lanes: (QT, 1) at g == 1, else (QT, n)
         s_lane = jax.lax.broadcasted_iota(jnp.int32, slot_ref.shape, 2)
-        slot = jnp.sum(jnp.where(s_lane == si, slot_ref[...], 0),
-                       axis=2)                                 # (QT, 1)
-        ranku = jnp.sum(jnp.where(s_lane == si, ranku_ref[...], 0), axis=2)
+        if g > 1:
+            group = jax.lax.broadcasted_iota(jnp.int32, (qt, n), 1) // blk
+
+        def per_lane(ref):
+            cols = [jnp.sum(jnp.where(s_lane == _position(si, j, g),
+                                      ref[...], 0), axis=2)
+                    for j in range(g)]                         # (QT, 1) each
+            out = cols[-1]
+            for j in range(g - 2, -1, -1):
+                out = jnp.where(group == j, cols[j], out)
+            return out
+
+        slot = per_lane(slot_ref)
+        ranku = per_lane(ranku_ref)
         # rank_of[q, other[i]]: a lane gather over nlist does not lower,
         # so contract the rank rows with a one-hot of `other` instead —
         # exact at HIGHEST (ranks < 2^24, BIG = 2^30)
         nlist = rank_ref.shape[2]
-        o_hot = (jax.lax.broadcasted_iota(jnp.int32, (nlist, blk), 0)
+        o_hot = (jax.lax.broadcasted_iota(jnp.int32, (nlist, n), 0)
                  == jnp.maximum(other, 0)).astype(jnp.float32)
         orank = jnp.dot(rank_ref[:, 0, :].astype(jnp.float32), o_hot,
                         precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32)    # (QT, BLK)
+                        preferred_element_type=jnp.float32)    # (QT, n)
         dup = (other >= 0) & (orank < ranku.astype(jnp.float32))
         item_ok = (ids >= 0) & (slot >= 0)
         keep = item_ok & ~dup
         if with_dead:
             # tombstoned candidates must not consume accumulator slots
             # (they are ADC-computed — DCO counts them — then discarded)
-            keep &= dead_ref[0].astype(jnp.int32) == 0
+            keep &= _cat([r[0].astype(jnp.int32) for r in dead_refs], 1) == 0
         dco_ref[...] += jnp.sum(item_ok.astype(jnp.int32), axis=1,
                                 keepdims=True)[:, None, :]
 
-        # -- candidate triple in plan layout: pos = slot*BLK + lane is the
+        # -- candidate triple in plan layout: pos = slot*BLK + item is the
         # flat position of the unfused stream, the lax.top_k tie-break.
-        # The block is sorted descending so its best F sit at the end,
-        # ready for merge_topf's half-cleaner against the ascending
-        # accumulator
-        lane = jax.lax.broadcasted_iota(jnp.int32, (qt, blk), 1)
+        # The step's candidates are sorted descending so their best F sit
+        # at the end, ready for merge_topf's half-cleaner against the
+        # ascending accumulator
+        lane = jax.lax.broadcasted_iota(jnp.int32, (qt, n), 1)
+        if g > 1:
+            lane &= blk - 1                       # item within its block
         pos = slot * blk + lane
         new = bitonic_sort([jnp.where(keep, d, jnp.inf),
                             jnp.where(keep, pos, PAD_POS),
                             jnp.where(keep, ids, -1)], descending=True)
-        if blk >= f:
-            # candidates beyond a block's own top-F can never survive
-            new = [x[:, blk - f:] for x in new]
+        if n >= f:
+            # candidates beyond a step's own top-F can never survive
+            new = [x[:, n - f:] for x in new]
         else:
-            pad = ((0, 0), (f - blk, 0))
+            pad = ((0, 0), (f - n, 0))
             new = [jnp.pad(new[0], pad, constant_values=jnp.inf),
                    jnp.pad(new[1], pad, constant_values=PAD_POS),
                    jnp.pad(new[2], pad, constant_values=-1)]
@@ -374,11 +439,15 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     included — exactly ``scan_blocks``' accounting).  The accumulator
     triple lives in VMEM for the whole inner grid pass (out BlockSpecs
     constant in the scan dimension), as do the query tile's rank table
-    and (S,) sidecar rows; each step is one bitonic sort of the block +
-    one bitonic merge against the accumulator (kernels/topk.py), keyed
-    lexicographically by (d, pos) so the result is bitwise the stable
-    ``preselect_candidates`` selection over the unfused stream with
-    masked entries at ``(+inf, PAD_POS, -1)``.
+    and (S,) sidecar rows.  Each grid step pages G = ``blocks_per_step
+    (BLK)`` scan positions, one paged operand per block and plane, so a
+    step fills the 128 lanes with candidates; the lists are padded to a
+    multiple of G with block 0 at ``slot = -1``, masked and uncounted
+    like an absent union position.  Each step is one bitonic sort of its
+    G*BLK candidates + one bitonic merge against the accumulator
+    (kernels/topk.py), keyed lexicographically by (d, pos) so the result
+    is bitwise the stable ``preselect_candidates`` selection over the
+    unfused stream with masked entries at ``(+inf, PAD_POS, -1)``.
     """
     b, m, k = lut.shape
     qb, s = tile_idx.shape
@@ -391,42 +460,42 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     f = pow2_ceil(max(fetch, 1))
     nlist = rank_of.shape[1]
     with_dead = dead is not None
+    g = blocks_per_step(blk)
+    # padding positions page block 0 at slot -1: masked, never counted
+    tile_idx = _pad_positions(tile_idx.astype(jnp.int32), g)
+    slot_of = _pad_positions(slot_of.astype(jnp.int32), g, -1)
+    rank_u = _pad_positions(rank_u.astype(jnp.int32), g)
+    s = tile_idx.shape[1]
 
     def row(x):   # per-block (TB, BLK) plane -> (TB, 1, BLK)
         return x.reshape(tb, 1, blk)
 
-    def paged(qi, si, idx):
-        return (idx[qi, si], 0, 0)
-
     def tile(qi, si, idx):
         return (qi, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((query_tile, 1, m * k), tile),
-        pl.BlockSpec((1, blk, mb), paged),
-        pl.BlockSpec((1, 1, blk), paged),
-        pl.BlockSpec((1, 1, blk), paged),
-        pl.BlockSpec((query_tile, 1, nlist), tile),
-        pl.BlockSpec((query_tile, 1, s), tile),
-        pl.BlockSpec((query_tile, 1, s), tile),
-    ]
-    blocks = [block_codes, row(block_ids.astype(jnp.int32)),
-              row(block_other.astype(jnp.int32))]
+    in_specs = [pl.BlockSpec((query_tile, 1, m * k), tile),
+                *_paged_specs((1, blk, mb), g),
+                *_paged_specs((1, 1, blk), g),
+                *_paged_specs((1, 1, blk), g),
+                pl.BlockSpec((query_tile, 1, nlist), tile),
+                pl.BlockSpec((query_tile, 1, s), tile),
+                pl.BlockSpec((query_tile, 1, s), tile)]
+    blocks = [block_codes] * g + [row(block_ids.astype(jnp.int32))] * g \
+        + [row(block_other.astype(jnp.int32))] * g
     per_query = [_kernel_lut(lut, packed),
                  rank_of.astype(jnp.int32).reshape(b, 1, nlist),
-                 slot_of.astype(jnp.int32).reshape(b, 1, s),
-                 rank_u.astype(jnp.int32).reshape(b, 1, s)]
+                 slot_of.reshape(b, 1, s), rank_u.reshape(b, 1, s)]
     if with_dead:
-        in_specs.append(pl.BlockSpec((1, 1, blk), paged))
-        dead_row = row(dead.astype(jnp.uint8))
+        in_specs += _paged_specs((1, 1, blk), g)
+        dead_rows = [row(dead.astype(jnp.uint8))] * g
 
     def call(idx, lut_c, rank_c, slot_c, ranku_c):
         bc = lut_c.shape[0]
         kernel = pl.pallas_call(
-            _make_topk_kernel(query_tile, blk, f, with_dead, packed),
+            _make_topk_kernel(blk, f, g, with_dead, packed),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=idx.shape,
+                grid=(idx.shape[0], idx.shape[1] // g),
                 in_specs=in_specs,
                 out_specs=[pl.BlockSpec((query_tile, 1, f), tile)] * 3
                 + [pl.BlockSpec((query_tile, 1, 1), tile)]),
@@ -441,10 +510,10 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
         )
         operands = [lut_c, *blocks, rank_c, slot_c, ranku_c]
         if with_dead:
-            operands.append(dead_row)
+            operands += dead_rows
         return tuple(kernel(idx, *operands))
 
     acc_d, acc_pos, acc_id, dco = _map_query_chunks(
-        call, tile_idx.astype(jnp.int32), per_query, query_tile)
+        call, tile_idx, per_query, query_tile)
     return (acc_d[:, 0, :fetch], acc_pos[:, 0, :fetch], acc_id[:, 0, :fetch],
             dco[:, 0, 0])

@@ -135,7 +135,8 @@ def snapshot_all(*, gateway=None, gateway_stats: Optional[dict] = None,
       schema_version  int — bump on layout changes.
       session   ``Searcher.compile_stats()``: compiles /
                 warmup_compiles / calls / dispatches / cache_hits /
-                padded_rows / refined_rows / buckets / topk_width,
+                padded_rows / refined_rows / buckets / topk_width /
+                blocks_per_step / padded_positions_share,
                 plus ``plan`` (hit_rate,
                 hits/extends/misses, mean_union_live / mean_own_live /
                 mean_width) when the session runs plan_reuse.

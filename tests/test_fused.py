@@ -21,6 +21,7 @@ from repro.core.engine import (BlockStore, QueryPlan, preselect_candidates,
 from repro.core.params import SearchParams
 from repro.core.search import seil_search
 from repro.kernels.topk import PAD_POS, bitonic_sort, merge_topf, pow2_ceil
+from repro.quant.nibbles import pack_nibbles
 
 EXEC_MODES = ("paged", "grouped", "clustered")
 
@@ -170,13 +171,15 @@ def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, nid=200,
 
 
 def _unfused_reference(store, plan, lut, rank_of, sel, live, fetch,
-                       exec_mode, use_kernel=False):
+                       exec_mode, use_kernel=False, query_tile=4,
+                       packed=False):
     """scan_blocks + live mask + stable preselect — the ground truth the
     fused stage must reproduce bitwise.  ``use_kernel`` must match the
     fused side so both streams carry the same ADC rounding (one-hot
     dot_general vs gather-sum differ in the last ulp)."""
     out = scan_blocks(store, plan, lut, rank_of, exec_mode=exec_mode,
-                      sel=sel, use_kernel=use_kernel, query_tile=4)
+                      sel=sel, use_kernel=use_kernel, query_tile=query_tile,
+                      packed=packed)
     d = out.flat_d
     if live is not None:
         dead = (out.flat_i >= 0) & ~live[jnp.maximum(out.flat_i, 0)]
@@ -230,23 +233,35 @@ def test_scan_blocks_topk_fetch_clamped_to_stream():
 
 # satellite: hypothesis property — fused candidate order equals the
 # stable preselect over the unfused stream for random plans, duplicate
-# distances/ids, and tombstones, in both fused implementations.
+# distances/ids, and tombstones, in both fused implementations.  The
+# kernels page G = 128 // BLK scan positions per grid step (one at BLK
+# 128), so the (BLK, S) cases cover S not a multiple of G, down to one
+# padded step: padding positions must neither add candidates nor count
+# in approx_dco.
+@pytest.mark.parametrize("blk,s", [(8, 5), (32, 1), (32, 5), (32, 7),
+                                   (128, 3)])
 @settings(max_examples=12, deadline=None)
 @given(seed=st.integers(0, 10 ** 6), exec_mode=st.sampled_from(EXEC_MODES),
-       blk=st.sampled_from([8, 32]), s=st.integers(1, 6),
        fetch=st.sampled_from([1, 8, 24]), use_kernel=st.booleans(),
-       with_live=st.booleans())
-def test_property_fused_topk_order(seed, exec_mode, blk, s, fetch,
-                                   use_kernel, with_live):
+       with_live=st.booleans(), packed=st.booleans(),
+       query_tile=st.sampled_from([1, 4]))
+def test_property_fused_topk_order(blk, s, seed, exec_mode, fetch,
+                                   use_kernel, with_live, packed,
+                                   query_tile):
     store, plan, lut, rank_of, sel, live = _synth(
         seed, s=s, blk=blk, tie_heavy=True)
+    if packed:
+        store = store._replace(block_codes=jnp.asarray(
+            pack_nibbles(np.asarray(store.block_codes))))
     live = live if with_live else None
     ref_d, ref_i, ref_dco = _unfused_reference(
         store, plan, lut, rank_of, sel, live,
-        min(fetch, s * blk), exec_mode, use_kernel=use_kernel)
+        min(fetch, s * blk), exec_mode, use_kernel=use_kernel,
+        query_tile=query_tile, packed=packed)
     out = scan_blocks_topk(store, plan, lut, rank_of, fetch=fetch,
                            exec_mode=exec_mode, use_kernel=use_kernel,
-                           query_tile=4, sel=sel, live=live)
+                           query_tile=query_tile, sel=sel, live=live,
+                           packed=packed)
     np.testing.assert_array_equal(np.asarray(out.flat_d), np.asarray(ref_d))
     np.testing.assert_array_equal(np.asarray(out.flat_i), np.asarray(ref_i))
     np.testing.assert_array_equal(np.asarray(out.approx_dco),

@@ -12,7 +12,8 @@ from jax.extend import core as jcore
 from repro.kernels.ops import (_align, pq_scan_grouped, pq_scan_paged,
                                pq_scan_tiled)
 from repro.kernels.pq_scan import (_kernel_lut, _score_block,
-                                   pq_scan_tiled_kernel, pq_scan_topk_kernel)
+                                   blocks_per_step, pq_scan_tiled_kernel,
+                                   pq_scan_topk_kernel)
 from repro.kernels.ref import onehot_lut_ref, pq_scan_paged_ref
 from repro.quant.nibbles import pack_nibbles
 
@@ -24,6 +25,8 @@ from repro.quant.nibbles import pack_nibbles
     (2, 16, 16, 7, 128, 3),
     (2, 32, 8, 5, 64, 4),     # 3-bit-table variant
     (16, 2, 16, 4, 32, 1),
+    (3, 8, 16, 9, 32, 7),     # 7 positions at 4 blocks per grid step
+    (2, 8, 16, 6, 8, 5),      # 16 blocks of 8 per step, 11 padding
 ])
 def test_pq_scan_paged_matches_ref(b, m, k, tb, blk, s):
     key = jax.random.PRNGKey(b * 131 + m)
@@ -86,13 +89,21 @@ def test_tiled_mode_per_tile_lists():
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("blk,g", [(8, 16), (32, 4), (64, 2), (128, 1),
+                                   (256, 1)])
+def test_blocks_per_step(blk, g):
+    """A grid step of either scan kernel pages the blocks that fill the
+    128 lanes: four at the deployments' block 32, one from 128 up."""
+    assert blocks_per_step(blk) == g
+
+
 def _score_one_block(lut, codes, packed):
     """``_score_block`` over one code block, interpreted: lut (QT, M, K),
     codes (BLK, MB) -> (QT, BLK), fed the flat LUT the kernels read."""
     qt, blk = lut.shape[0], codes.shape[0]
 
     def body(lut_ref, codes_ref, out_ref):
-        out_ref[...] = _score_block(lut_ref, codes_ref, packed)
+        out_ref[...] = _score_block(lut_ref, (codes_ref,), packed)
 
     return pl.pallas_call(
         body, out_shape=jax.ShapeDtypeStruct((qt, blk), jnp.float32),

@@ -142,6 +142,16 @@ def test_searcher_reports_fused_topk_width(rairs_index):
         fetch = min(10 * k_factor * over, sess.params.max_scan * blk)
         assert sess.compile_stats()["topk_width"] == pow2_ceil(fetch)
     assert sess.compile_stats()["topk_width"] == pow2_ceil(8 * blk)
+    # the kernel pages four blocks of 32 per grid step: max_scan 8 needs
+    # no padding, 6 pads two of eight positions
+    assert blk == 32
+    assert sess.compile_stats()["blocks_per_step"] == 4
+    assert sess.compile_stats()["padded_positions_share"] == 0
+    six = rairs_index.searcher(SearchParams(
+        k=10, nprobe=4, max_scan=6, use_kernel=True)).compile_stats()
+    assert (six["blocks_per_step"], six["padded_positions_share"]) == (4, 0.25)
+    plain = rairs_index.searcher(SearchParams(k=10, nprobe=4))
+    assert plain.compile_stats()["blocks_per_step"] is None
 
 
 def test_index_search_wrapper_reuses_sessions(rairs_index, unit_data):

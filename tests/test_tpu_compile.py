@@ -7,7 +7,9 @@ widths the chip runs: M=64 at 4 bits padded to 128, K=16, fetch=128,
 nlist=4096, blocks 32 and 128, unpacked and nibble-packed, with and
 without the tombstone plane, query tiles 1 to 8, a 1024-query batch
 whose scan lists need the SMEM chunking, and the fused kernel at the
-exact shapes of the SIFT1M and Text-to-Image 1M paged deployments.  Shapes only; nothing
+exact shapes of the SIFT1M and Text-to-Image 1M paged deployments,
+where a grid step pages four blocks of 32 (SIFT1M's 557 scan positions
+padded to 560).  Shapes only; nothing
 runs.  The topology is described inside a module fixture (never at
 import), and the persistent compilation cache is off around the
 compiles: an entry written for a described chip cannot be read back.
@@ -92,25 +94,32 @@ def test_topk_kernel_compiles(one_chip, blk, packed, dead, qt, b):
         *args)
 
 
-@pytest.mark.parametrize("packed", [False, True])
-def test_topk_kernel_compiles_at_sift1m_paged_shape(one_chip, packed):
+@pytest.mark.parametrize("packed,dead", [(False, False), (True, False),
+                                         (False, True)],
+                         ids=["False", "True", "dead"])
+def test_topk_kernel_compiles_at_sift1m_paged_shape(one_chip, packed, dead):
     """The fused kernel as the SIFT1M paged deployment runs it: a flat
-    (B, 1, M*K) LUT from the wrapper, query tile 1, block 32, M 64
-    padded to 128, nlist 4096, 557 scan positions, fetch 100, the
-    index's 54,140 blocks and a 1024-query batch; packed or not."""
+    (B, 1, M*K) LUT from the wrapper, query tile 1, block 32 (four
+    blocks per grid step), M 64 padded to 128, nlist 4096, 557 scan
+    positions (padded to 560), fetch 100, the index's 54,140 blocks and
+    a 1024-query batch; packed or not, and with the streaming index's
+    tombstone plane."""
     b, blk, tb, s, fetch = 1024, 32, 54140, 557, 100
     mb = M // 2 if packed else M
+    args = [_spec(one_chip, (b, M, K), jnp.float32),
+            _spec(one_chip, (tb, blk, mb), jnp.uint8),
+            _spec(one_chip, (tb, blk), jnp.int32),
+            _spec(one_chip, (tb, blk), jnp.int32),
+            _spec(one_chip, (b, s), jnp.int32),
+            _spec(one_chip, (b, NLIST), jnp.int32),
+            _spec(one_chip, (b, s), jnp.int32),
+            _spec(one_chip, (b, s), jnp.int32)]
+    if dead:
+        args.append(_spec(one_chip, (tb, blk), jnp.uint8))
     _compiled_kernel(
         lambda *a: pq_scan_topk_kernel(*a, query_tile=1, fetch=fetch,
                                        packed=packed),
-        _spec(one_chip, (b, M, K), jnp.float32),
-        _spec(one_chip, (tb, blk, mb), jnp.uint8),
-        _spec(one_chip, (tb, blk), jnp.int32),
-        _spec(one_chip, (tb, blk), jnp.int32),
-        _spec(one_chip, (b, s), jnp.int32),
-        _spec(one_chip, (b, NLIST), jnp.int32),
-        _spec(one_chip, (b, s), jnp.int32),
-        _spec(one_chip, (b, s), jnp.int32))
+        *args)
 
 
 @pytest.mark.parametrize("fetch", [500, 1000])
