@@ -10,20 +10,19 @@ One chip, one process:
                 (``sift1m_full``), generated on the chip from ``--seed``;
   2. build      ``build_index`` with nlist=4096, RAIR + SEIL (block 32);
   3. reference  exact top-10 by brute force at full f32 precision;
-  4. search     ``index.searcher(...)`` with the Pallas kernels compiled
+  4. search     ``index.searcher(...)`` with the Pallas kernel compiled
                 (checked in the executable's HLO) in the paged and
-                clustered exec modes, unfused and with the fused top-k;
-                ids must agree with the jnp path on >= 99% of
+                clustered exec modes; ids must agree with the jnp path on >= 99% of
                 (query, rank) entries; recall@10 against the reference;
   5. gateway    a few hundred single-query requests through ``Gateway``
-                + ``run_open_loop`` on the fused kernel path: no errors,
+                + ``run_open_loop`` on the kernel path: no errors,
                 every request answered;
-  6. fidelity   the 100k ``sift1m`` stand-in at nlist=256 / nprobe=16
-                (the configuration of BENCH_engine.json): recall must lie
-                within 0.01 of the same code's recall on CPU.
+  6. fidelity   the 100k ``sift1m`` stand-in at nlist=256 / nprobe=16:
+                recall must lie within 0.01 of the same code's recall on
+                CPU.
 
 ``--four-chips`` builds the same corpus, shards it over a 4-device mesh
-(``index.shard``) and compares the fused kernel path with the one-chip
+(``index.shard``) and compares the kernel path with the one-chip
 searcher in the same process: block arrays on all four devices, ids
 equal up to tie order, recall within 0.005.  It runs no other phase.
 
@@ -131,24 +130,22 @@ def search_phase(index, q, gt, times: dict) -> None:
         jax.block_until_ready(ref.ids)
     log(f"jnp: recall@10={recall_at_k(np.asarray(ref.ids), gt):.4f}")
     for mode in ("paged", "clustered"):
-        for fused in (False, True):
-            name = f"{mode}_kernel{'_fused' if fused else ''}"
-            sess = index.searcher(SearchParams(
-                k=10, nprobe=32, exec_mode=mode, use_kernel=True,
-                fused_topk=fused))
-            with phase(f"search_{name}", times):
-                res = sess(q)
-                jax.block_until_ready(res.ids)
-            rec = recall_at_k(np.asarray(res.ids), gt)
-            agree = id_agreement(res.ids, ref.ids)
-            log(f"{name}: recall@10={rec:.4f} id agreement with jnp="
-                f"{agree:.4f}")
-            check(kernel_in_hlo(sess, q.shape[0]),
-                  f"{name}: Pallas kernel compiled (tpu_custom_call in HLO)")
-            check(agree >= MIN_ID_AGREEMENT,
-                  f"{name}: id agreement {agree:.4f} >= {MIN_ID_AGREEMENT}")
-            check(bool(np.isfinite(np.asarray(res.dists)).all()),
-                  f"{name}: finite distances")
+        name = f"{mode}_kernel"
+        sess = index.searcher(SearchParams(
+            k=10, nprobe=32, exec_mode=mode, use_kernel=True))
+        with phase(f"search_{name}", times):
+            res = sess(q)
+            jax.block_until_ready(res.ids)
+        rec = recall_at_k(np.asarray(res.ids), gt)
+        agree = id_agreement(res.ids, ref.ids)
+        log(f"{name}: recall@10={rec:.4f} id agreement with jnp="
+            f"{agree:.4f}")
+        check(kernel_in_hlo(sess, q.shape[0]),
+              f"{name}: Pallas kernel compiled (tpu_custom_call in HLO)")
+        check(agree >= MIN_ID_AGREEMENT,
+              f"{name}: id agreement {agree:.4f} >= {MIN_ID_AGREEMENT}")
+        check(bool(np.isfinite(np.asarray(res.dists)).all()),
+              f"{name}: finite distances")
 
 
 def gateway_phase(index, q, times: dict) -> None:
@@ -156,7 +153,7 @@ def gateway_phase(index, q, times: dict) -> None:
     from repro.core import SearchParams
     from repro.gateway import Gateway, GatewayConfig, run_open_loop
     params = SearchParams(k=10, nprobe=32, exec_mode="paged",
-                          use_kernel=True, fused_topk=True)
+                          use_kernel=True)
     n_requests = 300
     with phase("gateway", times):
         with Gateway(index, params,
@@ -175,7 +172,7 @@ def gateway_phase(index, q, times: dict) -> None:
 
 
 def fidelity_recall(seed: int = 0) -> float:
-    """recall@10 of the 100k configuration behind BENCH_engine.json."""
+    """recall@10 of the 100k ``sift1m`` stand-in at nlist=256."""
     import jax
     import numpy as np
     from repro.core import (IndexConfig, SearchParams, build_index,
@@ -225,7 +222,7 @@ def four_chips(args, devices) -> None:
     with phase("exact_reference", times):
         gt = ground_truth(index.vectors, q, 10)
     params = SearchParams(k=10, nprobe=32, exec_mode="paged",
-                          use_kernel=True, fused_topk=True)
+                          use_kernel=True)
     with phase("search_one_chip", times):
         one = index.searcher(params)(q)
         jax.block_until_ready(one.ids)

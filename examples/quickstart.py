@@ -57,24 +57,18 @@ with tempfile.TemporaryDirectory() as td:
     print(f"save/load round-trip: identical results "
           f"({os.path.getsize(bundle) / 1e6:.1f}MB bundle)")
 
-# 6. the same search through the Pallas TPU kernel path (interpret on CPU)
+# 6. the same search through the Pallas TPU kernel (interpret on CPU): the
+#    scan stage emits only the bigK*oversample candidates finalize
+#    selects, kept by a VMEM-resident bitonic accumulator inside the
+#    kernel, instead of writing the full (S, BLK) score tensor to HBM
+#    (DESIGN.md §9)
 kp = SearchParams(k=10, nprobe=6, use_kernel=True)
 res_k = index.searcher(kp)(queries[:8])
 res_j = index.searcher(params)(queries[:8])
 assert np.array_equal(np.asarray(res_k.ids), np.asarray(res_j.ids))
-print("pallas pq_scan kernel path == jnp path (8 queries checked)")
+print("pallas scan->top-k kernel path == jnp path (8 queries checked)")
 
-# 7. fused scan->top-k: the scan stage emits only the bigK*oversample
-#    candidates finalize actually selects, instead of round-tripping the
-#    full (S, BLK) score tensor through HBM; with use_kernel=True the
-#    selection runs inside the Pallas kernel as a VMEM-resident bitonic
-#    accumulator.  Results are bitwise identical either way (DESIGN.md §9)
-fp = SearchParams(k=10, nprobe=6, use_kernel=True, fused_topk=True)
-res_f = index.searcher(fp)(queries[:8])
-assert np.array_equal(np.asarray(res_f.ids), np.asarray(res_k.ids))
-print("fused scan->top-k path == unfused path (8 queries checked)")
-
-# 8. a mesh is a deployment detail: shard the index and serve through the
+# 7. a mesh is a deployment detail: shard the index and serve through the
 #    *same* session API (1-device mesh here; bitwise-identical results —
 #    on a real pod only the mesh constructor changes)
 mesh = jax.make_mesh((len(jax.devices()),), ("data",))
@@ -84,7 +78,7 @@ assert np.array_equal(np.asarray(res_m.ids), np.asarray(res.ids[:64]))
 print(f"sharded ({sharded.ndev}-device) session == single-host session; "
       f"stats: {sharded.searcher_stats()}")
 
-# 9. steady-state serving with the locality-aware planner: clustered
+# 8. steady-state serving with the locality-aware planner: clustered
 #    execution buckets each batch by probed-list overlap (per-tile block
 #    unions) and plan_reuse carries those unions across adjacent batches
 #    — watch the plan-cache hit rate climb while results stay bitwise
@@ -106,7 +100,7 @@ print(f"steady-state plan cache after 5 batches: "
       f"tile_union~{plan['mean_union_live']:.0f} blocks "
       f"(scan width {plan['mean_width']:.0f})")
 
-# 10. serving *traffic* instead of batches: the async gateway coalesces
+# 9. serving *traffic* instead of batches: the async gateway coalesces
 #     single-query submissions into the same compiled buckets (flush on
 #     a 2ms deadline or a full bucket) and keeps first-class telemetry —
 #     batch_fill > 1 is the whole point (DESIGN.md §10).  On a
@@ -126,7 +120,7 @@ with Gateway(index, params,
           f"(batch_fill={snap['batch_fill']:.1f}, "
           f"p99={snap['latency']['p99_ms']:.1f}ms)")
 
-# 11. x-ray the dispatch: with a tracer active every engine stage is a
+# 10. x-ray the dispatch: with a tracer active every engine stage is a
 #     fenced span (device time + per-stage DCO); off, tracing costs
 #     literally nothing and results are bitwise identical either way.
 #     write_trace emits Chrome/Perfetto trace-event JSON — drop it on
@@ -151,7 +145,7 @@ print(f"traced dispatch == untraced (64 queries); per-stage {stages}; "
       f"attribution={snap['trace']['stage_attribution']:.0%} -> "
       f"{trace_path}")
 
-# 12. the two-tier quantization ladder (DESIGN.md §12): tier-1 scans a
+# 11. the two-tier quantization ladder (DESIGN.md §12): tier-1 scans a
 #     compact code plane (here coarse 4-bit PQ) keeping a widened
 #     bigK * refine_factor survivor set, tier-2 re-ranks the survivors
 #     exactly — same engine, same sessions, just cheaper scanning.
@@ -174,7 +168,7 @@ print(f"two-tier pq4/rf4: recall@10="
       f"subquantizers -> modeled total-ops "
       f"{model['total_ops_reduction_x']:.2f}x cheaper; rf=1 == single-tier")
 
-# 13. overload resilience (DESIGN.md §13): the same gateway, now with a
+# 12. overload resilience (DESIGN.md §13): the same gateway, now with a
 #     bounded queue.  Unbounded, a burst past capacity just queues (and
 #     p99 grows with the backlog); bounded with overload="reject", the
 #     excess fails *fast and typed* — submit returns an already-failed

@@ -1,12 +1,12 @@
 """JAX's persistent compilation cache, set once by each entry point.
 
-``enable()`` is called from ``main`` of ``chip_smoke.py``,
-``repro.launch.serve`` and ``benchmarks/run.py`` — never at import of a
-library module.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
-it from the environment and this sets no other directory.  Otherwise
-the cache lives at the fixed ``<checkout>/.jax_cache``: the directory
-is part of the cache key, so it is never derived from a temp dir, a
-PID or the time.
+``enable()`` is called from ``main`` of ``chip_smoke.py`` and
+``repro.launch.serve``, and by the benchmark's ``bench/harness.py`` —
+never at import of a library module.  Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it from the environment
+and this sets no other directory.  Otherwise the cache lives at the
+fixed ``<checkout>/.jax_cache``: the directory is part of the cache
+key, so it is never derived from a temp dir, a PID or the time.
 """
 from __future__ import annotations
 

@@ -19,13 +19,13 @@ Sharding scheme:
 Per query batch each device composes the SAME engine stages as the
 single-host searcher (core/engine/, DESIGN.md §5): ``select_lists``
 runs replicated, ``plan_blocks`` windows the deduplicated candidate
-set to the device's block range, ``scan_blocks`` scans the local
-``BlockStore`` in either exec mode, and the shared finalize tail is
-split around two small collectives: a local stable top-fetch
-(``preselect_candidates``) + one ``all_gather`` merges candidate
-streams, then ``finalize_candidates`` refines owner-scored exact
-distances with one ``pmin`` — two collectives per batch instead of
-moving vector data.
+set to the device's block range, ``scan_blocks_topk`` scans the local
+``BlockStore`` in any exec mode and keeps its stable top-fetch, and the
+shared finalize tail is split around two small collectives: a local
+stable top-fetch (``preselect_candidates``, over the scan's stream and
+the delta's) + one ``all_gather`` merges candidate streams, then
+``finalize_candidates`` refines owner-scored exact distances with one
+``pmin`` — two collectives per batch instead of moving vector data.
 
 ``build_serve_step`` is the only lowering entry point; ``ShardedSearcher``
 AOT-compiles it per batch bucket through the ``Searcher._lower`` hook.
@@ -39,8 +39,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh  # noqa: F401  (re-export for callers)
 
 from .engine import (BlockStore, ListTables, finalize_candidates,
-                     plan_blocks, preselect_candidates, scan_blocks,
-                     scan_blocks_topk, select_lists)
+                     plan_blocks, preselect_candidates, scan_blocks_topk,
+                     select_lists)
 from .params import SearchParams
 from .pq import PQCodebook, pq_lut, pq_lut_ip
 from .search import SearchResult
@@ -52,8 +52,7 @@ def build_serve_step(*, nprobe: int, bigk: int, k: int, max_scan_local: int,
                      oversample: int = 2, exec_mode: str = "paged",
                      query_tile: int = 8, axes=("data",), ndev: int = 1,
                      streaming: bool = False, use_kernel: bool = False,
-                     fused_topk: bool = False, stage: str = "all",
-                     packed_codes: bool = False):
+                     stage: str = "all", packed_codes: bool = False):
     """Build the per-device serve step for shard_map.
 
     Returns ``serve(block_codes, block_ids, block_other, owned,
@@ -106,20 +105,14 @@ def build_serve_step(*, nprobe: int, bigk: int, k: int, max_scan_local: int,
             # sel feeds the clustered exec mode: the cluster order is derived
             # from the replicated selection, so every device permutes its
             # (locally windowed) plan identically — per-device plans ride the
-            # same clustering with their own per-tile local unions
-            if fused_topk:
-                # the fused scan's width-fetch output IS the per-device
-                # preselect — tombstones applied pre-selection via ``live``
-                scan = scan_blocks_topk(
-                    store, plan, lut, selection.rank_of, fetch=fetch,
-                    exec_mode=exec_mode, use_kernel=use_kernel,
-                    query_tile=query_tile, sel=selection.sel,
-                    live=live if streaming else None, packed=packed_codes)
-            else:
-                scan = scan_blocks(store, plan, lut, selection.rank_of,
-                                   exec_mode=exec_mode, use_kernel=use_kernel,
-                                   query_tile=query_tile, sel=selection.sel,
-                                   packed=packed_codes)
+            # same clustering with their own per-tile local unions.  The
+            # scan's width-fetch output IS the per-device preselect —
+            # tombstones applied pre-selection via ``live``
+            scan = scan_blocks_topk(
+                store, plan, lut, selection.rank_of, fetch=fetch,
+                exec_mode=exec_mode, use_kernel=use_kernel,
+                query_tile=query_tile, sel=selection.sel,
+                live=live if streaming else None, packed=packed_codes)
             flat_d, flat_i = scan.flat_d, scan.flat_i
             approx_dco = scan.approx_dco
 
@@ -138,16 +131,16 @@ def build_serve_step(*, nprobe: int, bigk: int, k: int, max_scan_local: int,
                 flat_d = jnp.concatenate([flat_d, dd], axis=1)
                 flat_i = jnp.concatenate([flat_i, di], axis=1)
                 # tombstone mask over the whole id space, replicated (the
-                # fused base stream is already live-masked; re-masking it
-                # here is idempotent, and the delta needs it either way)
+                # base stream is already live-masked; re-masking it here
+                # is idempotent, and the delta needs it either way)
                 dead = (flat_i >= 0) & ~live[jnp.maximum(flat_i, 0)]
                 flat_d = jnp.where(dead, jnp.inf, flat_d)
                 approx_dco = approx_dco + jnp.sum(mine).astype(jnp.int32)
 
             # -- collective 1 (first half): local stable top-fetch.  (With
-            # fused_topk + no streaming merge the stream is already the
-            # stable top-fetch; the preselect is then a width-preserving
-            # stable sort, harmless and shape-identical.)
+            # no streaming merge the stream is already the stable
+            # top-fetch; the preselect is then a width-preserving stable
+            # sort, harmless and shape-identical.)
             l_d, l_ids = preselect_candidates(flat_d, flat_i, fetch=fetch)
             return (l_d, l_ids,
                     jax.lax.psum(approx_dco, axes),

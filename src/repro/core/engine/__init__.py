@@ -4,7 +4,9 @@ Composable, individually-jittable stages over static-shape pytrees:
 
     select_lists  -> ListSelection   centroid top-nprobe
     plan_blocks   -> QueryPlan       cell-level dedup + budget compaction
-    scan_blocks   -> ScanOut         ADC scan, exec_mode "paged"|"grouped"
+    scan_blocks_topk -> ScanOut      ADC scan + stable top-fetch, exec_mode
+                                     "paged"|"grouped"|"clustered"
+                                     (``scan_blocks``: its jnp scorer)
     finalize_candidates              top-bigK + id-dedup + exact refine
 
 ``core/search.py`` (single host) and ``core/distributed.py`` (the

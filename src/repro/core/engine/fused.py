@@ -1,14 +1,14 @@
-"""Stage 3+ — fused ADC scan -> stable partial top-``fetch``.
+"""Stage 3 — the scan stage: ADC scan -> stable partial top-``fetch``.
 
-``scan_blocks_topk`` is the drop-in fused alternative to
-``scan_blocks`` + ``preselect_candidates``: it returns a ``ScanOut``
-whose candidate stream is already the stable top-``fetch`` of the plan
-layout — width ``fetch`` instead of ``S * BLK`` — so the scan stage
-stops writing a (B, S, BLK) score tensor to HBM just for finalize to
-re-read and discard.  Contract (both paths, bitwise):
+``scan_blocks_topk`` is the scan stage of every engine composition.  It
+returns a ``ScanOut`` whose candidate stream is already the stable
+top-``fetch`` of the plan layout — width ``fetch`` instead of
+``S * BLK`` — so the scan stage never writes a (B, S, BLK) score tensor
+to HBM for finalize to re-read and discard.  Contract (both paths,
+bitwise):
 
   * ``flat_d``/``flat_i`` are the ascending stable selection of the
-    unfused stream — exactly ``preselect_candidates`` over
+    full candidate stream — exactly ``preselect_candidates`` over
     ``scan_blocks``' output with ties broken by flat plan position —
     with masked/overflow entries normalized to ``(+inf, -1)``;
   * ``approx_dco``/``scanned_blocks`` keep the logical accounting of
@@ -16,9 +16,7 @@ re-read and discard.  Contract (both paths, bitwise):
     ADC computation each, Alg. 5 L15-16);
   * with ``live`` (streaming tombstones) dead candidates are forced out
     *before* selection — they can neither be returned nor displace live
-    candidates — matching the distributed serve step's ordering; the
-    idempotent re-mask in ``finalize_candidates`` keeps end results
-    bitwise identical to the unfused live-in-finalize path.
+    candidates; the re-mask in ``finalize_candidates`` is idempotent.
 
 ``use_kernel=True`` routes through the fused Pallas kernel
 (``kernels/pq_scan.py::pq_scan_topk_kernel``): the keep mask moves
@@ -30,12 +28,12 @@ sorted-union positions in grouped/clustered), so the plan layout is
 carried in as two (B, S) sidecars built here: ``slot_of`` (the plan
 slot scanned at that position, -1 if the query does not plan it) and
 ``rank_u`` (that slot's probe rank) — one scatter through the same
-sorted-union ``searchsorted`` the unfused modes use, which is exact
+sorted-union ``searchsorted`` ``scan_blocks`` uses, which is exact
 because SEIL plans are per-query duplicate-free.
 
-``use_kernel=False`` is the stage-level fusion oracle: the jnp scan
-plus an in-stage stable preselect.  Identical output contract, no
-kernel — the shard_map serve path and CPU tests run this by default.
+``use_kernel=False`` is the oracle: the jnp scan (``scan_blocks``) plus
+an in-stage stable preselect.  Identical output contract, no kernel —
+a session without ``use_kernel`` runs it.
 Distances are assumed finite (a +/-inf ADC distance would be
 indistinguishable from a masked slot in the oracle's normalization).
 """
@@ -136,9 +134,9 @@ def scan_blocks_topk(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
                      exec_mode: str = "paged", use_kernel: bool = False,
                      query_tile: int = 8, sel=None, perm=None, unions=None,
                      live=None, packed: bool = False) -> ScanOut:
-    """Fused scan + stable top-``fetch`` selection (see module docstring).
+    """Scan + stable top-``fetch`` selection (see module docstring).
 
-    Same signature and semantics as ``scan_blocks`` plus ``fetch`` (the
+    Same arguments as ``scan_blocks`` plus ``use_kernel``, ``fetch`` (the
     candidate budget finalize needs: ``bigk * oversample`` for
     dedup-required layouts, ``bigk`` otherwise) and ``live`` (optional
     tombstone mask over the id space, applied pre-selection).
@@ -151,8 +149,8 @@ def scan_blocks_topk(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
     fetch = min(fetch, s * blk)
     if not use_kernel:
         out = scan_blocks(store, plan, lut, rank_of, exec_mode=exec_mode,
-                          use_kernel=False, query_tile=query_tile, sel=sel,
-                          perm=perm, unions=unions, packed=packed)
+                          query_tile=query_tile, sel=sel, perm=perm,
+                          unions=unions, packed=packed)
         d = out.flat_d
         if live is not None:
             dead = (out.flat_i >= 0) & ~live[jnp.maximum(out.flat_i, 0)]

@@ -1,29 +1,35 @@
-"""Stage 3 — ADC scan of the planned blocks, in one of two exec modes.
+"""Stage 3 — ADC scan of the planned blocks, the jnp scorer.
+
+``scan_blocks`` is the reference the scan stage is held to: the
+engine's compositions call ``scan_blocks_topk`` (engine/fused.py), which
+with ``use_kernel=False`` composes this scorer with
+``preselect_candidates`` and with ``use_kernel=True`` runs the Pallas
+kernel, bitwise the same selection.  Three exec modes:
 
 ``paged``   : every query pages its own scan list — one (block, query)
               fetch per plan entry.  This is the classic per-query IVF
-              scan (kernel: ``pq_scan_paged`` with query_tile=1).
+              scan.
 
 ``grouped`` : the paper's §5.3 cache optimization ("group tasks by
               list"), batch-union form.  The union of all blocks planned
               by *any* query in the batch is materialized once, sorted
               by physical block id; each union block is fetched once per
-              query tile and scored for the whole tile while resident
-              (kernel: ``pq_scan_grouped``).  Per-query distances are
-              then scattered back into the plan layout via a sorted-
-              union ``searchsorted``, so everything downstream —
-              item masks, DCO counters, top-K — is byte-for-byte the
-              same computation as paged mode.  HBM traffic drops from
-              sum_q |plan_q| block fetches to |union_batch| * ceil(B/QT);
-              logical DCO accounting is unchanged by construction.
+              query tile and scored for the whole tile while resident.
+              Per-query distances are then scattered back into the plan
+              layout via a sorted-union ``searchsorted``, so everything
+              downstream — item masks, DCO counters, top-K — is
+              byte-for-byte the same computation as paged mode.  HBM
+              traffic drops from sum_q |plan_q| block fetches to
+              |union_batch| * ceil(B/QT); logical DCO accounting is
+              unchanged by construction.
 
 ``clustered``: the locality-aware refinement of grouped (engine/
               cluster.py).  Queries are permuted into probe-overlap
               order (stable signature sort), the union is built *per
               query tile* instead of per batch, each tile scans only
-              its own working set (kernel: ``pq_scan_tiled``), and the
-              per-query distances are scattered back through the same
-              sorted-union ``searchsorted`` and un-permuted.  The
+              its own working set, and the per-query distances are
+              scattered back through the same sorted-union
+              ``searchsorted`` and un-permuted.  The
               redundant-compute term shrinks from B x U_batch to
               B x U_tile; on skewed traffic U_tile -> |plan_q| and the
               mode matches paged compute while keeping grouped's
@@ -37,7 +43,7 @@ tile) — an upper bound on the number of distinct planned blocks — so
 neither mode can drop a block the paged plan would scan: results are
 bitwise identical (asserted in tests/test_engine.py, tests/test_plan.py).
 
-Item-level masks (shared by both modes): invalid slots, and misc items
+Item-level masks (shared by every mode): invalid slots, and misc items
 whose co-assigned list was scanned at an earlier rank (their cell was
 already computed — Alg. 5 L15-16; the DCO is still counted, SEIL cannot
 avoid computing a misc duplicate before discarding it).
@@ -49,7 +55,7 @@ import jax.numpy as jnp
 
 from ...kernels.ref import adc_gather as _adc_gather
 from ...quant.nibbles import unpack_nibbles
-from .cluster import cluster_order, fit_tile, tile_unions, union_dims
+from .cluster import cluster_order, tile_unions, union_dims
 from .types import BIG, BlockStore, QueryPlan, ScanOut
 
 EXEC_MODES = ("paged", "grouped", "clustered")
@@ -72,35 +78,23 @@ def batch_union(plan: QueryPlan, total_blocks: int) -> jnp.ndarray:
     return tile_unions(plan.blocks, plan.valid, 1, u)[0]
 
 
-def _scan_paged(store: BlockStore, plan: QueryPlan, lut, use_kernel: bool,
+def _scan_paged(store: BlockStore, plan: QueryPlan, lut,
                 packed: bool = False):
-    if use_kernel:
-        from ...kernels.ops import pq_scan_paged
-        return pq_scan_paged(lut, store.block_codes, plan.blocks,
-                             packed=packed)
     codes = _codes_for(store.block_codes[plan.blocks], lut.shape[1],
                        packed)                     # (B, S, BLK, M)
     return _adc_gather(lut, codes)
 
 
-def _scan_grouped(store: BlockStore, plan: QueryPlan, lut,
-                  use_kernel: bool, query_tile: int, union=None,
+def _scan_grouped(store: BlockStore, plan: QueryPlan, lut, union=None,
                   packed: bool = False):
     b, s = plan.blocks.shape
     if union is None:
         union = batch_union(plan, store.block_codes.shape[0])   # (U,)
     safe_union = jnp.where(union < BIG, union, 0)
-    if use_kernel:
-        from ...kernels.ops import pq_scan_grouped
-        qt = fit_tile(b, query_tile)
-        dists_u = pq_scan_grouped(lut, store.block_codes, safe_union,
-                                  query_tile=qt,
-                                  packed=packed)             # (B, U, BLK)
-    else:
-        codes_u = _codes_for(store.block_codes[safe_union], lut.shape[1],
-                             packed)                        # (U, BLK, M)
-        dists_u = _adc_gather(
-            lut, jnp.broadcast_to(codes_u[None], (b,) + codes_u.shape))
+    codes_u = _codes_for(store.block_codes[safe_union], lut.shape[1],
+                         packed)                            # (U, BLK, M)
+    dists_u = _adc_gather(
+        lut, jnp.broadcast_to(codes_u[None], (b,) + codes_u.shape))
     # scatter back to the plan layout: every valid plan block is in the
     # sorted union, so searchsorted finds its exact position
     pos = jnp.searchsorted(union, plan.blocks.reshape(-1)).reshape(b, s)
@@ -109,8 +103,8 @@ def _scan_grouped(store: BlockStore, plan: QueryPlan, lut,
 
 
 def _scan_clustered(store: BlockStore, plan: QueryPlan, lut,
-                    use_kernel: bool, query_tile: int, sel=None,
-                    perm=None, unions=None, packed: bool = False):
+                    query_tile: int, sel=None, perm=None, unions=None,
+                    packed: bool = False):
     """Per-tile-union scan in cluster order; returns (B, S, BLK) dists
     in the *original* batch order — byte-for-byte the paged values."""
     b, s = plan.blocks.shape
@@ -124,20 +118,13 @@ def _scan_clustered(store: BlockStore, plan: QueryPlan, lut,
     t, w = unions.shape
     qt = b // t
     safe_u = jnp.where(unions < BIG, unions, 0)
-    lut_p = lut[perm]
-    if use_kernel:
-        from ...kernels.ops import pq_scan_tiled
-        d_u = pq_scan_tiled(lut_p, store.block_codes, safe_u,
-                            query_tile=qt,
-                            packed=packed)                  # (B, W, BLK)
-    else:
-        codes_u = _codes_for(store.block_codes[safe_u], lut.shape[1],
-                             packed)                       # (T, W, BLK, M)
-        m, k = lut.shape[1], lut.shape[2]
-        g = jnp.take_along_axis(
-            lut_p.reshape(t, qt, 1, 1, m, k),
-            codes_u[:, None].astype(jnp.int32)[..., None], axis=-1)
-        d_u = jnp.sum(g[..., 0], axis=-1).reshape(b, w, -1)  # (B, W, BLK)
+    codes_u = _codes_for(store.block_codes[safe_u], lut.shape[1],
+                         packed)                           # (T, W, BLK, M)
+    m, k = lut.shape[1], lut.shape[2]
+    g = jnp.take_along_axis(
+        lut[perm].reshape(t, qt, 1, 1, m, k),
+        codes_u[:, None].astype(jnp.int32)[..., None], axis=-1)
+    d_u = jnp.sum(g[..., 0], axis=-1).reshape(b, w, -1)     # (B, W, BLK)
     # per-tile sorted-union scatter: exact positions, then un-permute
     pos = jax.vmap(jnp.searchsorted)(unions, pb.reshape(t, qt * s))
     pos = jnp.minimum(pos.reshape(b, s), w - 1)
@@ -147,8 +134,7 @@ def _scan_clustered(store: BlockStore, plan: QueryPlan, lut,
 
 def scan_blocks(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
                 rank_of: jnp.ndarray, *, exec_mode: str = "paged",
-                use_kernel: bool = False, query_tile: int = 8,
-                sel=None, perm=None, unions=None,
+                query_tile: int = 8, sel=None, perm=None, unions=None,
                 packed: bool = False) -> ScanOut:
     """ADC distances + item masks + DCO for the planned blocks.
 
@@ -164,15 +150,14 @@ def scan_blocks(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
     assert exec_mode in EXEC_MODES, exec_mode
     bq = plan.blocks.shape[0]
     if exec_mode == "grouped":
-        dists = _scan_grouped(store, plan, lut, use_kernel, query_tile,
+        dists = _scan_grouped(store, plan, lut,
                               union=None if unions is None else unions[0],
                               packed=packed)
     elif exec_mode == "clustered":
-        dists = _scan_clustered(store, plan, lut, use_kernel, query_tile,
-                                sel=sel, perm=perm, unions=unions,
-                                packed=packed)
+        dists = _scan_clustered(store, plan, lut, query_tile, sel=sel,
+                                perm=perm, unions=unions, packed=packed)
     else:
-        dists = _scan_paged(store, plan, lut, use_kernel, packed=packed)
+        dists = _scan_paged(store, plan, lut, packed=packed)
 
     ids = store.block_ids[plan.blocks]             # (B, S, BLK)
     other = store.block_other[plan.blocks]

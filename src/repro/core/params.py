@@ -72,17 +72,15 @@ class SearchParams:
     exec_mode    "paged" (per-query) | "grouped" (§5.3 list-major batch)
                  | "clustered" (grouped with query-tile clustering:
                  per-tile block unions in probe-overlap order)
-    use_kernel   route the ADC scan through the Pallas kernel
-    fused_topk   fuse the scan with the stable top-fetch selection: the
-                 scan stage emits only ``bigk * oversample`` candidates
-                 per query instead of the full (S, BLK) score tensor.
-                 With use_kernel=True the selection runs inside the
-                 Pallas kernel (a VMEM-resident bitonic top-k
-                 accumulator — candidates never round-trip HBM); with
-                 use_kernel=False it is a stage-level jnp fusion.
-                 Results are bitwise identical either way (the fused
-                 selection reproduces ``preselect_candidates``' stable
-                 tie order; tests/test_fused.py).
+    use_kernel   route the scan stage through the Pallas kernel, whose
+                 VMEM-resident bitonic top-k accumulator keeps each
+                 query's ``fetch`` best candidates (DESIGN.md §9); off,
+                 the jnp scorer plus a stable preselect.  Results are
+                 bitwise identical either way (tests/test_fused.py).
+    fused_topk   always True: the scan stage always selects the top
+                 ``fetch`` candidates.  The field is pinned only by the
+                 benchmark's configs (``bench/configs/*.json``), which
+                 pass ``"fused_topk": true``; it goes when they drop it.
     query_tile   grouped/clustered query tile (VMEM residency per fetch;
                  the clustered union granularity)
     plan_reuse   incremental plans (grouped/clustered only): the session
@@ -106,7 +104,7 @@ class SearchParams:
     max_scan: Optional[int] = None
     exec_mode: str = "paged"
     use_kernel: bool = False
-    fused_topk: bool = False
+    fused_topk: bool = True
     query_tile: int = 8
     plan_reuse: bool = False
     batch_buckets: Optional[Tuple[int, ...]] = None
@@ -124,6 +122,10 @@ class SearchParams:
         if self.exec_mode not in EXEC_MODES:
             raise ValueError(
                 f"exec_mode must be one of {EXEC_MODES}, got {self.exec_mode!r}")
+        if not self.fused_topk:
+            raise ValueError(
+                "fused_topk must be True: the scan stage always selects "
+                f"the top fetch candidates, got {self.fused_topk!r}")
         if self.query_tile < 1:
             raise ValueError(f"query_tile must be >= 1, got {self.query_tile}")
         if self.plan_reuse and self.exec_mode == "paged":

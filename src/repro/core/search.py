@@ -7,18 +7,19 @@
   2. ``plan_blocks``   — gather owned / referenced / misc block tables,
      apply cell-level dedup (the vectorized ``listVisited`` probe) and
      compact candidates to a static scan budget;
-  3. ``scan_blocks``   — ADC distances for every surviving block (Pallas
-     kernel on TPU, jnp oracle elsewhere) + item-level masks, in either
-     ``exec_mode="paged"`` (per-query paging) or ``"grouped"`` (the
+  3. ``scan_blocks_topk`` — ADC distances for every surviving block +
+     item-level masks + the stable top-``fetch`` selection (the Pallas
+     kernel with ``use_kernel``, the jnp oracle otherwise), in
+     ``exec_mode="paged"`` (per-query paging), ``"grouped"`` (the
      paper's §5.3 list-major batch mode: one batch-union block plan,
-     each block fetched once per query tile);
+     each block fetched once per query tile) or ``"clustered"``;
   4. ``finalize_candidates`` — top-bigK (+ id-dedup for layouts without
      SEIL), exact refinement over the original vectors, top-K.
 
 DCO accounting is paper-faithful: every *valid* item in a scanned block
 counts one distance computation (misc duplicates included — SEIL cannot
 avoid them, Alg. 5 L15), skipped reference blocks count zero, refine
-adds one exact DCO per unique candidate.  Both exec modes produce
+adds one exact DCO per unique candidate.  Every exec mode produces
 bitwise-identical results and counters (tests/test_engine.py).
 
 The distributed serving step (core/distributed.py) composes the same
@@ -41,16 +42,16 @@ import numpy as np
 
 from .. import obs
 from .engine import (PlanProbe, cluster_order, finalize_candidates,
-                     plan_blocks, scan_blocks, scan_blocks_topk,
-                     select_lists, store_from_arrays, tables_from_arrays,
-                     tile_unions, union_dims)
+                     plan_blocks, scan_blocks_topk, select_lists,
+                     store_from_arrays, tables_from_arrays, tile_unions,
+                     union_dims)
 from .pq import PQCodebook, pq_lut, pq_lut_ip
 from .seil import SeilArrays
 
 
 def finalize_fetch(bigk: int, oversample: int, dedup_results: bool) -> int:
     """The candidate width ``finalize_candidates`` selects before exact
-    refinement — the budget a fused scan must deliver for bitwise parity
+    refinement — the budget the scan stage must deliver for bitwise parity
     (``preselect_candidates``' covering-width invariant)."""
     return bigk * (oversample if dedup_results else 1)
 
@@ -68,8 +69,7 @@ class SearchResult(NamedTuple):
     jax.jit,
     static_argnames=("nprobe", "bigk", "k", "max_scan", "metric",
                      "dedup_results", "use_kernel", "oversample",
-                     "exec_mode", "query_tile", "fused_topk",
-                     "packed_codes"))
+                     "exec_mode", "query_tile", "packed_codes"))
 def seil_search(
     arrays: SeilArrays,
     centroids: jnp.ndarray,       # (nlist, D)
@@ -87,7 +87,6 @@ def seil_search(
     oversample: int = 2,
     exec_mode: str = "paged",
     query_tile: int = 8,
-    fused_topk: bool = False,
     packed_codes: bool = False,   # arrays carry a nibble-packed quant plane
 ) -> SearchResult:
     with jax.named_scope("select_lists"):
@@ -99,18 +98,11 @@ def seil_search(
         lut = (pq_lut(codebook, queries) if metric == "l2"
                else pq_lut_ip(codebook, queries))            # (B, M, 16)
     with jax.named_scope("scan"):
-        if fused_topk:
-            scan = scan_blocks_topk(
-                store_from_arrays(arrays), plan, lut, selection.rank_of,
-                fetch=finalize_fetch(bigk, oversample, dedup_results),
-                exec_mode=exec_mode, use_kernel=use_kernel,
-                query_tile=query_tile, sel=selection.sel,
-                packed=packed_codes)
-        else:
-            scan = scan_blocks(store_from_arrays(arrays), plan, lut,
-                               selection.rank_of, exec_mode=exec_mode,
-                               use_kernel=use_kernel, query_tile=query_tile,
-                               sel=selection.sel, packed=packed_codes)
+        scan = scan_blocks_topk(
+            store_from_arrays(arrays), plan, lut, selection.rank_of,
+            fetch=finalize_fetch(bigk, oversample, dedup_results),
+            exec_mode=exec_mode, use_kernel=use_kernel,
+            query_tile=query_tile, sel=selection.sel, packed=packed_codes)
     with jax.named_scope("finalize"):
         out_ids, out_d, refine_dco = finalize_candidates(
             scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,
@@ -152,20 +144,14 @@ def _stage_plan(arrays, codebook, selection, queries, *, max_scan, metric):
 @functools.partial(
     jax.jit,
     static_argnames=("fetch", "exec_mode", "use_kernel", "query_tile",
-                     "fused_topk", "has_live", "packed_codes"))
+                     "has_live", "packed_codes"))
 def _stage_scan(arrays, plan, lut, selection, live, *, fetch, exec_mode,
-                use_kernel, query_tile, fused_topk, has_live,
-                packed_codes=False):
-    if fused_topk:
-        return scan_blocks_topk(
-            store_from_arrays(arrays), plan, lut, selection.rank_of,
-            fetch=fetch, exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, sel=selection.sel,
-            live=live if has_live else None, packed=packed_codes)
-    return scan_blocks(store_from_arrays(arrays), plan, lut,
-                       selection.rank_of, exec_mode=exec_mode,
-                       use_kernel=use_kernel, query_tile=query_tile,
-                       sel=selection.sel, packed=packed_codes)
+                use_kernel, query_tile, has_live, packed_codes=False):
+    return scan_blocks_topk(
+        store_from_arrays(arrays), plan, lut, selection.rank_of,
+        fetch=fetch, exec_mode=exec_mode, use_kernel=use_kernel,
+        query_tile=query_tile, sel=selection.sel,
+        live=live if has_live else None, packed=packed_codes)
 
 
 @functools.partial(
@@ -195,7 +181,6 @@ def seil_search_traced(
     oversample: int = 2,
     exec_mode: str = "paged",
     query_tile: int = 8,
-    fused_topk: bool = False,
     packed_codes: bool = False,
 ) -> SearchResult:
     """Stage-fenced ``seil_search`` for tracing: identical composition,
@@ -207,13 +192,13 @@ def seil_search_traced(
         plan, lut = obs.fence(_stage_plan(arrays, codebook, selection,
                                           queries, max_scan=max_scan,
                                           metric=metric))
-    name = "stage.scan_blocks_topk" if fused_topk else "stage.scan_blocks"
-    with obs.span(name, cat="device", exec_mode=exec_mode) as sp:
+    with obs.span("stage.scan_blocks_topk", cat="device",
+                  exec_mode=exec_mode) as sp:
         scan = obs.fence(_stage_scan(
             arrays, plan, lut, selection, lut,   # live unused (has_live=F)
             fetch=finalize_fetch(bigk, oversample, dedup_results),
             exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, fused_topk=fused_topk, has_live=False,
+            query_tile=query_tile, has_live=False,
             packed_codes=packed_codes))
         sp.add(approx_dco=int(np.sum(np.asarray(scan.approx_dco))),
                scanned_blocks=int(np.sum(np.asarray(scan.scanned_blocks))))
@@ -282,7 +267,7 @@ def probe_plan(
 @functools.partial(
     jax.jit,
     static_argnames=("bigk", "k", "metric", "dedup_results", "use_kernel",
-                     "oversample", "exec_mode", "query_tile", "fused_topk",
+                     "oversample", "exec_mode", "query_tile",
                      "packed_codes"))
 def scan_finalize(
     arrays: SeilArrays,
@@ -299,25 +284,16 @@ def scan_finalize(
     oversample: int = 2,
     exec_mode: str = "grouped",
     query_tile: int = 8,
-    fused_topk: bool = False,
     packed_codes: bool = False,
 ) -> SearchResult:
     """Stages 3-4 against caller-provided (possibly reused) unions."""
     with jax.named_scope("scan"):
-        if fused_topk:
-            scan = scan_blocks_topk(
-                store_from_arrays(arrays), probe.plan, probe.lut,
-                probe.rank_of,
-                fetch=finalize_fetch(bigk, oversample, dedup_results),
-                exec_mode=exec_mode, use_kernel=use_kernel,
-                query_tile=query_tile, perm=probe.perm, unions=unions,
-                packed=packed_codes)
-        else:
-            scan = scan_blocks(store_from_arrays(arrays), probe.plan,
-                               probe.lut, probe.rank_of, exec_mode=exec_mode,
-                               use_kernel=use_kernel, query_tile=query_tile,
-                               perm=probe.perm, unions=unions,
-                               packed=packed_codes)
+        scan = scan_blocks_topk(
+            store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
+            fetch=finalize_fetch(bigk, oversample, dedup_results),
+            exec_mode=exec_mode, use_kernel=use_kernel,
+            query_tile=query_tile, perm=probe.perm, unions=unions,
+            packed=packed_codes)
     with jax.named_scope("finalize"):
         out_ids, out_d, refine_dco = finalize_candidates(
             scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,

@@ -143,11 +143,11 @@ class Searcher:
         return d
 
     def topk_width(self) -> Optional[int]:
-        """Lane width F of the fused kernel's top-k accumulator (the
+        """Lane width F of the scan kernel's top-k accumulator (the
         power of two covering the candidates finalize fetches, capped by
         the scan width), or None when the session does not run it."""
         p = self.params
-        if not (p.use_kernel and p.fused_topk):
+        if not p.use_kernel:
             return None
         idx = self.index
         fetch = finalize_fetch(p.bigk_eff, idx.result_oversample,
@@ -205,7 +205,7 @@ class Searcher:
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            fused_topk=p.fused_topk, packed_codes=packed)
+            packed_codes=packed)
 
     def _call_inputs(self) -> tuple:
         """Runtime arguments preceding the query batch at dispatch."""
@@ -244,7 +244,7 @@ class Searcher:
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            fused_topk=p.fused_topk, packed_codes=packed)
+            packed_codes=packed)
 
     def _scan_inputs(self) -> tuple:
         idx = self.index
@@ -289,7 +289,7 @@ class Searcher:
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            fused_topk=p.fused_topk, packed_codes=packed)
+            packed_codes=packed)
 
     def _dispatch(self, bucket: int, qc: jnp.ndarray) -> SearchResult:
         """One padded chunk through either the monolithic executable or

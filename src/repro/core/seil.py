@@ -102,10 +102,9 @@ def _pad_table(groups: np.ndarray, values: np.ndarray, nlist: int,
     return table
 
 
-# Monotonic count of full layout builds.  The streaming subsystem
-# (core/stream/) asserts its delta append path never triggers one, and
-# benchmarks/run.py records it in BENCH_stream.json — a rebuild is the
-# O(n) cost the delta segment exists to avoid.
+# Monotonic count of full layout builds.  The streaming tests
+# (tests/test_stream.py) assert the delta append path never triggers one
+# — a rebuild is the O(n) cost the delta segment exists to avoid.
 _BUILD_SEIL_CALLS = 0
 
 

@@ -535,7 +535,7 @@ class ShardedSearcher(Searcher):
             oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
             axes=sh.axes, ndev=sh.ndev, streaming=sh.streaming,
-            use_kernel=p.use_kernel, fused_topk=p.fused_topk, stage=stage,
+            use_kernel=p.use_kernel, stage=stage,
             packed_codes=self._plane is not None)
 
     def _lower(self, bucket: int):

@@ -23,8 +23,9 @@ the mutable epoch state before the shared finalize stage:
     compete with base candidates under the exact same top-bigK /
     refinement rules;
   * the **tombstone mask** (``live``, over the whole id space base +
-    delta) is applied inside finalize — deleted items are forced to
-    +inf before selection instead of being rewritten out.
+    delta) is applied in the scan stage and again inside finalize —
+    deleted items are forced to +inf before selection instead of being
+    rewritten out.
 
 DCO accounting stays paper-faithful: the exhaustive path counts one ADC
 distance per live slot per query; the routed path counts one per live
@@ -52,8 +53,8 @@ import numpy as np
 
 from ... import obs
 from ..engine import (PlanProbe, finalize_candidates, plan_blocks,
-                      scan_blocks, scan_blocks_topk, select_lists,
-                      store_from_arrays, tables_from_arrays)
+                      scan_blocks_topk, select_lists, store_from_arrays,
+                      tables_from_arrays)
 from ..pq import PQCodebook, pq_lut, pq_lut_ip
 from ..search import (SearchResult, _stage_plan, _stage_scan, _stage_select,
                       finalize_fetch)
@@ -119,7 +120,7 @@ def _delta_candidates(lut, delta_codes, delta_ids, delta_post,
     static_argnames=("nprobe", "bigk", "k", "max_scan", "metric",
                      "dedup_results", "use_kernel", "oversample",
                      "exec_mode", "query_tile", "route_delta",
-                     "fused_topk", "packed_codes"))
+                     "packed_codes"))
 def streaming_search(
     arrays: SeilArrays,
     centroids: jnp.ndarray,       # (nlist, D)
@@ -143,7 +144,6 @@ def streaming_search(
     exec_mode: str = "paged",
     query_tile: int = 8,
     route_delta: bool = False,
-    fused_topk: bool = False,
     packed_codes: bool = False,   # arrays carry a nibble-packed quant plane
 ) -> SearchResult:
     with jax.named_scope("select_lists"):
@@ -155,21 +155,14 @@ def streaming_search(
         lut = (pq_lut(codebook, queries) if metric == "l2"
                else pq_lut_ip(codebook, queries))            # (B, M, 16)
     with jax.named_scope("scan"):
-        if fused_topk:
-            # live is applied pre-selection so tombstoned base candidates
-            # cannot occupy top-fetch slots; finalize's re-mask is
-            # idempotent
-            scan = scan_blocks_topk(
-                store_from_arrays(arrays), plan, lut, selection.rank_of,
-                fetch=finalize_fetch(bigk, oversample, dedup_results),
-                exec_mode=exec_mode, use_kernel=use_kernel,
-                query_tile=query_tile, sel=selection.sel, live=live,
-                packed=packed_codes)
-        else:
-            scan = scan_blocks(store_from_arrays(arrays), plan, lut,
-                               selection.rank_of, exec_mode=exec_mode,
-                               use_kernel=use_kernel, query_tile=query_tile,
-                               sel=selection.sel, packed=packed_codes)
+        # live is applied pre-selection so tombstoned base candidates
+        # cannot occupy top-fetch slots; finalize's re-mask is idempotent
+        scan = scan_blocks_topk(
+            store_from_arrays(arrays), plan, lut, selection.rank_of,
+            fetch=finalize_fetch(bigk, oversample, dedup_results),
+            exec_mode=exec_mode, use_kernel=use_kernel,
+            query_tile=query_tile, sel=selection.sel, live=live,
+            packed=packed_codes)
         dd, di, delta_dco = _delta_candidates(
             lut, delta_codes, delta_ids, delta_post, delta_assigns,
             selection.sel, selection.rank_of, route_delta)
@@ -214,8 +207,7 @@ def streaming_search_traced(
     arrays, centroids, codebook, vectors, delta_codes, delta_ids,
     delta_post, delta_assigns, live, queries, *, nprobe, bigk, k, max_scan,
     metric="l2", dedup_results=True, use_kernel=False, oversample=2,
-    exec_mode="paged", query_tile=8, route_delta=False, fused_topk=False,
-    packed_codes=False,
+    exec_mode="paged", query_tile=8, route_delta=False, packed_codes=False,
 ) -> SearchResult:
     """Stage-fenced ``streaming_search`` for tracing: identical
     composition, span + fence per stage, delta DCO on its own span."""
@@ -226,15 +218,15 @@ def streaming_search_traced(
         plan, lut = obs.fence(_stage_plan(arrays, codebook, selection,
                                           queries, max_scan=max_scan,
                                           metric=metric))
-    name = "stage.scan_blocks_topk" if fused_topk else "stage.scan_blocks"
-    with obs.span(name, cat="device", exec_mode=exec_mode) as sp:
-        # fused applies the tombstone mask pre-selection (has_live)
+    with obs.span("stage.scan_blocks_topk", cat="device",
+                  exec_mode=exec_mode) as sp:
+        # the tombstone mask applies pre-selection (has_live)
         scan = obs.fence(_stage_scan(
             arrays, plan, lut, selection, live,
             fetch=finalize_fetch(bigk, oversample, dedup_results),
             exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, fused_topk=fused_topk,
-            has_live=fused_topk, packed_codes=packed_codes))
+            query_tile=query_tile, has_live=True,
+            packed_codes=packed_codes))
         sp.add(approx_dco=int(np.sum(np.asarray(scan.approx_dco))),
                scanned_blocks=int(np.sum(np.asarray(scan.scanned_blocks))))
     with obs.span("stage.delta_scan", cat="device",
@@ -259,7 +251,7 @@ def streaming_search_traced(
     jax.jit,
     static_argnames=("bigk", "k", "metric", "dedup_results", "use_kernel",
                      "oversample", "exec_mode", "query_tile", "route_delta",
-                     "fused_topk", "packed_codes"))
+                     "packed_codes"))
 def scan_finalize_stream(
     arrays: SeilArrays,
     vectors: jnp.ndarray,
@@ -281,27 +273,18 @@ def scan_finalize_stream(
     exec_mode: str = "grouped",
     query_tile: int = 8,
     route_delta: bool = False,
-    fused_topk: bool = False,
     packed_codes: bool = False,
 ) -> SearchResult:
     """Streaming stages 3-4 against caller-provided (reused) unions —
     the probe half is the base ``probe_plan`` (the delta needs no block
     planning), so incremental plans compose with churn unchanged."""
     with jax.named_scope("scan"):
-        if fused_topk:
-            scan = scan_blocks_topk(
-                store_from_arrays(arrays), probe.plan, probe.lut,
-                probe.rank_of,
-                fetch=finalize_fetch(bigk, oversample, dedup_results),
-                exec_mode=exec_mode, use_kernel=use_kernel,
-                query_tile=query_tile, perm=probe.perm, unions=unions,
-                live=live, packed=packed_codes)
-        else:
-            scan = scan_blocks(store_from_arrays(arrays), probe.plan,
-                               probe.lut, probe.rank_of, exec_mode=exec_mode,
-                               use_kernel=use_kernel, query_tile=query_tile,
-                               perm=probe.perm, unions=unions,
-                               packed=packed_codes)
+        scan = scan_blocks_topk(
+            store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
+            fetch=finalize_fetch(bigk, oversample, dedup_results),
+            exec_mode=exec_mode, use_kernel=use_kernel,
+            query_tile=query_tile, perm=probe.perm, unions=unions,
+            live=live, packed=packed_codes)
         dd, di, delta_dco = _delta_candidates(
             probe.lut, delta_codes, delta_ids, delta_post, delta_assigns,
             probe.sel, probe.rank_of, route_delta)

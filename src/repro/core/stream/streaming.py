@@ -533,7 +533,7 @@ class StreamingIndex:
 
         O(batch): strategy-registry assignment + PQ encoding of the new
         rows and buffer patches — never a layout rebuild (asserted via
-        ``seil.build_seil_call_count`` in tests and BENCH_stream.json).
+        ``seil.build_seil_call_count`` in tests/test_stream.py).
         """
         x = np.asarray(x, np.float32)
         if x.ndim != 2 or x.shape[1] != self.base.vectors.shape[1]:
@@ -933,8 +933,7 @@ class StreamingSearcher(Searcher):
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            route_delta=self._route_delta, fused_topk=p.fused_topk,
-            packed_codes=packed)
+            route_delta=self._route_delta, packed_codes=packed)
 
     def _dispatch_traced(self, bucket: int, qc):
         """Stage-fenced streaming dispatch (repro/obs/): the base stage
@@ -954,8 +953,7 @@ class StreamingSearcher(Searcher):
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            route_delta=self._route_delta, fused_topk=p.fused_topk,
-            packed_codes=packed)
+            route_delta=self._route_delta, packed_codes=packed)
 
     def _post_arg(self, dev) -> jnp.ndarray:
         """The posting-map argument: real directory when routed, a
@@ -991,8 +989,7 @@ class StreamingSearcher(Searcher):
             dedup_results=idx.needs_result_dedup,
             use_kernel=p.use_kernel, oversample=idx.result_oversample,
             exec_mode=p.exec_mode, query_tile=p.query_tile,
-            route_delta=self._route_delta, fused_topk=p.fused_topk,
-            packed_codes=packed)
+            route_delta=self._route_delta, packed_codes=packed)
 
     def _scan_inputs(self) -> tuple:
         idx = self.stream.base

@@ -12,8 +12,7 @@ ValueError), so pre-taxonomy ``except`` clauses keep working — the
 taxonomy tightens what is raised, never what is caught.
 
 This module is dependency-free on purpose: anything (core/io.py, the
-gateway, the fault injector, the stdlib-only regression gate's test
-fixtures) may import it without pulling in jax.
+gateway, the fault injector) may import it without pulling in jax.
 """
 from __future__ import annotations
 

@@ -4,14 +4,8 @@ Open-loop means arrivals follow their own clock (a Poisson process at
 ``offered_qps``), not the server's: a slow server does not slow the
 generator down, so queueing delay shows up in the measured latency
 instead of being hidden by closed-loop back-pressure.  This is the
-load model the bench (``bench_serve``) and the CI gateway-smoke job
-drive.
-
-The per-request baseline the bench compares against is the same
-generator pointed at a gateway configured with ``max_batch=1`` /
-``max_delay_ms=0`` — identical queue, identical sessions, but every
-dispatch carries exactly one query — so the measured gap is purely the
-value of deadline coalescing.
+load model the serve CLI (``--gateway``), ``chip_smoke.py`` and the
+overload tests drive.
 """
 from __future__ import annotations
 
@@ -36,8 +30,8 @@ def run_open_loop(gateway, queries: np.ndarray, offered_qps: float,
                   timeout_s: float = 60.0,
                   exponential: bool = True,
                   tick_ms: float = 2.0,
-                  on_request: Optional[Callable[[int], None]] = None,
-                  collect: bool = False) -> dict:
+                  on_request: Optional[Callable[[int], None]] = None
+                  ) -> dict:
     """Drive ``n_requests`` single-query submissions at ``offered_qps``
     and block for every response.
 
@@ -54,15 +48,12 @@ def run_open_loop(gateway, queries: np.ndarray, offered_qps: float,
     on_request    optional hook called after every submit with the
                   request index — the churn/handover tests use it to
                   interleave mutations with live traffic
-    collect       also return the raw per-answer arrays (query index,
-                  result ids) so a caller can score recall offline —
-                  the overload bench needs this to price degradation
 
     Returns one load-point summary: achieved qps, latency percentiles
     (ms), the mean coalesced batch size, and a full typed accounting of
     every submission — ``n_ok + shed + deadline_failed + closed +
-    errors == n_requests`` is the no-silent-drops invariant the
-    regression gate asserts.  ``shed``/``deadline_failed``/``closed``
+    errors == n_requests`` is the no-silent-drops invariant
+    tests/test_faults.py asserts.  ``shed``/``deadline_failed``/``closed``
     count requests the gateway failed *typed* (``Overloaded`` /
     ``DeadlineExceeded`` / ``GatewayClosed``); ``errors`` is anything
     untyped — a healthy run, overloaded or not, keeps it at zero.
@@ -93,9 +84,8 @@ def run_open_loop(gateway, queries: np.ndarray, offered_qps: float,
 
     results = []
     shed = deadline_failed = closed = errors = 0
-    ok_idx, ok_ids = [], []
     levels: dict = {}
-    for i, req in enumerate(pending):
+    for req in pending:
         try:
             r = req.result(timeout_s)
         except Overloaded:
@@ -112,9 +102,6 @@ def run_open_loop(gateway, queries: np.ndarray, offered_qps: float,
             continue
         results.append(r)
         levels[r.level] = levels.get(r.level, 0) + 1
-        if collect:
-            ok_idx.append(i % len(queries))
-            ok_ids.append(np.asarray(r.ids))
     t1 = time.perf_counter()
 
     lat = sorted(r.latency_s for r in results)
@@ -130,9 +117,6 @@ def run_open_loop(gateway, queries: np.ndarray, offered_qps: float,
         "errors": errors,
         "levels": {str(k): v for k, v in sorted(levels.items())},
         "wall_s": wall,
-        **({"ok_query_idx": np.asarray(ok_idx, np.int64),
-            "ok_ids": (np.stack(ok_ids) if ok_ids
-                       else np.zeros((0, 0), np.int64))} if collect else {}),
         "p50_ms": _pct(lat, 50) * 1e3,
         "p95_ms": _pct(lat, 95) * 1e3,
         "p99_ms": _pct(lat, 99) * 1e3,

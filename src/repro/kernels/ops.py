@@ -1,4 +1,4 @@
-"""Jitted public wrappers around the Pallas kernels.
+"""The public wrapper around the Pallas scan kernel.
 
 The backend decides how a kernel runs, explicitly (``_interpret``):
 
@@ -15,13 +15,10 @@ LUT rows are 0, so they contribute nothing.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from .pq_scan import (pq_scan_paged_kernel, pq_scan_tiled_kernel,
-                      pq_scan_topk_kernel)
+from .pq_scan import pq_scan_topk_kernel
 
 _LANE = 128
 
@@ -79,53 +76,6 @@ def _align(lut, block_codes, packed: bool, on_tpu: bool):
     return lut, block_codes
 
 
-@functools.partial(jax.jit, static_argnames=("packed",))
-def pq_scan_paged(lut: jnp.ndarray, block_codes: jnp.ndarray,
-                  block_idx: jnp.ndarray, *,
-                  packed: bool = False) -> jnp.ndarray:
-    """Per-query paged ADC scan.  lut (B, M, K) f32, block_codes
-    (TB, BLK, M) uint8, block_idx (B, S) int32 (>= 0) -> (B, S, BLK) f32."""
-    interpret = _interpret()
-    lut, block_codes = _align(lut, block_codes, packed, not interpret)
-    return pq_scan_paged_kernel(lut, block_codes, block_idx.astype(jnp.int32),
-                                query_tile=1, interpret=interpret,
-                                packed=packed)
-
-
-def pq_scan_grouped(lut: jnp.ndarray, block_codes: jnp.ndarray,
-                    shared_idx: jnp.ndarray, query_tile: int = 8,
-                    *, packed: bool = False) -> jnp.ndarray:
-    """List-major batch mode (paper §5.3 cache optimization): all B queries
-    score the SAME scan list.  lut (B, M, K), shared_idx (S,) -> (B, S, BLK).
-    The code tile for each position stays resident in VMEM across the
-    query-tile grid steps."""
-    b = lut.shape[0]
-    interpret = _interpret()
-    lut, block_codes = _align(lut, block_codes, packed, not interpret)
-    idx = jnp.broadcast_to(shared_idx[None, :],
-                           (b // query_tile, shared_idx.shape[0]))
-    return pq_scan_tiled_kernel(lut, block_codes, idx.astype(jnp.int32),
-                                query_tile=query_tile, interpret=interpret,
-                                packed=packed)
-
-
-def pq_scan_tiled(lut: jnp.ndarray, block_codes: jnp.ndarray,
-                  tile_idx: jnp.ndarray, query_tile: int = 8,
-                  *, packed: bool = False) -> jnp.ndarray:
-    """Clustered mode (locality-aware §5.3): each query *tile* scores its
-    own scan list — the tile's block union, padded per tile rather than
-    to the batch-wide maximum.  lut (B, M, K) in cluster order, tile_idx
-    (B // query_tile, W) -> (B, W, BLK).  The scalar-prefetched tile
-    lists feed the kernel index_map directly (no (B, W) re-broadcast);
-    the code tile for each union position stays resident in VMEM across
-    its tile's grid steps."""
-    interpret = _interpret()
-    lut, block_codes = _align(lut, block_codes, packed, not interpret)
-    return pq_scan_tiled_kernel(lut, block_codes, tile_idx.astype(jnp.int32),
-                                query_tile=query_tile, interpret=interpret,
-                                packed=packed)
-
-
 def pq_scan_topk(lut: jnp.ndarray, block_codes: jnp.ndarray,
                  block_ids: jnp.ndarray, block_other: jnp.ndarray,
                  tile_idx: jnp.ndarray, rank_of: jnp.ndarray,
@@ -135,8 +85,8 @@ def pq_scan_topk(lut: jnp.ndarray, block_codes: jnp.ndarray,
     and the stable partial top-k folded into the kernel, so only
     ``fetch`` candidates per query cross the HBM boundary instead of
     (S, BLK) scores.  tile_idx (B // query_tile, S) pages per-tile scan
-    lists exactly like ``pq_scan_tiled``; ``slot_of``/``rank_u`` (B, S)
-    map each scan position back to the query's plan slot (see
+    lists (at query_tile 1, every query its own); ``slot_of``/``rank_u``
+    (B, S) map each scan position back to the query's plan slot (see
     ``core/engine/fused.py`` for the per-exec-mode construction).
     Returns (acc_d, acc_pos, acc_id, dco) — (B, fetch) sorted candidate
     triple + (B,) logical DCO."""

@@ -1,4 +1,7 @@
-"""Paged PQ fast-scan Pallas TPU kernel — the DCO hot spot of the paper.
+"""Paged PQ fast-scan -> top-k Pallas TPU kernel — the DCO hot spot of
+the paper.  One kernel, ``pq_scan_topk_kernel``: it scores the planned
+blocks and keeps each query's best ``fetch`` candidates in VMEM, so only
+those leave the chip's scan stage.
 
 CPU PQ Fast Scan keeps 16-entry LUTs in SIMD registers and uses the
 AVX2 ``pshufb`` 16-way shuffle to score 32 packed items at once.  TPUs
@@ -29,20 +32,23 @@ with no per-item scalar work) to the MXU:
     positions (four blocks of 32, one block from 128 up), one paged
     operand per block, and scores them as one (G*BLK)-row tile: a
     step's fixed cost (pipeline bookkeeping, the small paged DMAs)
-    outweighs its work at 32 items, and G*BLK fills the 128 lanes.
+    outweighs its work at 32 items, and G*BLK fills the 128 lanes;
+  * each step's candidates are masked (invalid slots and items, misc
+    duplicates, tombstones), bitonic-sorted and merged into a top-F
+    accumulator that stays in VMEM for the whole scan (kernels/topk.py).
 
 Production tiling notes (TPU v5e): native block size 128 (lane width)
 instead of the paper's 32 — ``block`` stays a config knob and the
 paper's Fig. 16 block-size study covers the sweep.  uint8 code tiles
 want (32, 128) alignment, so M is zero-padded to a multiple of 128 by
-``ops.pq_scan_paged`` (padded codes select lut[m_pad, 0] == 0).
+``ops.pq_scan_topk`` (padded codes select lut[m_pad, 0] == 0).
 
 Block layout rules of the TPU lowering: the last two dimensions of a
 block must be multiples of (8, 128) or equal the array's.  Every
 operand paged one row at a time (per-block ids/other/tombstones) and
 every per-query array tiled by a query tile that may be 1, 2 or 4 rows
 therefore carries a singleton axis in front of its minor dimension —
-``(TB, 1, BLK)``, ``(B, 1, F)``, ``(B, S, 1, BLK)`` — so that a block's
+``(TB, 1, BLK)``, ``(B, 1, F)``, ``(B, 1, S)`` — so that a block's
 last two dimensions equal the array's.  The scalar-prefetched scan
 lists live in SMEM (1 MiB on v5e); ``_map_query_chunks`` splits a batch
 whose lists would not fit into sequential kernel calls over query
@@ -55,7 +61,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import checkify
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -121,7 +126,7 @@ def _tile_codes(codes_refs, packed: bool) -> jnp.ndarray:
 
 
 def _kernel_lut(lut: jnp.ndarray, packed: bool) -> jnp.ndarray:
-    """(B, M, K) LUT -> the (B, 1, M*K) rows the kernels read.
+    """(B, M, K) LUT -> the (B, 1, M*K) rows the kernel reads.
 
     Flattened once per call in XLA, so no grid step merges the K lanes
     of the table; for a packed plane the rows are first put in the
@@ -146,8 +151,7 @@ def _score_block(lut_ref, codes_refs, packed: bool) -> jnp.ndarray:
     product per output, codes < 256), and a compare with the lane
     constant ``j % K`` selects.  A (BLK, M, K) tile reshaped to
     (BLK, M*K) would merge lanes, which Mosaic lowers as sublane
-    rotates and shuffles that spill on every grid step.  Both kernels
-    score through here, so their distances are bitwise identical.  The
+    rotates and shuffles that spill on every grid step.  The
     scoring contraction is pinned to full f32 (HIGHEST): the one-hot is
     exact in bf16 but the LUT is not, and the distances must match the
     jnp reference's."""
@@ -168,26 +172,8 @@ def _score_block(lut_ref, codes_refs, packed: bool) -> jnp.ndarray:
                                preferred_element_type=jnp.float32)
 
 
-def _make_kernel(packed: bool):
-    """Body factory for the unfused scan (packed-ness is static)."""
-
-    def _kernel(idx_ref, lut_ref, *refs):
-        """One grid step: score a step's G code blocks for QT queries.
-
-        lut_ref:   (QT, 1, M*K) f32 in VMEM (``_kernel_lut``)
-        refs:      G (1, BLK, MB) uint8 paged blocks in VMEM (MB = M, or
-                   M/2 when nibble-packed), then the (QT, 1, 1, G*BLK)
-                   f32 output block
-        """
-        *codes_refs, out_ref = refs
-        d = _score_block(lut_ref, codes_refs, packed)
-        out_ref[...] = d[:, None, None, :]
-
-    return _kernel
-
-
 def blocks_per_step(blk: int) -> int:
-    """Scan positions G that one grid step of either scan kernel pages
+    """Scan positions G that one grid step of the scan kernel pages
     and scores: as many blocks of ``blk`` items as fill the 128 lanes of
     a vreg, so the step's fixed cost (pipeline bookkeeping, the small
     paged DMAs) is paid once per 128 candidates.  One at ``blk >= 128``."""
@@ -211,99 +197,6 @@ def _paged_specs(shape, g: int):
     def paged(j):
         return lambda qi, si, idx: (idx[qi, _position(si, j, g)], 0, 0)
     return [pl.BlockSpec(shape, paged(j)) for j in range(g)]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("query_tile", "interpret", "packed"))
-def pq_scan_tiled_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
-                         tile_idx: jnp.ndarray, *, query_tile: int = 8,
-                         interpret: bool = False,
-                         packed: bool = False) -> jnp.ndarray:
-    """Per-tile paged scan: every query tile pages its *own* scan list.
-
-    lut (B, M, K) f32, block_codes (TB, BLK, M) uint8, tile_idx
-    (B // query_tile, S) -> (B, S, BLK) f32.  The scalar-prefetched
-    ``tile_idx`` drives the BlockSpec index_map directly at tile
-    granularity — the clustered exec mode hands each tile its own
-    (tile-padded) block union with no re-broadcast to a batch-wide
-    list.  B % query_tile == 0; entries must be valid (callers clamp
-    padding to 0 and mask downstream).  With ``packed=True`` the code
-    tile carries two 4-bit codes per byte (quant plane) and M must be
-    2x the byte width — half the DMA bytes per block."""
-    b, m, k = lut.shape
-    qb, s = tile_idx.shape
-    tb, blk, mb = block_codes.shape
-    assert (2 * mb if packed else mb) == m, (mb, m, packed)
-    assert b == qb * query_tile, (b, qb, query_tile)
-    lut = _kernel_lut(lut, packed)
-    g = blocks_per_step(blk)
-
-    def call(idx, lut_c):
-        steps = idx.shape[1] // g
-        kernel = pl.pallas_call(
-            _make_kernel(packed),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(idx.shape[0], steps),
-                in_specs=[
-                    pl.BlockSpec((query_tile, 1, m * k),
-                                 lambda qi, si, idx: (qi, 0, 0)),
-                    *_paged_specs((1, blk, mb), g),
-                ],
-                out_specs=pl.BlockSpec((query_tile, 1, 1, g * blk),
-                                       lambda qi, si, idx: (qi, si, 0, 0)),
-            ),
-            out_shape=jax.ShapeDtypeStruct((lut_c.shape[0], steps, 1,
-                                            g * blk), jnp.float32),
-            interpret=interpret,
-            name="pq_scan",
-        )
-        return kernel(idx, lut_c, *[block_codes] * g)
-
-    out = _map_query_chunks(call, _pad_positions(tile_idx, g), [lut],
-                            query_tile)
-    return out.reshape(b, -1, blk)[:, :s]
-
-
-def pq_scan_paged_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
-                         block_idx: jnp.ndarray, *, query_tile: int = 8,
-                         interpret: bool = False, packed: bool = False,
-                         debug: bool = False) -> jnp.ndarray:
-    """lut (B, M, K) f32, block_codes (TB, BLK, M) uint8, block_idx (B, S)
-    -> (B, S, BLK) f32.  B % query_tile == 0; block_idx entries must be
-    valid (callers clamp padding to 0 and mask downstream).
-
-    Paging is per (query-tile, position): with query_tile == 1 every query
-    pages its own scan list; with query_tile > 1 every query of a tile
-    MUST carry the same scan list (the paper's §5.3 list-major batch mode
-    — see ops.pq_scan_grouped / ops.pq_scan_tiled), because only row 0 of
-    each tile drives the paging index_map.  The invariant is enforced:
-    eager calls raise ``ValueError`` on mismatched tile rows, and traced
-    calls with ``debug=True`` emit a ``checkify.check`` (run the caller
-    under ``checkify.checkify`` and ``err.throw()``) — misuse fails
-    loudly instead of silently scoring the wrong blocks."""
-    b = lut.shape[0]
-    assert b % query_tile == 0, (b, query_tile)
-    qb = b // query_tile
-    s = block_idx.shape[1]
-    rows = block_idx.reshape(qb, query_tile, s)
-    if query_tile > 1:
-        shared = jnp.all(rows == rows[:, :1, :])
-        if not isinstance(block_idx, jax.core.Tracer):
-            if not bool(shared):
-                raise ValueError(
-                    f"pq_scan_paged_kernel: query_tile={query_tile} but the "
-                    "tile rows of block_idx disagree — per-tile paging "
-                    "scores row 0's list for the whole tile.  Use "
-                    "query_tile=1 (per-query paging) or a tile-shared scan "
-                    "list (ops.pq_scan_grouped / ops.pq_scan_tiled).")
-        elif debug:
-            checkify.check(
-                shared, "pq_scan_paged_kernel: tile rows of block_idx "
-                "disagree under query_tile > 1 (tile-shared-list invariant)")
-    return pq_scan_tiled_kernel(lut, block_codes, rows[:, 0, :],
-                                query_tile=query_tile, interpret=interpret,
-                                packed=packed)
 
 
 def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
@@ -335,8 +228,7 @@ def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
             acc_id_ref[...] = jnp.full((qt, 1, f), -1, jnp.int32)
             dco_ref[...] = jnp.zeros((qt, 1, 1), jnp.int32)
 
-        # -- score the paged blocks: the unfused kernel's contraction, so
-        # distances are bitwise identical
+        # -- score the paged blocks
         d = _score_block(lut_ref, codes_refs, packed)          # (QT, n)
 
         # -- in-kernel keep mask (Alg. 5 L15-16, scan_blocks' post-hoc

@@ -141,7 +141,7 @@ def run_gateway(serving, args, q, compact_async: bool = False):
     params = SearchParams(
         k=args.k, nprobe=args.nprobe, max_scan=args.max_scan,
         exec_mode=args.exec_mode, use_kernel=args.use_kernel,
-        fused_topk=args.fused_topk, plan_reuse=args.plan_reuse,
+        plan_reuse=args.plan_reuse,
         refine=refine_params(args))
     ladder = (degrade_ladder(params, levels=args.degrade_levels)[1:]
               if args.degrade_levels else None)
@@ -240,10 +240,6 @@ def main():
                          "report plan-cache stats")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route the ADC scan through the Pallas kernel")
-    ap.add_argument("--fused-topk", action="store_true",
-                    help="fuse candidate selection into the scan stage "
-                         "(with --use-kernel: VMEM-resident top-k inside "
-                         "the Pallas kernel, DESIGN.md §9)")
     ap.add_argument("--refine-plane", default=None,
                     choices=("pq4", "binary", "full"),
                     help="two-tier ladder (DESIGN.md §12): scan this "
@@ -438,7 +434,7 @@ def main():
     searcher = serving.searcher(SearchParams(
         k=args.k, nprobe=args.nprobe, max_scan=args.max_scan,
         exec_mode=args.exec_mode, use_kernel=args.use_kernel,
-        fused_topk=args.fused_topk, plan_reuse=args.plan_reuse,
+        plan_reuse=args.plan_reuse,
         refine=refine_params(args)))
 
     # score against the index's own live corpus (== x when freshly built;

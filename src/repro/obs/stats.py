@@ -9,9 +9,9 @@ docstring; locked by tests/test_obs.py and rendered to Prometheus text
 by ``repro.obs.to_prometheus``).
 
 ``scan_traffic_model`` is the single definition of the scan/finalize
-boundary traffic model ``bench_fused`` introduced; ``benchmarks/
-roofline.py`` re-exports it so benchmark reports and serving snapshots
-use identical accounting.
+boundary traffic model: what the scan stage's top-``fetch`` selection
+saves against writing the full candidate stream (tests/test_obs.py
+holds it to a 4x cut at the benchmark's deployments).
 """
 from __future__ import annotations
 
@@ -58,9 +58,7 @@ def session_traffic_model(searcher) -> Dict[str, Any]:
     widened ``bigk_eff`` survivor budget, the modeled per-query code
     read traffic of each tier-1 variant, and the weighted total-ops
     model (tier-1 LUT lookups + tier-2 exact dims) against the
-    single-tier baseline — the same accounting ``bench_refine`` and
-    ``check_regression`` gate on, so serving snapshots and committed
-    benches can never disagree about the claimed reduction."""
+    single-tier baseline."""
     from ..core.search import finalize_fetch
     p = searcher.params
     idx = searcher.index

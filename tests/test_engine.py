@@ -11,8 +11,8 @@ import pytest
 from repro.core import IndexConfig, build_index
 from repro.core.distributed import distributed_search
 from repro.core.engine import (BlockStore, batch_union, plan_blocks,
-                               scan_blocks, select_lists, store_from_arrays,
-                               tables_from_arrays)
+                               scan_blocks_topk, select_lists,
+                               store_from_arrays, tables_from_arrays)
 from repro.core.engine.types import BIG
 from repro.kernels.ref import pq_scan_paged_ref
 
@@ -111,8 +111,9 @@ def test_plan_budget_and_dropped(rairs_index, unit_data):
 
 
 def test_scan_grouped_kernel_matches_oracle():
-    """pq_scan_grouped through the engine == jnp oracle on a synthetic
-    store (the §5.3 kernel path, interpret mode on CPU)."""
+    """The grouped exec mode's kernel path through the engine's scan
+    stage, at a fetch that keeps every candidate, == jnp oracle on a
+    synthetic store (the §5.3 kernel path, interpret mode on CPU)."""
     key = jax.random.PRNGKey(3)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     b, m, kk, tbn, blk, s = 8, 8, 16, 24, 32, 6
@@ -125,21 +126,31 @@ def test_scan_grouped_kernel_matches_oracle():
     nlist = 16
     sel = jax.random.randint(k4, (b, 4), 0, nlist, jnp.int32)
     rank_of = jnp.full((b, nlist), BIG, jnp.int32)
-    blocks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0, tbn,
-                                jnp.int32)
+    # a plan lists each block at most once per query (plan_blocks'
+    # dedup), which the kernel path's slot maps rely on
+    blocks = jax.vmap(lambda kq: jax.random.permutation(kq, tbn)[:s])(
+        jax.random.split(jax.random.PRNGKey(5), b)).astype(jnp.int32)
     from repro.core.engine.types import QueryPlan
     plan = QueryPlan(blocks=blocks, ranks=jnp.zeros((b, s), jnp.int32),
                      valid=jnp.ones((b, s), bool),
                      dropped=jnp.zeros((b,), jnp.int32))
-    out_k = scan_blocks(store, plan, lut, rank_of, exec_mode="grouped",
-                        use_kernel=True, query_tile=4)
-    ref = np.asarray(pq_scan_paged_ref(lut, store.block_codes, blocks)
-                     ).reshape(b, -1)
-    np.testing.assert_allclose(np.asarray(out_k.flat_d), ref,
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(
-        np.asarray(out_k.flat_i),
-        np.asarray(store.block_ids[blocks]).reshape(b, -1))
+    out_k = scan_blocks_topk(store, plan, lut, rank_of, fetch=s * blk,
+                             exec_mode="grouped", use_kernel=True,
+                             query_tile=4)
+    ref_d = np.asarray(pq_scan_paged_ref(lut, store.block_codes, blocks)
+                       ).reshape(b, -1)
+    ref_i = np.asarray(store.block_ids[blocks]).reshape(b, -1)
+    out_d, out_i = np.asarray(out_k.flat_d), np.asarray(out_k.flat_i)
+    # the stage returns the stream sorted by distance; compare it with
+    # the oracle's stream ordered by (id, distance) on both sides
+    for q in range(b):
+        o, r = np.lexsort((out_d[q], out_i[q])), np.lexsort((ref_d[q],
+                                                             ref_i[q]))
+        np.testing.assert_array_equal(out_i[q][o], ref_i[q][r])
+        np.testing.assert_allclose(out_d[q][o], ref_d[q][r],
+                                   rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(out_k.approx_dco),
+                                  np.full(b, s * blk))
 
 
 def test_kernel_exec_modes_agree_end_to_end(rairs_index, unit_data):

@@ -31,7 +31,8 @@ from repro.errors import (CorruptBundleError, DeadlineExceeded,
                           FaultInjected, GatewayClosed, HandoverFailed,
                           Overloaded, RairsError)
 from repro.faults import FaultPlan, FaultSpec
-from repro.gateway import Gateway, GatewayConfig, degrade_ladder
+from repro.gateway import (Gateway, GatewayConfig, degrade_ladder,
+                           run_open_loop)
 
 CHAOS_SEED = int(os.environ.get("RAIRS_CHAOS_SEED", "0"))
 
@@ -135,6 +136,38 @@ def test_overload_chaos_every_request_resolves(rairs_index, unit_data):
     c = snap["counters"]
     assert c["requests"] == n
     assert c["responses"] == ok and c["shed"] == shed
+
+
+@pytest.mark.parametrize("mode", ["unbounded", "shed", "degrade"])
+def test_open_loop_overload_resolves_typed(rairs_index, unit_data, mode):
+    """An open-loop Poisson stream far past saturation, with injected
+    dispatch latency, through each admission mode — no bound, a bounded
+    queue that rejects, and the bounded queue with the quality ladder:
+    every request is answered or fails typed (ok + shed + deadline +
+    closed == submitted, no untyped error), and the unbounded gateway
+    never sheds."""
+    _, q, _ = unit_data
+    params = SearchParams(k=10, nprobe=8, max_scan=16)
+    base = dict(max_batch=8, max_delay_ms=1.0, warmup=False)
+    bounded = dict(base, max_queue=16, overload="reject")
+    cfg = {"unbounded": GatewayConfig(**base),
+           "shed": GatewayConfig(**bounded),
+           "degrade": GatewayConfig(
+               **bounded, degrade=degrade_ladder(params, levels=2)[1:],
+               degrade_hold=2)}[mode]
+    n = 128
+    plan = FaultPlan(CHAOS_SEED, (
+        FaultSpec("gateway.dispatch", kind="delay", prob=0.5,
+                  delay_s=0.02),))
+    with plan.installed():
+        with Gateway(rairs_index, params, config=cfg) as gw:
+            pt = run_open_loop(gw, np.asarray(q), 2000.0, n,
+                               seed=CHAOS_SEED)
+    assert (pt["n_ok"] + pt["shed"] + pt["deadline_failed"] + pt["closed"]
+            + pt["errors"]) == n, pt
+    assert pt["errors"] == 0, pt
+    if mode == "unbounded":
+        assert pt["shed"] == 0, pt
 
 
 def test_block_policy_applies_backpressure(rairs_index, unit_data):

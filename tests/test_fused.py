@@ -1,13 +1,15 @@
-"""Fused scan -> top-k parity (engine/fused.py + kernels/topk.py).
+"""Scan stage -> top-k parity (engine/fused.py + kernels/topk.py).
 
 The contract under test: ``scan_blocks_topk`` — oracle or Pallas kernel
 — returns bitwise the stable ``preselect_candidates`` selection over
-``scan_blocks``' unfused candidate stream (ties broken by flat plan
-position, masked entries normalized to ``(+inf, -1)``), with logical
-DCO accounting unchanged.  Covered across exec modes, tombstones,
-synthetic adversarial plans (duplicate distances, duplicate ids, dead
-items), and end-to-end through the frozen / streaming / sharded
-pipelines.
+the full candidate stream (ties broken by flat plan position, masked
+entries normalized to ``(+inf, -1)``), with logical DCO accounting
+unchanged.  The stream is ``scan_blocks``' (the jnp scorer) or, for the
+kernel, the kernel's own full-width stream (``scan_stream``) under
+``scan_blocks``' masks, so both sides carry the same rounding.  Covered
+across exec modes, tombstones, synthetic adversarial plans (duplicate
+distances, duplicate ids, dead items), and end to end through
+``seil_search`` against an in-test stage composition.
 """
 import jax
 import jax.numpy as jnp
@@ -15,13 +17,17 @@ import numpy as np
 import pytest
 from hypothesis_stub import given, settings, st
 
-from repro.core import IndexConfig, build_index
-from repro.core.engine import (BlockStore, QueryPlan, preselect_candidates,
-                               scan_blocks, scan_blocks_topk)
-from repro.core.params import SearchParams
-from repro.core.search import seil_search
+from repro.core.engine import (BIG, BlockStore, QueryPlan, batch_union,
+                               cluster_order, finalize_candidates, fit_tile,
+                               plan_blocks, preselect_candidates, scan_blocks,
+                               scan_blocks_topk, select_lists,
+                               store_from_arrays, tables_from_arrays,
+                               tile_unions, union_dims)
+from repro.core.pq import pq_lut
+from repro.core.search import SearchResult, finalize_fetch, seil_search
 from repro.kernels.topk import PAD_POS, bitonic_sort, merge_topf, pow2_ceil
 from repro.quant.nibbles import pack_nibbles
+from scan_stream import kernel_stream
 
 EXEC_MODES = ("paged", "grouped", "clustered")
 
@@ -88,47 +94,6 @@ def test_pow2_ceil():
 
 
 # ---------------------------------------------------------------------------
-# satellite: pq_scan_paged_kernel tile-row invariant fails loudly
-# ---------------------------------------------------------------------------
-
-def test_paged_kernel_tile_row_invariant():
-    from jax.experimental import checkify
-
-    from repro.kernels.pq_scan import pq_scan_paged_kernel
-    rng = np.random.default_rng(3)
-    lut = jnp.asarray(rng.standard_normal((4, 4, 16)).astype(np.float32))
-    codes = jnp.asarray(rng.integers(0, 16, (6, 8, 4)).astype(np.uint8))
-    per_query = jnp.asarray(rng.integers(0, 6, (4, 3)).astype(np.int32))
-    shared = jnp.repeat(per_query[::2], 2, axis=0)     # rows agree per tile
-
-    # tile-shared rows: allowed, and row 0's list is really what's scored.
-    # A 2-row and a 1-row contraction may round differently in the last
-    # ulp, so they agree to the dot_general tolerance of the kernel tests
-    out = pq_scan_paged_kernel(lut, codes, shared, query_tile=2,
-                               interpret=True)
-    ref = pq_scan_paged_kernel(lut, codes, shared, query_tile=1,
-                               interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-    # eager misuse raises instead of silently scoring the wrong blocks
-    with pytest.raises(ValueError, match="tile rows"):
-        pq_scan_paged_kernel(lut, codes, per_query, query_tile=2,
-                             interpret=True)
-
-    # traced misuse is checkable via debug=True + checkify
-    def run(bi):
-        return pq_scan_paged_kernel(lut, codes, bi, query_tile=2,
-                                    interpret=True, debug=True)
-
-    err, _ = jax.jit(checkify.checkify(run))(per_query)
-    with pytest.raises(Exception, match="tile rows"):
-        err.throw()
-    err, _ = jax.jit(checkify.checkify(run))(shared)
-    err.throw()                                        # no error when shared
-
-
-# ---------------------------------------------------------------------------
 # engine-level parity on adversarial synthetic plans
 # ---------------------------------------------------------------------------
 
@@ -170,17 +135,54 @@ def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, nid=200,
         jnp.asarray(sel), live
 
 
+def _kernel_dists(store, plan, lut, sel, exec_mode, query_tile, packed):
+    """(B, S, BLK) plan-layout distances scored by the kernel, paged
+    exactly as the fused stage pages them (engine/fused.py): every
+    query its own list, the batch union per query tile, or per-tile
+    unions in cluster order — so the rounding is the fused stage's."""
+    b, s = plan.blocks.shape
+    codes = store.block_codes
+    if exec_mode == "paged":
+        return kernel_stream(lut, codes, plan.blocks, packed=packed)
+    if exec_mode == "grouped":
+        qt = fit_tile(b, query_tile)
+        union = batch_union(plan, codes.shape[0])
+        tile_idx = jnp.broadcast_to(jnp.where(union < BIG, union, 0)[None],
+                                    (b // qt, union.shape[0]))
+        d_u = kernel_stream(lut, codes, tile_idx, query_tile=qt,
+                            packed=packed)
+        pos = jnp.searchsorted(union, plan.blocks.reshape(-1))
+        pos = jnp.minimum(pos.reshape(b, s), union.shape[0] - 1)
+        return jnp.take_along_axis(d_u, pos[:, :, None], axis=1)
+    perm = cluster_order(sel)
+    pb = plan.blocks[perm]
+    t, w = union_dims(b, s, codes.shape[0], "clustered", query_tile)
+    unions = tile_unions(pb, plan.valid[perm], t, w)
+    qt = b // t
+    d_u = kernel_stream(lut[perm], codes, jnp.where(unions < BIG, unions, 0),
+                        query_tile=qt, packed=packed)
+    pos = jax.vmap(jnp.searchsorted)(unions, pb.reshape(t, qt * s))
+    pos = jnp.minimum(pos.reshape(b, s), w - 1)
+    d_p = jnp.take_along_axis(d_u, pos[:, :, None], axis=1)
+    return d_p[jnp.argsort(perm)]
+
+
 def _unfused_reference(store, plan, lut, rank_of, sel, live, fetch,
                        exec_mode, use_kernel=False, query_tile=4,
                        packed=False):
-    """scan_blocks + live mask + stable preselect — the ground truth the
-    fused stage must reproduce bitwise.  ``use_kernel`` must match the
-    fused side so both streams carry the same ADC rounding (one-hot
-    dot_general vs gather-sum differ in the last ulp)."""
+    """Full candidate stream + live mask + stable preselect — the
+    ground truth the scan stage must reproduce bitwise.  The stream is
+    ``scan_blocks``' (masks and DCO always, distances without the
+    kernel); with ``use_kernel`` its kept distances are the kernel's
+    own (``_kernel_dists``), so both sides carry the same ADC rounding
+    (one-hot dot_general vs gather-sum differ in the last ulp)."""
     out = scan_blocks(store, plan, lut, rank_of, exec_mode=exec_mode,
-                      sel=sel, use_kernel=use_kernel, query_tile=query_tile,
-                      packed=packed)
+                      sel=sel, query_tile=query_tile, packed=packed)
     d = out.flat_d
+    if use_kernel:
+        kd = _kernel_dists(store, plan, lut, sel, exec_mode, query_tile,
+                           packed).reshape(d.shape)
+        d = jnp.where(jnp.isfinite(d), kd, jnp.inf)
     if live is not None:
         dead = (out.flat_i >= 0) & ~live[jnp.maximum(out.flat_i, 0)]
         d = jnp.where(dead, jnp.inf, d)
@@ -269,7 +271,7 @@ def test_property_fused_topk_order(blk, s, seed, exec_mode, fetch,
 
 
 # ---------------------------------------------------------------------------
-# end-to-end: frozen / streaming / sharded pipelines, fused == unfused
+# end to end: seil_search == select -> plan -> reference stream -> finalize
 # ---------------------------------------------------------------------------
 
 def _assert_results_equal(a, b):
@@ -285,67 +287,34 @@ def _assert_results_equal(a, b):
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_seil_search_fused_parity(rairs_index, unit_data, exec_mode,
                                   use_kernel):
+    """The served program against the stages composed by hand around
+    the reference stream: the same ids, distances and DCO, bitwise."""
     _, q, _ = unit_data
     idx = rairs_index
-    kw = dict(nprobe=8, bigk=32, k=10, max_scan=idx.default_max_scan(8),
+    assert idx.config.metric == "l2"
+    kw = dict(bigk=32, k=10, metric="l2",
               dedup_results=idx.needs_result_dedup,
-              oversample=idx.result_oversample, exec_mode=exec_mode,
-              query_tile=4)
-    base = seil_search(idx.arrays, idx.centroids, idx.codebook, idx.vectors,
-                       q[:16], use_kernel=use_kernel, **kw)
-    fused = seil_search(idx.arrays, idx.centroids, idx.codebook, idx.vectors,
-                        q[:16], use_kernel=use_kernel, fused_topk=True, **kw)
-    _assert_results_equal(fused, base)
+              oversample=idx.result_oversample)
+    max_scan = idx.default_max_scan(8)
+    served = seil_search(idx.arrays, idx.centroids, idx.codebook,
+                         idx.vectors, q[:16], nprobe=8, max_scan=max_scan,
+                         use_kernel=use_kernel, exec_mode=exec_mode,
+                         query_tile=4, **kw)
 
+    @jax.jit
+    def composed(queries):
+        selection = select_lists(queries, idx.centroids, nprobe=8)
+        plan = plan_blocks(tables_from_arrays(idx.arrays), selection,
+                           max_scan=max_scan)
+        cd, ci, dco = _unfused_reference(
+            store_from_arrays(idx.arrays), plan,
+            pq_lut(idx.codebook, queries), selection.rank_of, selection.sel,
+            None, finalize_fetch(kw["bigk"], kw["oversample"],
+                                 kw["dedup_results"]),
+            exec_mode, use_kernel=use_kernel)
+        ids, dists, refine_dco = finalize_candidates(
+            cd, ci, vectors=idx.vectors, queries=queries, **kw)
+        return SearchResult(ids, dists, dco, refine_dco,
+                            plan.valid.sum(1), plan.dropped)
 
-@pytest.mark.parametrize("exec_mode", EXEC_MODES)
-def test_streaming_fused_parity(rairs_index, unit_data, exec_mode):
-    from repro.core.stream import StreamingIndex
-    x, q, _ = unit_data
-    rng = np.random.default_rng(11)
-    st_idx = StreamingIndex(rairs_index)
-    st_idx.insert(jnp.asarray(
-        rng.standard_normal((37, x.shape[1])).astype(np.float32)))
-    st_idx.delete(jnp.arange(0, 60, 5, dtype=jnp.int32))
-    for uk in (False, True):
-        base = st_idx.searcher(SearchParams(
-            k=10, nprobe=8, exec_mode=exec_mode, query_tile=4,
-            use_kernel=uk))(q[:16])
-        fused = st_idx.searcher(SearchParams(
-            k=10, nprobe=8, exec_mode=exec_mode, query_tile=4,
-            use_kernel=uk, fused_topk=True))(q[:16])
-        _assert_results_equal(fused, base)
-
-
-def test_streaming_fused_parity_plan_reuse(rairs_index, unit_data):
-    from repro.core.stream import StreamingIndex
-    x, q, _ = unit_data
-    rng = np.random.default_rng(13)
-    st_idx = StreamingIndex(rairs_index)
-    st_idx.insert(jnp.asarray(
-        rng.standard_normal((21, x.shape[1])).astype(np.float32)))
-    st_idx.delete(jnp.arange(0, 40, 7, dtype=jnp.int32))
-    base = st_idx.searcher(SearchParams(
-        k=10, nprobe=8, exec_mode="clustered", query_tile=4,
-        plan_reuse=True, use_kernel=True))
-    fused = st_idx.searcher(SearchParams(
-        k=10, nprobe=8, exec_mode="clustered", query_tile=4,
-        plan_reuse=True, use_kernel=True, fused_topk=True))
-    for lo in (0, 8):                     # second batch hits the plan cache
-        _assert_results_equal(fused(q[lo:lo + 8]), base(q[lo:lo + 8]))
-
-
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_sharded_fused_parity(rairs_index, unit_data, use_kernel):
-    """Mesh sessions now run the (interpret-mode) kernel path too: the
-    fused per-device top-fetch replaces the preselect before the gather."""
-    _, q, _ = unit_data
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
-    sh = rairs_index.shard(mesh)
-    base = sh.searcher(SearchParams(k=10, nprobe=8, exec_mode="grouped",
-                                    query_tile=4,
-                                    use_kernel=use_kernel))(q[:16])
-    fused = sh.searcher(SearchParams(k=10, nprobe=8, exec_mode="grouped",
-                                     query_tile=4, use_kernel=use_kernel,
-                                     fused_topk=True))(q[:16])
-    _assert_results_equal(fused, base)
+    _assert_results_equal(served, composed(q[:16]))

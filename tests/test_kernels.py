@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweep vs the pure-jnp oracle
-(interpret mode executes the kernel body on CPU)."""
+(interpret mode executes the kernel body on CPU).  The scan kernel is
+read at full width (``scan_stream.kernel_stream``): every scored
+candidate, scattered back to (B, S, BLK) by its plan-layout position."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,13 +11,12 @@ from hypothesis_stub import given, settings, st
 from jax.experimental import pallas as pl
 from jax.extend import core as jcore
 
-from repro.kernels.ops import (_align, pq_scan_grouped, pq_scan_paged,
-                               pq_scan_tiled)
+from repro.kernels.ops import _align
 from repro.kernels.pq_scan import (_kernel_lut, _score_block,
-                                   blocks_per_step, pq_scan_tiled_kernel,
-                                   pq_scan_topk_kernel)
+                                   blocks_per_step, pq_scan_topk_kernel)
 from repro.kernels.ref import onehot_lut_ref, pq_scan_paged_ref
 from repro.quant.nibbles import pack_nibbles
+from scan_stream import kernel_stream
 
 
 @pytest.mark.parametrize("b,m,k,tb,blk,s", [
@@ -34,7 +35,7 @@ def test_pq_scan_paged_matches_ref(b, m, k, tb, blk, s):
     lut = jax.random.normal(k1, (b, m, k), jnp.float32)
     codes = jax.random.randint(k2, (tb, blk, m), 0, k).astype(jnp.uint8)
     idx = jax.random.randint(k3, (b, s), 0, tb, jnp.int32)
-    out = pq_scan_paged(lut, codes, idx)
+    out = kernel_stream(lut, codes, idx)
     ref = pq_scan_paged_ref(lut, codes, idx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -47,7 +48,7 @@ def test_pq_scan_dtypes(dtype):
     lut = jax.random.normal(k1, (4, 8, 16), jnp.float32).astype(dtype)
     codes = jax.random.randint(k2, (6, 32, 8), 0, 16).astype(jnp.uint8)
     idx = jax.random.randint(k3, (4, 3), 0, 6, jnp.int32)
-    out = pq_scan_paged(lut.astype(jnp.float32), codes, idx)
+    out = kernel_stream(lut.astype(jnp.float32), codes, idx)
     ref = pq_scan_paged_ref(lut.astype(jnp.float32), codes, idx)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -62,7 +63,10 @@ def test_grouped_mode_query_tiles():
     codes = jax.random.randint(k2, (tb, blk, m), 0, kk).astype(jnp.uint8)
     sidx = jax.random.randint(k3, (s,), 0, tb, jnp.int32)
     for qt in (1, 2, 4, 8):
-        out = pq_scan_grouped(lut, codes, sidx, query_tile=qt)
+        # the grouped exec mode's paging: one shared list for every tile
+        out = kernel_stream(lut, codes,
+                            jnp.broadcast_to(sidx[None], (b // qt, s)),
+                            query_tile=qt)
         ref = pq_scan_paged_ref(lut, codes,
                                 jnp.broadcast_to(sidx[None], (b, s)))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -70,10 +74,10 @@ def test_grouped_mode_query_tiles():
 
 
 def test_tiled_mode_per_tile_lists():
-    """pq_scan_tiled: each query tile pages its own (tile-padded) scan
-    list through the scalar-prefetched index_map — the clustered exec
-    mode's kernel path, validated in interpret mode on CPU against the
-    per-query oracle fed the tile-broadcast lists."""
+    """Each query tile pages its own (tile-padded) scan list through
+    the scalar-prefetched index_map — the clustered exec mode's kernel
+    path, validated in interpret mode on CPU against the per-query
+    oracle fed the tile-broadcast lists."""
     key = jax.random.PRNGKey(13)
     k1, k2, k3 = jax.random.split(key, 3)
     b, m, kk, tb, blk, w = 16, 8, 16, 20, 32, 5
@@ -82,7 +86,7 @@ def test_tiled_mode_per_tile_lists():
     for qt in (1, 2, 4, 8, 16):
         tiles = b // qt
         tile_idx = jax.random.randint(k3, (tiles, w), 0, tb, jnp.int32)
-        out = pq_scan_tiled(lut, codes, tile_idx, query_tile=qt)
+        out = kernel_stream(lut, codes, tile_idx, query_tile=qt)
         full = jnp.repeat(tile_idx, qt, axis=0)          # (B, W) broadcast
         ref = pq_scan_paged_ref(lut, codes, full)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -92,7 +96,7 @@ def test_tiled_mode_per_tile_lists():
 @pytest.mark.parametrize("blk,g", [(8, 16), (32, 4), (64, 2), (128, 1),
                                    (256, 1)])
 def test_blocks_per_step(blk, g):
-    """A grid step of either scan kernel pages the blocks that fill the
+    """A grid step of the scan kernel pages the blocks that fill the
     128 lanes: four at the deployments' block 32, one from 128 up."""
     assert blocks_per_step(blk) == g
 
@@ -155,9 +159,8 @@ def _kernel_jaxprs(closed):
 
 
 @pytest.mark.parametrize("packed", [False, True])
-@pytest.mark.parametrize("fused", [False, True])
-def test_kernel_bodies_merge_no_lanes(fused, packed):
-    """No reshape inside either kernel body changes the minor (lane)
+def test_kernel_bodies_merge_no_lanes(packed):
+    """No reshape inside the kernel body changes the minor (lane)
     dimension: a lane-merging reshape of the one-hot or the LUT lowers
     on TPU as sublane rotates and shuffles on every grid step."""
     b, m, kk, tb, blk, s, nlist = 8, 16, 16, 4, 32, 3, 8
@@ -165,15 +168,11 @@ def test_kernel_bodies_merge_no_lanes(fused, packed):
     lut = jnp.zeros((b, m, kk), jnp.float32)
     codes = jnp.zeros((tb, blk, mb), jnp.uint8)
     idx = jnp.zeros((b, s), jnp.int32)
-    if fused:
-        plane = jnp.zeros((tb, blk), jnp.int32)
-        closed = jax.make_jaxpr(lambda *a: pq_scan_topk_kernel(
-            *a, query_tile=1, fetch=16, interpret=True, packed=packed))(
-            lut, codes, plane, plane, idx, jnp.zeros((b, nlist), jnp.int32),
-            idx, idx)
-    else:
-        closed = jax.make_jaxpr(lambda *a: pq_scan_tiled_kernel(
-            *a, query_tile=1, interpret=True, packed=packed))(lut, codes, idx)
+    plane = jnp.zeros((tb, blk), jnp.int32)
+    closed = jax.make_jaxpr(lambda *a: pq_scan_topk_kernel(
+        *a, query_tile=1, fetch=16, interpret=True, packed=packed))(
+        lut, codes, plane, plane, idx, jnp.zeros((b, nlist), jnp.int32),
+        idx, idx)
     bodies = _kernel_jaxprs(closed)
     assert bodies
     for jaxpr in bodies:
@@ -195,5 +194,5 @@ def test_property_pq_scan(seed, m, blk, s, b):
     codes = jax.random.randint(k2, (tb, blk, m), 0, 16).astype(jnp.uint8)
     idx = jax.random.randint(k3, (b, s), 0, tb, jnp.int32)
     np.testing.assert_allclose(
-        np.asarray(pq_scan_paged(lut, codes, idx)),
+        np.asarray(kernel_stream(lut, codes, idx)),
         np.asarray(pq_scan_paged_ref(lut, codes, idx)), rtol=1e-5, atol=1e-5)

@@ -75,9 +75,9 @@ def test_disabled_tracing_does_no_work(rairs_index, unit_data):
 
 @pytest.mark.parametrize("label,params,expect_spans", [
     ("paged", SearchParams(k=10, nprobe=8),
-     {"stage.select_lists", "stage.plan_blocks", "stage.scan_blocks",
+     {"stage.select_lists", "stage.plan_blocks", "stage.scan_blocks_topk",
       "stage.finalize"}),
-    ("fused", SearchParams(k=10, nprobe=8, fused_topk=True),
+    ("fused", SearchParams(k=10, nprobe=8, use_kernel=True),
      {"stage.scan_blocks_topk"}),
     ("plan_reuse", SearchParams(k=10, nprobe=8, exec_mode="clustered",
                                 plan_reuse=True),
@@ -312,10 +312,41 @@ def test_snapshot_all_schema(rairs_index, unit_data):
     trace = snap["trace"]
     assert 0.0 < trace["stage_attribution"] <= 1.0
     assert trace["fences"] > 0 and trace["dropped"] == 0
-    assert trace["dco"]["stage.scan_blocks.approx_dco"] > 0
+    assert trace["dco"]["stage.scan_blocks_topk.approx_dco"] > 0
     assert trace["dco"]["stage.finalize.refine_dco"] > 0
     # the trace section renders to prometheus lines end-to-end
     assert "rairs_trace_stage_attribution" in obs.to_prometheus(snap)
+
+
+def _assert_boundary_traffic_cut(model):
+    """The scan stage's top-``fetch`` selection cuts the modeled bytes
+    per query crossing the scan/finalize boundary at least 4x, written
+    and round trip, against writing the full candidate stream."""
+    assert model["fused_scan_write"] < model["unfused_scan_write"], model
+    assert model["write_reduction_x"] >= 4.0, model
+    assert model["roundtrip_reduction_x"] >= 4.0, model
+
+
+@pytest.mark.parametrize("max_scan,k_factor", [(557, 10), (604, 50)],
+                         ids=["sift1m", "t2i1m"])
+def test_scan_traffic_model_at_the_deployments(max_scan, k_factor):
+    """At the benchmark deployments' widths: block 32, k 10, SEIL
+    layouts (no result dedup, so fetch = bigK)."""
+    from repro.core.search import finalize_fetch
+    from repro.obs.stats import scan_traffic_model
+    _assert_boundary_traffic_cut(scan_traffic_model(
+        scan_width=max_scan * 32,
+        fetch=finalize_fetch(10 * k_factor, 2, False)))
+
+
+def test_session_traffic_model_cuts_boundary_traffic(rairs_index):
+    """At a live session's own operating point (the unit index at
+    nprobe 16, its default scan budget)."""
+    from repro.obs.stats import session_traffic_model
+    model = session_traffic_model(
+        rairs_index.searcher(SearchParams(k=10, nprobe=16)))
+    assert model["scan_width"] > model["fetch"] > 0
+    _assert_boundary_traffic_cut(model["bytes_per_query"])
 
 
 def test_snapshot_all_with_gateway(rairs_index, unit_data):
@@ -378,7 +409,7 @@ def _session(label, rairs_index, unit_data, shared_trained):
         return stream.searcher(p)
     return rairs_index.searcher({
         "paged": p,
-        "fused": SearchParams(k=10, nprobe=8, fused_topk=True),
+        "fused": SearchParams(k=10, nprobe=8, use_kernel=True),
         "plan_reuse": SearchParams(k=10, nprobe=8, exec_mode="clustered",
                                    plan_reuse=True)}[label])
 

@@ -15,7 +15,10 @@ Key invariants:
     (nprobe = nlist), reduce DCO at serving nprobe, and keep inserted
     items retrievable;
   * the derived per-device ``max_scan_local`` (per-shard list occupancy)
-    never truncates a plan — recall-neutral vs an un-truncating budget.
+    never truncates a plan — recall-neutral vs an un-truncating budget;
+  * on a Zipf-skewed serving trace (and its uniform control) per-tile
+    unions cut the batch union, the plan cache's hit/extend/miss counts
+    partition its tiles, and every exec mode agrees on ids and DCO.
 """
 import dataclasses
 
@@ -25,10 +28,11 @@ import numpy as np
 import pytest
 from hypothesis_stub import given, settings, st
 
-from repro.core import (IndexConfig, SearchParams, StaleSessionError,
-                        StreamingIndex, build_index, cluster_order,
-                        merge_unions_host, plan_blocks, select_lists,
-                        tile_signatures, tile_unions, union_dims, union_live)
+from repro.core import (IndexConfig, SearchParams, Searcher,
+                        StaleSessionError, StreamingIndex, build_index,
+                        cluster_order, merge_unions_host, plan_blocks,
+                        select_lists, tile_signatures, tile_unions,
+                        union_dims, union_live)
 from repro.core.engine import tables_from_arrays
 from repro.core.engine.types import BIG
 
@@ -66,8 +70,9 @@ def test_clustered_equals_paged_under_budget_pressure(rairs_index,
 
 
 def test_clustered_kernel_path(rairs_index, unit_data):
-    """pq_scan_tiled through the engine (interpret mode): ids must match
-    paged-kernel, distances to kernel tolerance."""
+    """The clustered exec mode's per-tile kernel paging through the
+    engine (interpret mode): ids must match paged-kernel, distances to
+    kernel tolerance."""
     _, q, _ = unit_data
     qs = q[:8]
     rk_p = rairs_index.search(qs, k=10, nprobe=2, max_scan=24,
@@ -523,3 +528,95 @@ def test_plan_equivalence_property(seed, nprobe, exec_mode, query_tile,
                               )(qs)
         if sharded.ndev == 1:
             _assert_results_identical(ref, rs, msg="sharded ")
+
+
+# ---------------------------------------------------------------------------
+# a serving trace: Zipf-popular queries against a uniform control
+# ---------------------------------------------------------------------------
+SKEW_BATCH, SKEW_BATCHES, SKEW_TILE, SKEW_NPROBE = 256, 12, 16, 16
+
+
+@pytest.fixture(scope="module")
+def skewed_streams(unit_data):
+    """Two traces of 12 batches of 256 queries over the unit query pool:
+    ``uniform`` draws iid; ``zipf`` draws from 16 hot queries with
+    Zipf(1.1) popularity (the head of a search-query distribution).
+    Every draw gets Gaussian jitter at 2% of the pool's scale, so
+    batches are near-duplicates, not exact repeats."""
+    _, q, _ = unit_data
+    rng = np.random.default_rng(0)
+    pool = np.asarray(q)
+    scale = float(pool.std()) * 0.02
+    p = 1.0 / np.arange(1, 17) ** 1.1
+    p /= p.sum()
+    streams = {"uniform": [], "zipf": []}
+    for _ in range(SKEW_BATCHES):
+        for name, picks in (
+                ("uniform", rng.integers(0, pool.shape[0], SKEW_BATCH)),
+                ("zipf", rng.choice(16, SKEW_BATCH, p=p))):
+            qb = pool[picks] + rng.normal(0.0, scale,
+                                          (SKEW_BATCH, pool.shape[1]))
+            streams[name].append(jnp.asarray(qb, jnp.float32))
+    return streams
+
+
+def _skew_session(index, exec_mode, plan_reuse=False):
+    return Searcher(index, SearchParams(
+        k=10, nprobe=SKEW_NPROBE, exec_mode=exec_mode, plan_reuse=plan_reuse,
+        query_tile=SKEW_TILE, batch_buckets=(SKEW_BATCH,)))
+
+
+@pytest.mark.parametrize("stream", ["uniform", "zipf"])
+def test_skewed_stream_tile_unions_cut_the_batch_union(rairs_index,
+                                                       skewed_streams,
+                                                       stream):
+    """Clustered execution's per-tile block unions are a strict cut of
+    the batch-wide union the grouped mode scans, on both traces."""
+    for qb in skewed_streams[stream][:4]:
+        selection = select_lists(qb, rairs_index.centroids,
+                                 nprobe=SKEW_NPROBE)
+        plan = plan_blocks(tables_from_arrays(rairs_index.arrays), selection,
+                           max_scan=rairs_index.default_max_scan(SKEW_NPROBE))
+        blocks, valid = np.asarray(plan.blocks), np.asarray(plan.valid)
+        batch_live = len(np.unique(blocks[valid]))
+        perm = np.asarray(cluster_order(selection.sel))
+        t = SKEW_BATCH // SKEW_TILE
+        pb = blocks[perm].reshape(t, SKEW_TILE, -1)
+        pv = valid[perm].reshape(t, SKEW_TILE, -1)
+        tile_live = np.mean([len(np.unique(pb[i][pv[i]])) for i in range(t)])
+        assert tile_live < batch_live, (stream, tile_live, batch_live)
+
+
+@pytest.mark.parametrize("stream", ["uniform", "zipf"])
+def test_skewed_stream_plan_cache_accounting(rairs_index, skewed_streams,
+                                             stream):
+    """Over a whole trace, hit / extend / miss partition the tiles the
+    plan cache saw, and it reuses at least one; on the Zipf trace most
+    tiles hit at steady state."""
+    sess = _skew_session(rairs_index, "clustered", plan_reuse=True)
+    for qb in skewed_streams[stream]:
+        sess(qb)
+    p = sess.compile_stats()["plan"]
+    assert p["batches"] == SKEW_BATCHES
+    assert p["tiles"] == SKEW_BATCHES * SKEW_BATCH // SKEW_TILE
+    assert p["hits"] + p["extends"] + p["misses"] == p["tiles"], p
+    assert p["hits"] + p["extends"] > 0, p
+    if stream == "zipf":
+        assert p["hit_rate"] > 0.5, p
+
+
+@pytest.mark.parametrize("stream", ["uniform", "zipf"])
+def test_skewed_stream_exec_modes_agree(rairs_index, skewed_streams, stream):
+    """Paged, grouped and clustered-with-plan-reuse sessions return the
+    same ids and count the same ADC distance computations on every
+    batch of the trace."""
+    sessions = [_skew_session(rairs_index, "paged"),
+                _skew_session(rairs_index, "grouped"),
+                _skew_session(rairs_index, "clustered", plan_reuse=True)]
+    for qb in skewed_streams[stream]:
+        ref, *rest = [s(qb) for s in sessions]
+        for r in rest:
+            np.testing.assert_array_equal(np.asarray(r.ids),
+                                          np.asarray(ref.ids))
+            np.testing.assert_array_equal(np.asarray(r.approx_dco),
+                                          np.asarray(ref.approx_dco))

@@ -125,7 +125,7 @@ def test_two_tier_kernel_fused_parity(rairs_index, unit_data):
                      refine=ref))(q[:32])
     rk = rairs_index.searcher(
         SearchParams(k=10, nprobe=16, exec_mode="clustered", use_kernel=True,
-                     fused_topk=True, refine=ref))(q[:32])
+                     refine=ref))(q[:32])
     np.testing.assert_array_equal(np.asarray(rj.ids), np.asarray(rk.ids))
 
 

@@ -124,21 +124,23 @@ def test_searcher_counts_refined_rows_per_padded_dispatch(rairs_index,
 
 
 def test_searcher_reports_fused_topk_width(rairs_index):
-    """``compile_stats()["topk_width"]`` is the fused kernel's
+    """``compile_stats()["topk_width"]`` is the scan kernel's
     accumulator width F = pow2_ceil(fetch), the fetch capped by the scan
-    width; None for a session that does not run the fused kernel."""
+    width; None for a session that does not run the kernel."""
     from repro.kernels.topk import pow2_ceil
     blk = rairs_index.arrays.block_codes.shape[1]
     over = (rairs_index.result_oversample
             if rairs_index.needs_result_dedup else 1)
     assert rairs_index.searcher(SearchParams(
-        k=10, nprobe=4, use_kernel=True)).compile_stats()["topk_width"] is None
-    assert rairs_index.searcher(SearchParams(
-        k=10, nprobe=4, fused_topk=True)).compile_stats()["topk_width"] is None
+        k=10, nprobe=4)).compile_stats()["topk_width"] is None
+    kernel = rairs_index.searcher(SearchParams(
+        k=10, nprobe=4, use_kernel=True))
+    assert kernel.compile_stats()["topk_width"] == pow2_ceil(
+        min(100 * over, kernel.params.max_scan * blk))
     for k_factor, max_scan in ((10, None), (50, None), (100, 8)):
         sess = rairs_index.searcher(SearchParams(
             k=10, nprobe=4, k_factor=k_factor, max_scan=max_scan,
-            use_kernel=True, fused_topk=True))
+            use_kernel=True))
         fetch = min(10 * k_factor * over, sess.params.max_scan * blk)
         assert sess.compile_stats()["topk_width"] == pow2_ceil(fetch)
     assert sess.compile_stats()["topk_width"] == pow2_ceil(8 * blk)
@@ -190,6 +192,16 @@ def test_search_params_validation():
         SearchParams(batch_buckets=(8, 4))       # not ascending
     with pytest.raises(ValueError):
         SearchParams(query_tile=0)
+
+
+def test_search_params_pin_the_scan_stage_selection():
+    """The scan stage always selects the top ``fetch`` candidates:
+    ``fused_topk`` defaults to True, the benchmark's configs may say so,
+    and False is refused instead of silently ignored."""
+    assert SearchParams().fused_topk is True
+    assert SearchParams(fused_topk=True) == SearchParams()
+    with pytest.raises(ValueError, match="top fetch candidates"):
+        SearchParams(fused_topk=False)
 
 
 def test_search_params_resolve_pins_max_scan(rairs_index):
@@ -373,8 +385,7 @@ def test_ip_searcher_wide_refine_matches_exact_reference():
     recall = {}
     for k_factor in (10, 100):
         sess = index.searcher(SearchParams(
-            k=10, nprobe=4, k_factor=k_factor, use_kernel=True,
-            fused_topk=True))
+            k=10, nprobe=4, k_factor=k_factor, use_kernel=True))
         res = sess(q)
         ids, dists = np.asarray(res.ids), np.asarray(res.dists)
         assert sess.compile_stats()["topk_width"] == pow2_ceil(
