@@ -20,8 +20,9 @@ bitwise):
 
 ``use_kernel=True`` routes through the fused Pallas kernel
 (``kernels/pq_scan.py::pq_scan_topk_kernel``): the keep mask moves
-in-kernel (``rank_of`` rides the query-tile prefetch, ``block_ids`` /
-``block_other`` tiles are DMA'd alongside the code tiles) and the
+in-kernel (the query tile's ranked probe lists ``sel`` ride a per-tile
+VMEM block, ``block_ids`` / ``block_other`` tiles are DMA'd alongside
+the code tiles) and the
 top-``fetch`` accumulator lives in VMEM across the scan grid.  The
 kernel iterates *scan positions* (per-query plan slots in paged mode,
 sorted-union positions in grouped/clustered), so the plan layout is
@@ -76,8 +77,8 @@ def plan_slot_maps(blocks: jnp.ndarray, ranks: jnp.ndarray,
     return slot_of, rank_u
 
 
-def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, rank_of,
-                       *, fetch: int, exec_mode: str, query_tile: int,
+def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, *,
+                       fetch: int, exec_mode: str, query_tile: int,
                        sel, perm, unions, dead, packed: bool = False):
     """Per-exec-mode kernel dispatch: build (tile_idx, slot_of, rank_u)
     and run the fused Pallas kernel.  Returns (flat_d, flat_i, dco)."""
@@ -90,7 +91,7 @@ def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, rank_of,
         slot_of = jnp.where(plan.valid, slots, -1)
         d, _, ids, dco = pq_scan_topk(
             lut, store.block_codes, store.block_ids, store.block_other,
-            plan.blocks, rank_of, slot_of, plan.ranks, dead,
+            plan.blocks, sel, slot_of, plan.ranks, dead,
             fetch=fetch, query_tile=1, packed=packed)
         return d, ids, dco
 
@@ -105,7 +106,7 @@ def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, rank_of,
                                          plan.valid, union[None, :])
         d, _, ids, dco = pq_scan_topk(
             lut, store.block_codes, store.block_ids, store.block_other,
-            tile_idx, rank_of, slot_of, rank_u, dead,
+            tile_idx, sel, slot_of, rank_u, dead,
             fetch=fetch, query_tile=qt, packed=packed)
         return d, ids, dco
 
@@ -123,7 +124,7 @@ def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, rank_of,
     slot_of, rank_u = plan_slot_maps(pb, pr, pv, unions)
     d, _, ids, dco = pq_scan_topk(
         lut[perm], store.block_codes, store.block_ids, store.block_other,
-        safe_u, rank_of[perm], slot_of, rank_u, dead,
+        safe_u, sel[perm], slot_of, rank_u, dead,
         fetch=fetch, query_tile=qt, packed=packed)
     inv = jnp.argsort(perm)
     return d[inv], ids[inv], dco[inv]
@@ -132,8 +133,8 @@ def _fused_kernel_scan(store: BlockStore, plan: QueryPlan, lut, rank_of,
 def scan_blocks_topk(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
                      rank_of: jnp.ndarray, *, fetch: int,
                      exec_mode: str = "paged", use_kernel: bool = False,
-                     query_tile: int = 8, sel=None, perm=None, unions=None,
-                     live=None, packed: bool = False) -> ScanOut:
+                     query_tile: int = 8, sel: jnp.ndarray, perm=None,
+                     unions=None, live=None, packed: bool = False) -> ScanOut:
     """Scan + stable top-``fetch`` selection (see module docstring).
 
     Same arguments as ``scan_blocks`` plus ``use_kernel``, ``fetch`` (the
@@ -141,7 +142,9 @@ def scan_blocks_topk(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
     dedup-required layouts, ``bigk`` otherwise) and ``live`` (optional
     tombstone mask over the id space, applied pre-selection).
     ``packed`` marks the code store as a nibble-packed quant plane,
-    exactly as in ``scan_blocks``.
+    exactly as in ``scan_blocks``.  ``sel`` (B, P) is each query's
+    ranked probe list: the kernel tests the compute-once rule against it,
+    the oracle against ``rank_of`` (``rank_table(sel)``).
     """
     assert exec_mode in EXEC_MODES, exec_mode
     b, s = plan.blocks.shape
@@ -167,7 +170,7 @@ def scan_blocks_topk(store: BlockStore, plan: QueryPlan, lut: jnp.ndarray,
         dead = ((store.block_ids >= 0)
                 & ~live[jnp.maximum(store.block_ids, 0)]).astype(jnp.uint8)
     d, ids, dco = _fused_kernel_scan(
-        store, plan, lut, rank_of, fetch=fetch, exec_mode=exec_mode,
+        store, plan, lut, fetch=fetch, exec_mode=exec_mode,
         query_tile=query_tile, sel=sel, perm=perm, unions=unions, dead=dead,
         packed=packed)
     return ScanOut(

@@ -292,8 +292,8 @@ def scan_finalize(
             store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
             fetch=finalize_fetch(bigk, oversample, dedup_results),
             exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, perm=probe.perm, unions=unions,
-            packed=packed_codes)
+            query_tile=query_tile, sel=probe.sel, perm=probe.perm,
+            unions=unions, packed=packed_codes)
     with jax.named_scope("finalize"):
         out_ids, out_d, refine_dco = finalize_candidates(
             scan.flat_d, scan.flat_i, bigk=bigk, k=k, vectors=vectors,

@@ -283,8 +283,8 @@ def scan_finalize_stream(
             store_from_arrays(arrays), probe.plan, probe.lut, probe.rank_of,
             fetch=finalize_fetch(bigk, oversample, dedup_results),
             exec_mode=exec_mode, use_kernel=use_kernel,
-            query_tile=query_tile, perm=probe.perm, unions=unions,
-            live=live, packed=packed_codes)
+            query_tile=query_tile, sel=probe.sel, perm=probe.perm,
+            unions=unions, live=live, packed=packed_codes)
         dd, di, delta_dco = _delta_candidates(
             probe.lut, delta_codes, delta_ids, delta_post, delta_assigns,
             probe.sel, probe.rank_of, route_delta)

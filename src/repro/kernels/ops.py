@@ -78,7 +78,7 @@ def _align(lut, block_codes, packed: bool, on_tpu: bool):
 
 def pq_scan_topk(lut: jnp.ndarray, block_codes: jnp.ndarray,
                  block_ids: jnp.ndarray, block_other: jnp.ndarray,
-                 tile_idx: jnp.ndarray, rank_of: jnp.ndarray,
+                 tile_idx: jnp.ndarray, sel: jnp.ndarray,
                  slot_of: jnp.ndarray, rank_u: jnp.ndarray, dead=None,
                  *, fetch: int, query_tile: int = 8, packed: bool = False):
     """Fused scan -> top-``fetch``: the paged ADC scan with the keep mask
@@ -87,13 +87,15 @@ def pq_scan_topk(lut: jnp.ndarray, block_codes: jnp.ndarray,
     (S, BLK) scores.  tile_idx (B // query_tile, S) pages per-tile scan
     lists (at query_tile 1, every query its own); ``slot_of``/``rank_u``
     (B, S) map each scan position back to the query's plan slot (see
-    ``core/engine/fused.py`` for the per-exec-mode construction).
+    ``core/engine/fused.py`` for the per-exec-mode construction); ``sel``
+    (B, P) is each query's ranked probe list, against which the kernel
+    tests SEIL's compute-once rule.
     Returns (acc_d, acc_pos, acc_id, dco) — (B, fetch) sorted candidate
     triple + (B,) logical DCO."""
     interpret = _interpret()
     lut, block_codes = _align(lut, block_codes, packed, not interpret)
     return pq_scan_topk_kernel(
         lut, block_codes, block_ids, block_other,
-        tile_idx.astype(jnp.int32), rank_of, slot_of, rank_u, dead,
+        tile_idx.astype(jnp.int32), sel, slot_of, rank_u, dead,
         query_tile=query_tile, fetch=fetch, interpret=interpret,
         packed=packed)

@@ -36,6 +36,10 @@ with no per-item scalar work) to the MXU:
   * each step's candidates are masked (invalid slots and items, misc
     duplicates, tombstones), bitonic-sorted and merged into a top-F
     accumulator that stays in VMEM for the whole scan (kernels/topk.py).
+    A misc duplicate is an item whose co-assigned list the query probed
+    at an earlier rank (SEIL's compute-once rule): the kernel compares
+    the items' lists with the query's ranked probe list ``sel`` (P on
+    sublanes), integer compares with nothing of size nlist.
 
 Production tiling notes (TPU v5e): native block size 128 (lane width)
 instead of the paper's 32 — ``block`` stays a config knob and the
@@ -71,6 +75,8 @@ from .topk import PAD_POS, bitonic_sort, merge_topf, pow2_ceil
 SMEM_PREFETCH_BYTES = 256 * 1024
 # lanes of a vreg: the width one grid step fills with candidates
 _LANES = 128
+# sublanes of a vreg: the probe list is padded to a multiple of it
+_SUBLANES = 8
 
 
 def _tiles_per_call(qb: int, s: int) -> int:
@@ -181,7 +187,7 @@ def blocks_per_step(blk: int) -> int:
 
 
 def _pad_positions(x: jnp.ndarray, g: int, value: int = 0) -> jnp.ndarray:
-    """Pad the scan-position axis of a (rows, S) list to a multiple of g."""
+    """Pad axis 1 of a (rows, S) list to a multiple of g."""
     pad = -x.shape[1] % g
     return jnp.pad(x, ((0, 0), (0, pad)), constant_values=value) if pad else x
 
@@ -212,7 +218,7 @@ def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
     def kernel(idx_ref, lut_ref, *refs):
         codes_refs, bids_refs, bother_refs = (refs[:g], refs[g:2 * g],
                                               refs[2 * g:3 * g])
-        rank_ref, slot_ref, ranku_ref = refs[3 * g:3 * g + 3]
+        sel_ref, slot_ref, ranku_ref = refs[3 * g:3 * g + 3]
         dead_refs = refs[3 * g + 3:4 * g + 3] if with_dead else ()
         acc_d_ref, acc_pos_ref, acc_id_ref, dco_ref = refs[-4:]
         qt = lut_ref.shape[0]
@@ -254,16 +260,15 @@ def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
 
         slot = per_lane(slot_ref)
         ranku = per_lane(ranku_ref)
-        # rank_of[q, other[i]]: a lane gather over nlist does not lower,
-        # so contract the rank rows with a one-hot of `other` instead —
-        # exact at HIGHEST (ranks < 2^24, BIG = 2^30)
-        nlist = rank_ref.shape[2]
-        o_hot = (jax.lax.broadcasted_iota(jnp.int32, (nlist, n), 0)
-                 == jnp.maximum(other, 0)).astype(jnp.float32)
-        orank = jnp.dot(rank_ref[:, 0, :].astype(jnp.float32), o_hot,
-                        precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32)    # (QT, n)
-        dup = (other >= 0) & (orank < ranku.astype(jnp.float32))
+        # rank_of[q, other[i]] = the first probe rank r with sel[q, r] ==
+        # other[i], P if none (above every rank_u): the query's probe list
+        # on sublanes against `other` on lanes, exact int32 compares and a
+        # min over P
+        sel = sel_ref[...]                                     # (QT, P, 1)
+        p = sel.shape[1]
+        rank = jax.lax.broadcasted_iota(jnp.int32, (qt, p, n), 1)
+        orank = jnp.min(jnp.where(sel == other, rank, p), axis=1)  # (QT, n)
+        dup = (other >= 0) & (orank < ranku)
         item_ok = (ids >= 0) & (slot >= 0)
         keep = item_ok & ~dup
         if with_dead:
@@ -307,7 +312,7 @@ def _make_topk_kernel(blk: int, f: int, g: int, with_dead: bool,
                                     "packed"))
 def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
                         block_ids: jnp.ndarray, block_other: jnp.ndarray,
-                        tile_idx: jnp.ndarray, rank_of: jnp.ndarray,
+                        tile_idx: jnp.ndarray, sel: jnp.ndarray,
                         slot_of: jnp.ndarray, rank_u: jnp.ndarray,
                         dead=None, *, query_tile: int = 8, fetch: int = 64,
                         interpret: bool = False, packed: bool = False):
@@ -319,7 +324,7 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     block_ids  (TB, BLK) i32     item ids (-1 invalid)
     block_other(TB, BLK) i32     co-assigned list of shared items (-1 none)
     tile_idx   (B//QT, S) i32    scalar-prefetched per-tile scan lists
-    rank_of    (B, nlist) i32    probe rank table (BIG if unprobed)
+    sel        (B, P) i32        ranked probe lists (rank r probes sel[b, r])
     slot_of    (B, S) i32        plan slot of scan position s for query b
                                  (-1: not in this query's plan -> masked)
     rank_u     (B, S) i32        probe rank of that slot's scan
@@ -330,8 +335,9 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     DCO counter (one per valid item of a planned block, duplicates
     included — exactly ``scan_blocks``' accounting).  The accumulator
     triple lives in VMEM for the whole inner grid pass (out BlockSpecs
-    constant in the scan dimension), as do the query tile's rank table
-    and (S,) sidecar rows.  Each grid step pages G = ``blocks_per_step
+    constant in the scan dimension), as do the query tile's probe lists
+    (padded to a multiple of 8 with -1, a (QT, P, 1) block) and (S,)
+    sidecar rows.  Each grid step pages G = ``blocks_per_step
     (BLK)`` scan positions, one paged operand per block and plane, so a
     step fills the 128 lanes with candidates; the lists are padded to a
     multiple of G with block 0 at ``slot = -1``, masked and uncounted
@@ -349,8 +355,8 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     assert blk == pow2_ceil(blk), f"block size must be a power of 2: {blk}"
     assert slot_of.shape == (b, s), (slot_of.shape, (b, s))
     assert rank_u.shape == (b, s), (rank_u.shape, (b, s))
+    assert sel.shape[0] == b, (sel.shape, b)
     f = pow2_ceil(max(fetch, 1))
-    nlist = rank_of.shape[1]
     with_dead = dead is not None
     g = blocks_per_step(blk)
     # padding positions page block 0 at slot -1: masked, never counted
@@ -358,6 +364,10 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
     slot_of = _pad_positions(slot_of.astype(jnp.int32), g, -1)
     rank_u = _pad_positions(rank_u.astype(jnp.int32), g)
     s = tile_idx.shape[1]
+    # the probe lists ride sublanes, padded to whole vregs with -1 (no
+    # co-assigned list is -1: `other >= 0` guards the compare)
+    sel = _pad_positions(sel.astype(jnp.int32), _SUBLANES, -1)
+    p = sel.shape[1]
 
     def row(x):   # per-block (TB, BLK) plane -> (TB, 1, BLK)
         return x.reshape(tb, 1, blk)
@@ -369,19 +379,19 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
                 *_paged_specs((1, blk, mb), g),
                 *_paged_specs((1, 1, blk), g),
                 *_paged_specs((1, 1, blk), g),
-                pl.BlockSpec((query_tile, 1, nlist), tile),
+                pl.BlockSpec((query_tile, p, 1), tile),
                 pl.BlockSpec((query_tile, 1, s), tile),
                 pl.BlockSpec((query_tile, 1, s), tile)]
     blocks = [block_codes] * g + [row(block_ids.astype(jnp.int32))] * g \
         + [row(block_other.astype(jnp.int32))] * g
     per_query = [_kernel_lut(lut, packed),
-                 rank_of.astype(jnp.int32).reshape(b, 1, nlist),
+                 sel.reshape(b, p, 1),
                  slot_of.reshape(b, 1, s), rank_u.reshape(b, 1, s)]
     if with_dead:
         in_specs += _paged_specs((1, 1, blk), g)
         dead_rows = [row(dead.astype(jnp.uint8))] * g
 
-    def call(idx, lut_c, rank_c, slot_c, ranku_c):
+    def call(idx, lut_c, sel_c, slot_c, ranku_c):
         bc = lut_c.shape[0]
         kernel = pl.pallas_call(
             _make_topk_kernel(blk, f, g, with_dead, packed),
@@ -400,7 +410,7 @@ def pq_scan_topk_kernel(lut: jnp.ndarray, block_codes: jnp.ndarray,
             interpret=interpret,
             name="pq_scan_topk",
         )
-        operands = [lut_c, *blocks, rank_c, slot_c, ranku_c]
+        operands = [lut_c, *blocks, sel_c, slot_c, ranku_c]
         if with_dead:
             operands += dead_rows
         return tuple(kernel(idx, *operands))

@@ -22,9 +22,10 @@ def kernel_stream(lut, block_codes, tile_idx, *, query_tile=1,
     tb, blk = block_codes.shape[:2]
     ids = jnp.arange(tb * blk, dtype=jnp.int32).reshape(tb, blk)
     other = jnp.full((tb, blk), -1, jnp.int32)
+    sel = jnp.full((b, 1), -1, jnp.int32)      # no list co-assigned
     slot_of = jnp.broadcast_to(jnp.arange(w, dtype=jnp.int32)[None], (b, w))
     d, pos, _, _ = pq_scan_topk(
-        lut, block_codes, ids, other, tile_idx, jnp.zeros((b, 1), jnp.int32),
+        lut, block_codes, ids, other, tile_idx, sel,
         slot_of, jnp.zeros((b, w), jnp.int32), fetch=w * blk,
         query_tile=query_tile, packed=packed)
     rows = jnp.arange(b)[:, None]

@@ -11,7 +11,7 @@ import pytest
 from repro.core import IndexConfig, build_index
 from repro.core.distributed import distributed_search
 from repro.core.engine import (BlockStore, batch_union, plan_blocks,
-                               scan_blocks_topk, select_lists,
+                               rank_table, scan_blocks_topk, select_lists,
                                store_from_arrays, tables_from_arrays)
 from repro.core.engine.types import BIG
 from repro.kernels.ref import pq_scan_paged_ref
@@ -125,7 +125,7 @@ def test_scan_grouped_kernel_matches_oracle():
         block_other=jnp.full((tbn, blk), -1, jnp.int32))
     nlist = 16
     sel = jax.random.randint(k4, (b, 4), 0, nlist, jnp.int32)
-    rank_of = jnp.full((b, nlist), BIG, jnp.int32)
+    rank_of = rank_table(sel, nlist)
     # a plan lists each block at most once per query (plan_blocks'
     # dedup), which the kernel path's slot maps rely on
     blocks = jax.vmap(lambda kq: jax.random.permutation(kq, tbn)[:s])(
@@ -136,7 +136,7 @@ def test_scan_grouped_kernel_matches_oracle():
                      dropped=jnp.zeros((b,), jnp.int32))
     out_k = scan_blocks_topk(store, plan, lut, rank_of, fetch=s * blk,
                              exec_mode="grouped", use_kernel=True,
-                             query_tile=4)
+                             query_tile=4, sel=sel)
     ref_d = np.asarray(pq_scan_paged_ref(lut, store.block_codes, blocks)
                        ).reshape(b, -1)
     ref_i = np.asarray(store.block_ids[blocks]).reshape(b, -1)

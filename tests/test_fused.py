@@ -19,8 +19,8 @@ from hypothesis_stub import given, settings, st
 
 from repro.core.engine import (BIG, BlockStore, QueryPlan, batch_union,
                                cluster_order, finalize_candidates, fit_tile,
-                               plan_blocks, preselect_candidates, scan_blocks,
-                               scan_blocks_topk, select_lists,
+                               plan_blocks, preselect_candidates, rank_table,
+                               scan_blocks, scan_blocks_topk, select_lists,
                                store_from_arrays, tables_from_arrays,
                                tile_unions, union_dims)
 from repro.core.pq import pq_lut
@@ -97,12 +97,14 @@ def test_pow2_ceil():
 # engine-level parity on adversarial synthetic plans
 # ---------------------------------------------------------------------------
 
-def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, nid=200,
-           tie_heavy=False, ip=False):
+def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, p=5,
+           nid=200, tie_heavy=False, ip=False):
     """A consistent (store, plan, lut, rank_of, sel, live) with duplicate
     ids, invalid items, misc co-assignments, and (optionally) integer
     luts so exact distance ties are everywhere, or inner-product tables
-    (``pq_lut_ip``: negative entries, 2-dim subspaces)."""
+    (``pq_lut_ip``: negative entries, 2-dim subspaces).  ``sel`` probes
+    ``p`` of the ``nlist`` lists and ``rank_of`` is its rank table, as
+    ``select_lists`` returns them."""
     rng = np.random.default_rng(seed)
     if ip:
         from repro.core.pq import PQCodebook, pq_lut_ip
@@ -120,19 +122,17 @@ def _synth(seed, *, b=8, s=5, tb=12, blk=32, m=4, k=16, nlist=10, nid=200,
     # SEIL plans are per-query duplicate-free among valid slots
     blocks = np.stack([rng.choice(tb, s, replace=False)
                        for _ in range(b)]).astype(np.int32)
-    ranks = np.sort(rng.integers(0, nlist, (b, s)), axis=1).astype(np.int32)
+    ranks = np.sort(rng.integers(0, p, (b, s)), axis=1).astype(np.int32)
     valid = rng.random((b, s)) < 0.85
-    rank_of = np.where(rng.random((b, nlist)) < 0.5,
-                       rng.integers(0, nlist, (b, nlist)),
-                       2 ** 30).astype(np.int32)
-    sel = np.sort(rng.choice(nlist, (b, 3), replace=True), 1).astype(np.int32)
+    sel = np.stack([rng.permutation(nlist)[:p]
+                    for _ in range(b)]).astype(np.int32)
+    rank_of = rank_table(jnp.asarray(sel), nlist)
     live = jnp.asarray(rng.random(nid) < 0.8)
     store = BlockStore(jnp.asarray(codes), jnp.asarray(ids),
                        jnp.asarray(other))
     plan = QueryPlan(jnp.asarray(blocks), jnp.asarray(ranks),
                      jnp.asarray(valid), jnp.zeros(b, jnp.int32))
-    return store, plan, jnp.asarray(lut), jnp.asarray(rank_of), \
-        jnp.asarray(sel), live
+    return store, plan, jnp.asarray(lut), rank_of, jnp.asarray(sel), live
 
 
 def _kernel_dists(store, plan, lut, sel, exec_mode, query_tile, packed):
@@ -223,7 +223,8 @@ def test_scan_blocks_topk_fetch_clamped_to_stream():
     store, plan, lut, rank_of, sel, live = _synth(5, s=2, blk=8)
     wide = 999
     out = scan_blocks_topk(store, plan, lut, rank_of, fetch=wide,
-                           exec_mode="paged", use_kernel=True, query_tile=1)
+                           exec_mode="paged", use_kernel=True, query_tile=1,
+                           sel=sel)
     s, blk = plan.blocks.shape[1], store.block_codes.shape[1]
     assert out.flat_d.shape == (plan.blocks.shape[0], s * blk)
     ref_d, ref_i, _ = _unfused_reference(store, plan, lut, rank_of, None,
@@ -268,6 +269,108 @@ def test_property_fused_topk_order(blk, s, seed, exec_mode, fetch,
     np.testing.assert_array_equal(np.asarray(out.flat_i), np.asarray(ref_i))
     np.testing.assert_array_equal(np.asarray(out.approx_dco),
                                   np.asarray(ref_dco))
+
+
+# SEIL's compute-once rule in the kernel: an item is a misc duplicate iff
+# its co-assigned list ``other`` is in the query's probe list at a rank
+# below its block's (``rank_of[q, other] < rank_u``).  The kernel tests
+# it against ``sel`` itself; the oracle reads ``rank_of``.
+_EARLIER, _LATER, _SAME, _UNPROBED, _NONE = range(5)
+
+
+def _compute_once_case(seed, *, nprobe, blk, s, b=8, m=4, k=16):
+    """Per-query blocks whose items' ``other`` is, relative to that
+    query's probe list, probed at an earlier rank than the block's own
+    list, at a later rank, at the same rank, not probed, or -1 — one
+    kind per item, cycling.  Integer LUTs make every distance exact, so
+    the kernel's and the oracle's ADC agree bitwise.  Returns the
+    arguments of ``scan_blocks_topk`` and the (B,) count of items the
+    rule must drop."""
+    rng = np.random.default_rng(seed)
+    nlist = nprobe + 3
+    sel = np.stack([rng.permutation(nlist) for _ in range(b)])
+    unprobed, sel = sel[:, nprobe:], sel[:, :nprobe].astype(np.int32)
+    ranks = np.sort(rng.integers(0, nprobe, (b, s)), axis=1).astype(np.int32)
+    valid = rng.random((b, s)) < 0.9
+    valid[:, 0] = True
+    blocks = np.arange(b * s, dtype=np.int32).reshape(b, s)  # disjoint
+    kind = np.arange(blk) % 5
+    other = np.full((b * s, blk), -1, np.int32)
+    drops = np.zeros(b, np.int64)
+    for q in range(b):
+        for j in range(s):
+            r = ranks[q, j]
+            row = other[blocks[q, j]]
+            for i in range(blk):
+                want = kind[i]
+                if want == _EARLIER and r > 0:
+                    row[i] = sel[q, rng.integers(0, r)]
+                    drops[q] += int(valid[q, j])
+                elif want == _LATER and r + 1 < nprobe:
+                    row[i] = sel[q, rng.integers(r + 1, nprobe)]
+                elif want in (_EARLIER, _LATER, _SAME):
+                    row[i] = sel[q, r]
+                elif want == _UNPROBED:
+                    row[i] = rng.choice(unprobed[q])
+    ids = np.arange(b * s * blk, dtype=np.int32).reshape(b * s, blk)
+    store = BlockStore(
+        jnp.asarray(rng.integers(0, k, (b * s, blk, m)).astype(np.uint8)),
+        jnp.asarray(ids), jnp.asarray(other))
+    plan = QueryPlan(jnp.asarray(blocks), jnp.asarray(ranks),
+                     jnp.asarray(valid), jnp.zeros(b, jnp.int32))
+    lut = jnp.asarray(rng.integers(0, 3, (b, m, k)).astype(np.float32))
+    sel = jnp.asarray(sel)
+    live = jnp.asarray(rng.random(b * s * blk) < 0.8)
+    return store, plan, lut, rank_table(sel, nlist), sel, live, drops
+
+
+@pytest.fixture
+def drop_executables():
+    """Each case compiles the kernel at shapes of its own, and every
+    executable holds some 800 memory mappings: a process that ran this
+    whole file keeping them would reach the kernel's limit on mappings
+    (``vm.max_map_count``, 65,530 by default) and crash inside a compile.
+    So the executables are dropped after each case."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("nprobe", [1, 5, 32, 33])
+@pytest.mark.parametrize("exec_mode,query_tile", [("paged", 1),
+                                                  ("clustered", 8)])
+@pytest.mark.parametrize("blk,s", [(32, 6), (128, 2)])
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("with_live", [False, True])
+def test_kernel_compute_once_rule_matches_oracle(nprobe, exec_mode,
+                                                 query_tile, blk, s, packed,
+                                                 with_live,
+                                                 drop_executables):
+    """The kernel's compute-once test against the probe list equals the
+    oracle's rank-table test bitwise — candidates, order and DCO — at
+    probe lists of 1, 5, 32 and 33 (not a multiple of the 8 sublanes
+    the kernel pads to with -1), query tiles 1 and 8, four blocks of 32
+    or one of 128 per grid step, packed or not, with tombstones or not;
+    and it drops exactly the items probed at an earlier rank."""
+    store, plan, lut, rank_of, sel, live, drops = _compute_once_case(
+        nprobe * 7 + blk, nprobe=nprobe, blk=blk, s=s)
+    if packed:
+        store = store._replace(block_codes=jnp.asarray(
+            pack_nibbles(np.asarray(store.block_codes))))
+    live = live if with_live else None
+    scan = jax.jit(scan_blocks_topk, static_argnames=(
+        "fetch", "exec_mode", "use_kernel", "query_tile", "packed"))
+    kw = dict(fetch=s * blk, exec_mode=exec_mode, query_tile=query_tile,
+              sel=sel, live=live, packed=packed)
+    ref = scan(store, plan, lut, rank_of, use_kernel=False, **kw)
+    out = scan(store, plan, lut, rank_of, use_kernel=True, **kw)
+    for got, want in zip(out, ref):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if live is None:
+        # every item is a valid id: a kept candidate is one not dropped
+        items = np.asarray(plan.valid).sum(1) * blk
+        np.testing.assert_array_equal(np.asarray(out.approx_dco), items)
+        np.testing.assert_array_equal(
+            np.isfinite(np.asarray(out.flat_d)).sum(1), items - drops)
 
 
 # ---------------------------------------------------------------------------
